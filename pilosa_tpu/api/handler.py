@@ -1042,9 +1042,12 @@ class Handler:
                 "(unstaged = view missing/unstageable, oom = device "
                 "memory exhausted after eviction, hbm_infeasible = one "
                 "view overflows the budget, quarantined = plan "
-                "signature serving a failure quarantine).")
+                "signature serving a failure quarantine, compile = the "
+                "compiler refused the program, error = any other "
+                "exception on the device path).")
             fb.add(stats.get("fallback", 0), {"reason": "unstaged"})
-            for reason in ("oom", "hbm_infeasible", "quarantined"):
+            for reason in ("oom", "hbm_infeasible", "quarantined",
+                           "compile", "error"):
                 fb.add(stats.get(f"fallback_{reason}", 0),
                        {"reason": reason})
             fams.append(fb)
@@ -1530,6 +1533,16 @@ class Handler:
                 snap = dict(snap, count_calibration=cal)
         except Exception:  # noqa: BLE001 — debug never 500s
             pass
+        # The JAX runtime behind the device path: versions, platform,
+        # device kind and count, compile-cache directory, compile
+        # totals, device memory. Only once the device path has come up
+        # (asking earlier would initialise a backend nobody asked for).
+        if "mesh" in snap or "count_calibration" in snap:
+            from .. import jaxrt
+
+            rt = jaxrt.snapshot()
+            if rt is not None:
+                snap = dict(snap, jax_runtime=rt)
         hc = getattr(self.executor, "host_cache_stats", None)
         if hc:
             snap = dict(snap, host_cache=dict(hc))

@@ -905,17 +905,17 @@ class Config:
 
 # -- roofline peak table (obs/profile.py) ---------------------------------
 #
-# Per-backend peak memory bandwidth in bytes/s. TPU entries are the
-# per-chip HBM spec (v5e: ~819 GB/s — PROFILE_ROOFLINE.md uses the same
-# number); the roofline judges a single chip's stream, the profile
-# reports bytes touched across all local devices, so fractions > 1 on a
-# multi-chip mesh mean "faster than one chip", which is the honest
-# per-dispatch reading until per-device attribution lands.
+# Peak HBM bandwidth of ONE chip in bytes/s, keyed by the device_kind
+# JAX reports (jax.devices()[0].device_kind). The roofline judges a
+# single chip's stream while the profile reports bytes touched across
+# all local devices, so fractions > 1 on a multi-chip mesh mean "faster
+# than one chip". A device that is not in the table is an error, not a
+# default: a share of a guessed peak is not a measurement.
 HBM_PEAK_BYTES_PER_S = {
-    "tpu": 819e9,        # default TPU guess: v5e per-chip HBM
-    "tpu-v5e": 819e9,
-    "tpu-v4": 1228e9,
-    "gpu": 2039e9,       # A100-80G class
+    # Google Cloud documentation, "TPU v5e": 16 GB of HBM2e at 819 GB/s.
+    "TPU v5 lite": 819e9,
+    # Google Cloud documentation, "TPU v4": 32 GiB of HBM2 at 1,228 GB/s.
+    "TPU v4": 1228e9,
 }
 
 _HOST_PEAK: Optional[float] = None
@@ -942,15 +942,20 @@ def _measure_host_bandwidth() -> float:
     return (2 * src.nbytes) / best if best > 0 else 1e9
 
 
-def peak_memory_bandwidth(backend: str) -> float:
-    """Peak bytes/s for a backend name ("tpu", "cpu", "host", ...).
-    Unknown accelerators fall back to the TPU default; cpu/host use the
-    measured (cached) host memcpy bandwidth."""
-    b = (backend or "").lower()
-    if b in ("cpu", "host", ""):
+def peak_memory_bandwidth(device_kind: str) -> float:
+    """Peak bytes/s for a device kind as JAX names it ("TPU v5 lite"),
+    or for the host ("cpu", "host", ""), whose figure is the measured
+    (cached) memcpy bandwidth. An accelerator that is not in the table
+    raises KeyError."""
+    if (device_kind or "").lower() in ("cpu", "host", ""):
         global _HOST_PEAK
         with _HOST_PEAK_MU:
             if _HOST_PEAK is None:
                 _HOST_PEAK = _measure_host_bandwidth()
             return _HOST_PEAK
-    return HBM_PEAK_BYTES_PER_S.get(b, HBM_PEAK_BYTES_PER_S["tpu"])
+    try:
+        return HBM_PEAK_BYTES_PER_S[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no HBM peak recorded for device kind {device_kind!r}; add "
+            f"it to config.HBM_PEAK_BYTES_PER_S with its source") from None

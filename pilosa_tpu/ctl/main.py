@@ -96,6 +96,12 @@ def cmd_server(args) -> int:
     # trace/span id into every record (obs/log.py).
     obs_log.setup(level=cfg.log_level, fmt=cfg.log_format,
                   path=args.log_path or cfg.log_file or cfg.log_path)
+    if cfg.use_device_flag() is not False:
+        # Before anything compiles: a cold start otherwise pays every
+        # program again (a host-only server never imports jax).
+        from ..jaxrt import setup_compile_cache
+
+        setup_compile_cache()
     srv = Server(cfg)
     srv.open()
     print(f"pilosa-tpu listening on http://{srv.host} "

@@ -187,6 +187,9 @@ def _device_top_pairs(frag, min_threshold: int, n: int):
         padded = 1 << (len(row_ids) - 1).bit_length()
         counts = np.asarray(pool_row_counts(pool, padded))[:len(row_ids)]
     except Exception:  # noqa: BLE001 — device attempt failed: host path
+        obs.get_logger("executor").warning(
+            "per-fragment device TopN failed; the host path answers",
+            exc_info=True)
         return None
     keep = np.nonzero(counts >= min_threshold)[0]
     order = np.lexsort((row_ids[keep], -counts[keep]))
@@ -1068,6 +1071,7 @@ class Executor:
                     if neg is None:
                         return None
             except Exception:  # noqa: BLE001 — device failure → host
+                self._device_failed("BSI sum")
                 return None
             return sum_from_plane_dicts(counts, neg, schema.bit_depth)
 
@@ -1106,6 +1110,7 @@ class Executor:
                          mgr.count(index, shape, leaves, batch_slices,
                                    num))
                 except Exception:  # noqa: BLE001 — device → host
+                    self._device_failed("BSI extremum count")
                     return None
                 return None if n is None else int(n)
 
@@ -1202,8 +1207,23 @@ class Executor:
                                              config=self.mesh_config)
             except Exception:  # noqa: BLE001 — device layer unavailable
                 self._mesh_mgr_failed = True
+                obs.get_logger("executor").warning(
+                    "mesh manager construction failed; serving from the "
+                    "host for the life of this process", exc_info=True)
                 return None
         return self._mesh_mgr
+
+    def _device_failed(self, what: str) -> None:
+        """A device attempt raised and the host path is about to answer
+        in its place. Production keeps serving; the failure is counted
+        (pilosa_device_fallback_total{reason="error"}) and logged with
+        its traceback, so a device path that never works cannot pass
+        for one that does. Call from the except block."""
+        mgr = self._mesh_mgr
+        if mgr is not None:
+            mgr.stats.inc("fallback_error")
+        obs.get_logger("executor").warning(
+            "device %s failed; the host path answers", what, exc_info=True)
 
     def invalidate_device_index(self, index: Optional[str] = None):
         """Drop staged device images for an index (or all). Called by
@@ -1855,6 +1875,7 @@ class Executor:
                         index, shape, leaves, batch_slices,
                         self._batch_num_slices(index, batch_slices))
                 except Exception:  # noqa: BLE001 — device failure → host
+                    self._device_failed("SPMD count")
                     return None
                 if n is not None and self._shadow_sampled():
                     n = self._shadow_check_count(
@@ -1868,6 +1889,7 @@ class Executor:
                 n = mgr.count(index, shape, leaves, batch_slices,
                               self._batch_num_slices(index, batch_slices))
             except Exception:  # noqa: BLE001 — any device failure → host path
+                self._device_failed("count")
                 return None
             if n is not None and self._shadow_sampled():
                 n = self._shadow_check_count(
@@ -2157,6 +2179,7 @@ class Executor:
                         attr_predicate=attr_predicate,
                         tanimoto_threshold=tanimoto)
                 except Exception:  # noqa: BLE001 — device failure → host
+                    self._device_failed("SPMD TopN")
                     return None
                 return shadow(batch_slices, pairs, "spmd")
 
@@ -2172,6 +2195,7 @@ class Executor:
                     attr_predicate=attr_predicate,
                     tanimoto_threshold=tanimoto)
             except Exception:  # noqa: BLE001 — any device failure → host path
+                self._device_failed("TopN")
                 return None
             return shadow(batch_slices, pairs, "mesh")
 

@@ -6,8 +6,9 @@ tracer) through the same seams the tracer instruments — parse, plan,
 H2D staging, compile, device dispatch, D2H readback, host fold, remote
 fan-out — but where spans record *shape* (who called what, when), the
 profile records *cost*: per-phase wall time unioned across threads,
-bytes moved per direction, and the achieved-bytes/s-vs-peak roofline
-that PROFILE_ROOFLINE.md used to compute by hand.
+bytes moved per direction, and achieved bytes/s against the device's
+peak (a host-bracketed figure; a kernel's roofline share needs a
+device trace).
 
 Same cardinal rule as the tracer: near-free when nobody is looking.
 `phase("x")` with no active profile is one ContextVar read returning a
@@ -237,9 +238,17 @@ class QueryProfile:
             out["fraction_of_peak"] = 0.0
             return out
         achieved = touched / (ns / 1e9)
-        peak = peak_bytes_per_s(self.backend if engine == "device"
-                                else "host")
         out["achieved_bytes_per_s"] = round(achieved, 1)
+        kind = default_device_kind() if engine == "device" else "host"
+        try:
+            peak = peak_bytes_per_s(kind)
+        except KeyError:
+            # An accelerator the table does not list: the query still
+            # answers, with no share of a peak nobody recorded.
+            out["peak_bytes_per_s"] = None
+            out["fraction_of_peak"] = None
+            out["peak_unknown_device_kind"] = kind
+            return out
         out["peak_bytes_per_s"] = round(peak, 1)
         out["fraction_of_peak"] = round(achieved / peak, 6) if peak else 0.0
         return out
@@ -335,11 +344,32 @@ def default_backend() -> str:
     return b
 
 
-def peak_bytes_per_s(backend: str) -> float:
-    """Per-backend peak memory bandwidth (config.py owns the table;
-    lazy import — config imports parallel which imports obs)."""
+_DEVICE_KIND: Optional[str] = None
+
+
+def default_device_kind() -> str:
+    """Cached jax.devices()[0].device_kind ("TPU v5 lite"), the key of
+    config.py's peak table; "cpu" on the CPU backend, whose peak is
+    the measured host bandwidth."""
+    global _DEVICE_KIND
+    k = _DEVICE_KIND
+    if k is None:
+        if default_backend() == "cpu":
+            k = "cpu"
+        else:
+            import jax
+            k = str(jax.devices()[0].device_kind)
+        _DEVICE_KIND = k
+    return k
+
+
+def peak_bytes_per_s(device_kind: str) -> float:
+    """Peak memory bandwidth of a device kind, or of the host
+    (config.py owns the table and raises for an accelerator it does
+    not list; lazy import — config imports parallel which imports
+    obs)."""
     from .. import config as _config
-    return _config.peak_memory_bandwidth(backend)
+    return _config.peak_memory_bandwidth(device_kind)
 
 
 # -- process-wide phase histograms (exported at /metrics) ----------------

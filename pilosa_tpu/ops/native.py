@@ -2,17 +2,26 @@
 
 The analog of the reference's runtime assembly dispatch
 (roaring/assembly_asm.go:20,40-80 hasAsm + function-pointer selection):
-on first import, build (if needed) and load native/libpilosa_native.so;
-every kernel has a numpy fallback so the package works without a C++
-toolchain. `has_native()` reports which path is live;
+on first use, build (if needed) and load the library under
+native/build/; every kernel has a numpy fallback so the package works
+without a C++ toolchain. `has_native()` reports which path is live;
 `PILOSA_TPU_NO_NATIVE=1` forces the fallback (the reference's
 `go build -tags noasm` escape hatch).
+
+The library is compiled with -march=native, so it belongs to the CPU
+that built it: its file name carries a digest of that CPU's flags. A
+build directory that travelled here from another machine (it is not in
+git, but a copy of the tree brings it along) then holds a library this
+host does not look for, and the right one is built beside it.
 """
 
 from __future__ import annotations
 
 import ctypes
+import functools
+import hashlib
 import os
+import platform
 import subprocess
 from typing import Optional
 
@@ -21,7 +30,31 @@ import numpy as np
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "build", "libpilosa_native.so")
+
+
+@functools.lru_cache(maxsize=None)
+def _lib_name() -> str:
+    """The library's file name on this host: it carries a digest of
+    what -march=native selects here, the machine type and the CPU's
+    feature flags (first `flags`/`Features` line of /proc/cpuinfo; the
+    machine type alone where there is no such file)."""
+    flags = ""
+    try:
+        with open("/proc/cpuinfo", encoding="utf-8") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    flags = " ".join(sorted(line.split(":", 1)[1].split()))
+                    break
+    except OSError:
+        pass
+    key = hashlib.sha1(
+        f"{platform.machine()} {flags}".encode()).hexdigest()[:12]
+    return f"libpilosa_native-{key}.so"
+
+
+def _lib_path() -> str:
+    return os.path.join(_NATIVE_DIR, "build", _lib_name())
+
 
 _U64P = ctypes.POINTER(ctypes.c_uint64)
 _U32P = ctypes.POINTER(ctypes.c_uint32)
@@ -29,7 +62,6 @@ _U8P = ctypes.POINTER(ctypes.c_uint8)
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
-_FAIL_STAMP = os.path.join(_NATIVE_DIR, "build", ".build_failed")
 
 
 def _src_mtime() -> float:
@@ -46,19 +78,22 @@ def _build() -> bool:
     # A previously failed build is cached on disk and only retried when
     # the source changes, so toolchain-less machines pay the failed
     # compile once, not per process.
+    fail_stamp = _lib_path() + ".build_failed"
     try:
-        if os.path.exists(_FAIL_STAMP) and                 float(open(_FAIL_STAMP).read() or 0) == _src_mtime():
-            return False
+        with open(fail_stamp) as f:
+            if float(f.read() or 0) == _src_mtime():
+                return False
     except (OSError, ValueError):
         pass
     try:
-        subprocess.run(["make", "-C", _NATIVE_DIR, "-s"], check=True,
+        subprocess.run(["make", "-C", _NATIVE_DIR, "-s",
+                        f"LIB_NAME={_lib_name()}"], check=True,
                        capture_output=True, timeout=120)
-        return os.path.exists(_LIB_PATH)
+        return os.path.exists(_lib_path())
     except Exception:  # noqa: BLE001 — no toolchain: numpy fallback
         try:
-            os.makedirs(os.path.dirname(_FAIL_STAMP), exist_ok=True)
-            with open(_FAIL_STAMP, "w") as f:
+            os.makedirs(os.path.dirname(fail_stamp), exist_ok=True)
+            with open(fail_stamp, "w") as f:
                 f.write(str(_src_mtime()))
         except OSError:
             pass
@@ -72,10 +107,10 @@ def _load() -> Optional[ctypes.CDLL]:
     # source, and rebuilds a stale .so after source edits. A failed build
     # (no toolchain) still loads a previously built library if present.
     _build()
-    if not os.path.exists(_LIB_PATH):
+    if not os.path.exists(_lib_path()):
         return None
     try:
-        lib = ctypes.CDLL(_LIB_PATH)
+        lib = ctypes.CDLL(_lib_path())
     except OSError:
         return None
     lib.pilosa_popcnt_slice.restype = ctypes.c_uint64

@@ -571,9 +571,7 @@ class SpmdServer:
 
         fp = (np.int64(0) if fingerprint_blob is None
               else np.int64(zlib.crc32(fingerprint_blob) + 1))
-        # older jax returns a 0-d array for a scalar single-process
-        # allgather — normalize before indexing
-        fps = np.atleast_1d(multihost_utils.process_allgather(fp))
+        fps = multihost_utils.process_allgather(fp)  # (num_processes,)
         # Veto accounting distinguishes the two skip causes: this rank
         # (or a peer — every rank that gathered a 0 reports not_ready)
         # had no program vs all ranks resolved programs that DISAGREE.

@@ -212,7 +212,9 @@ class Server:
                     from .ops.calibrate import resolve_backend
                     resolve_backend()
                 except Exception:  # noqa: BLE001 — boot never dies here
-                    pass
+                    self.logger.warning(
+                        "count-backend calibration failed at boot; the "
+                        "first count resolves it again", exc_info=True)
             threading.Thread(target=_kick, daemon=True,
                              name="count-calibrate-boot").start()
         self.executor = Executor(

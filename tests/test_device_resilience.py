@@ -220,6 +220,104 @@ class TestOomRecovery:
         assert mgr.stats["stage"] == 0
 
 
+# What the chip's compiler says when it refuses a program, and what the
+# runtime says when an allocation fails. The first two were raised here
+# by the installed compiler for a described v5e chip
+# (tests/test_tpu_compile.py raises them again, live); the third came
+# from a TPU v5 lite (chip run, PR 21). All three carry
+# RESOURCE_EXHAUSTED; only the third is a device OOM.
+VMEM_REFUSAL = (
+    "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+    "allocating on stack for %run.1 = s32[28,960]{1,0:T(8,128)S(1)} "
+    "custom-call(...), custom_call_target=\"tpu_custom_call\". Scoped "
+    "allocation with size 17.63M and limit 16.00M exceeded scoped vmem "
+    "limit by 1.63M.")
+HBM_COMPILE_REFUSAL = (
+    "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+    "memory in memory space hbm. Used 22.50G of 15.75G hbm. Exceeded hbm "
+    "capacity by 6.75G.")
+RUNTIME_OOM = (
+    "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting to "
+    "allocate 1.00G. That was not possible. There are 765.88M free.; "
+    "(0x0x0_HBM0)")
+
+
+class TestCompileRefusalIsNotOom:
+    """jit compiles lazily, so a compiler refusal surfaces at the first
+    launch inside _guarded_exec with the same status word a device OOM
+    carries. It must not walk the OOM ladder: evicting every staged
+    view cannot make a kernel's VMEM need smaller."""
+
+    @pytest.mark.parametrize("msg", [VMEM_REFUSAL, HBM_COMPILE_REFUSAL])
+    def test_classified_as_refusal_not_oom(self, msg):
+        from pilosa_tpu.parallel.serve import (_is_compile_refusal,
+                                               _is_resource_exhausted)
+
+        err = RuntimeError(msg)
+        assert _is_compile_refusal(err)
+        assert not _is_resource_exhausted(err)
+
+    def test_runtime_allocation_failure_is_still_oom(self):
+        from pilosa_tpu.parallel.serve import (_is_compile_refusal,
+                                               _is_resource_exhausted)
+
+        err = ValueError(RUNTIME_OOM)  # the class jax raised on the chip
+        assert _is_resource_exhausted(err)
+        assert not _is_compile_refusal(err)
+
+    @pytest.mark.parametrize("msg", [VMEM_REFUSAL, HBM_COMPILE_REFUSAL])
+    def test_guarded_exec_neither_evicts_nor_retries(self, holder, msg):
+        seed(holder, bits=[(1, 0), (1, 1)])
+        e = make_executor(holder, budget_bytes=-1)
+        assert q(e, "i", "Count(Bitmap(rowID=1))") == [2]  # stages a view
+        mgr = e.mesh_manager()
+        assert len(mgr._views) == 1
+        staged0 = mgr.stats["stage"]
+        launches = []
+
+        def launch():
+            launches.append(1)
+            raise RuntimeError(msg)
+
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            mgr._guarded_exec("[\"leaf\", 0]", launch)
+        assert launches == [1]                       # not retried
+        assert len(mgr._views) == 1                  # nothing evicted
+        assert mgr.stats["evicted_oom"] == 0
+        assert mgr.stats["oom_retries"] == 0
+        assert mgr.stats["fallback_oom"] == 0
+        assert mgr.stats["fallback_compile"] == 1    # counted as itself
+        assert mgr.stats["stage"] == staged0
+
+    def test_refusal_at_result_fetch_is_not_counted_as_oom(self, holder):
+        """The async half: an error that surfaces when the batch's
+        result is fetched goes through the same classifier."""
+        seed(holder, bits=[(1, 0), (2, 0)])
+        e = make_executor(holder, budget_bytes=-1, quarantine_after=1000)
+        mgr = e.mesh_manager()
+
+        class Limbs:  # what a launch hands to finish()
+            def copy_to_host_async(self):
+                pass
+
+            def __array__(self, *a, **k):
+                raise RuntimeError(VMEM_REFUSAL)
+
+        real = mgr._guarded_exec
+        mgr._guarded_exec = lambda sig, launch, **kw: Limbs()
+        mgr.lone_fused = False  # take the batch path, whose fetch is async
+        try:
+            host = Executor(holder, use_device=False)
+            pql = "Count(Intersect(Bitmap(rowID=1), Bitmap(rowID=2)))"
+            assert q(e, "i", pql) == q(host, "i", pql) == [1]
+        finally:
+            mgr._guarded_exec = real
+        assert mgr.stats["fallback_compile"] == 1
+        assert mgr.stats["fallback_oom"] == 0
+        assert mgr.stats["evicted_oom"] == 0
+        assert mgr.stats["fallback_error"] == 1  # the executor said so
+
+
 class TestInfeasible:
     def test_budget_below_one_view_host_folds(self, holder):
         seed(holder, bits=[(1, 0), (1, SLICE_WIDTH + 2)])

@@ -171,10 +171,25 @@ class TestQueryProfile:
 
 class TestPeakBandwidth:
     def test_tpu_table(self):
-        assert config.peak_memory_bandwidth("tpu") == 819e9
-        assert config.peak_memory_bandwidth("tpu-v4") == 1228e9
-        # Unknown accelerator falls back to the conservative default.
-        assert config.peak_memory_bandwidth("tpu-v9") == 819e9
+        """Keyed by the device_kind JAX reports; a device the table
+        does not list is an error, not a default."""
+        assert config.peak_memory_bandwidth("TPU v5 lite") == 819e9
+        assert config.peak_memory_bandwidth("TPU v4") == 1228e9
+        for unknown in ("tpu", "TPU v9", "gpu"):
+            with pytest.raises(KeyError, match="no HBM peak recorded"):
+                config.peak_memory_bandwidth(unknown)
+
+    def test_roofline_of_an_unlisted_device_has_no_fraction(
+            self, monkeypatch):
+        monkeypatch.setattr(profile, "_DEVICE_KIND", "TPU v9")
+        p = profile.QueryProfile()
+        p.add_phase_ns("device_exec", 1_000_000)
+        p.add_bytes("bytes_touched_hbm", 1 << 20)
+        p.finish()
+        rf = p.to_dict()["roofline"]
+        assert rf["achieved_bytes_per_s"] > 0
+        assert rf["fraction_of_peak"] is None
+        assert rf["peak_unknown_device_kind"] == "TPU v9"
 
     def test_host_measured_and_cached(self):
         a = config.peak_memory_bandwidth("cpu")

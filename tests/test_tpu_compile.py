@@ -1,0 +1,411 @@
+"""The main path's kernels and serve programs, compiled for the chip.
+
+Interpret mode and the CPU mesh say a kernel computes the right thing;
+they do not say the chip's compiler will take it. The TPU compiler is
+installed here and compiles for a chip that is described, not attached
+(`jax.experimental.topologies`), so these tests hold every program the
+serving dispatch can select at the headline shape (960 slices, an
+8-row dense pool of (960, 128, 2048) uint32, `_MAX_BATCH` = 16, 2-8
+leaves, shared groups up to the 28 pairs of 8 rows) to what that
+compiler accepts: VMEM and SMEM budgets, tiling, compile-time HBM
+accounting. Nothing runs, so nothing here is a result or a time.
+
+The topology is described inside a module-scoped fixture, never while
+a module is imported, in a skipif or in parametrize: only one process
+may load the TPU library, and under pytest-xdist every worker imports
+every test file. Keep these tests in this one file (a second file
+could land on a worker that cannot load the library, and skip).
+"""
+
+import itertools
+
+import numpy as np
+import pytest
+
+S, CAP = 960, 128  # the BASELINE.json "1B-col Intersect+Count" pool
+AND2 = ["and", ["leaf", 0], ["leaf", 1]]
+PAIRS28 = tuple(itertools.combinations(range(8), 2))
+
+
+def nary(op, n):
+    tree = ["leaf", 0]
+    for i in range(1, n):
+        tree = [op, tree, ["leaf", i]]
+    return tree
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent
+    # cache but cannot be read back without one (it warns and compiles
+    # again), so the cache is off around these tests.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+class Chip:
+    """Shapes on a mesh of described devices (1 chip, or the 2x2)."""
+
+    def __init__(self, devices):
+        from jax.sharding import Mesh
+
+        from pilosa_tpu.parallel.mesh import SLICE_AXIS
+
+        self.axis = SLICE_AXIS
+        self.mesh = Mesh(np.array(devices), (SLICE_AXIS,))
+
+    def _sds(self, shape, dtype, spec):
+        import jax
+        from jax.sharding import NamedSharding
+
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(self.mesh, spec))
+
+    def sliced(self, dtype, *extra, s=S):
+        from jax.sharding import PartitionSpec as P
+
+        return self._sds((s,) + extra, dtype, P(self.axis))
+
+    def repl(self, dtype, *shape):
+        from jax.sharding import PartitionSpec as P
+
+        return self._sds(shape, dtype, P())
+
+    def pool(self, cap=CAP):
+        return self.sliced(np.uint32, cap, 2048)
+
+    def starts_valid(self, n):
+        return (tuple(self.sliced(np.int32) for _ in range(n)),
+                tuple(self.sliced(np.uint32) for _ in range(n)))
+
+    def idx_hit(self, n):
+        return (tuple(self.sliced(np.int32, 16) for _ in range(n)),
+                tuple(self.sliced(np.uint32, 16) for _ in range(n)))
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    return Chip(topo.devices[:1])
+
+
+@pytest.fixture(scope="module")
+def four(topo):
+    return Chip(topo.devices)
+
+
+def compiled(fn, *args):
+    """Lower and compile; what the chip's compiler would raise, this
+    raises. Returns (HLO text, memory analysis)."""
+    import jax
+
+    fn = fn if hasattr(fn, "lower") else jax.jit(fn)
+    c = fn.lower(*args).compile()
+    return c.as_text(), c.memory_analysis()
+
+
+def kernels_in(text):
+    return text.count("tpu_custom_call")
+
+
+# -- raw kernels -------------------------------------------------------------
+
+def test_pair_count_kernel(chip):
+    """fused_pair_count's kernel over a whole 960-slice row pair."""
+    from pilosa_tpu.ops.kernels import _pallas_pair_count
+
+    a = chip.repl(np.uint32, S * 16, 2048)
+    text, _ = compiled(_pallas_pair_count, a, a)
+    assert kernels_in(text) == 1
+
+
+@pytest.mark.parametrize("k", [512, 2048, 4096])
+def test_sparse_pair_kernel(chip, k):
+    """The sorted-array intersect kernel at real value capacities: one
+    b-slab (512, the calibrator's shape) and several (a 3%-dense
+    container pads to 2048; 4096 is the array break-even). Above one
+    slab it did not lower before PR 21."""
+    from pilosa_tpu.ops.kernels import pallas_sparse_pair_counts
+
+    n = S * 16
+    text, _ = compiled(pallas_sparse_pair_counts,
+                       chip.repl(np.uint16, n, k), chip.repl(np.int32, n),
+                       chip.repl(np.uint16, n, k), chip.repl(np.int32, n))
+    assert kernels_in(text) == 1
+
+
+def test_general_tree_kernels(chip):
+    """tree_count_pallas (slab scan) and its coarse twin, the kernels
+    behind compile_mesh_count's Pallas backend."""
+    from pilosa_tpu.ops.kernels import (tree_count_pallas,
+                                        tree_count_pallas_coarse)
+
+    w = chip.repl(np.uint32, S, CAP, 2048)
+    text, _ = compiled(
+        lambda w, i, h: tree_count_pallas(w, i, h, AND2), w,
+        chip.repl(np.int32, 2, S, 16), chip.repl(np.int32, 2, S, 16))
+    assert kernels_in(text) >= 1
+    text, _ = compiled(
+        lambda w, st: tree_count_pallas_coarse(w, st, AND2), w,
+        chip.repl(np.int32, 2, S))
+    assert kernels_in(text) == 1
+
+
+# -- what _run_count_group and _coarse_fn can select -------------------------
+
+@pytest.mark.parametrize("op,leaves", [("and", 1), ("and", 2), ("or", 2),
+                                       ("andnot", 2), ("and", 8),
+                                       ("andnot", 8)])
+def test_lone_coarse_pallas(chip, op, leaves):
+    """A group of one: the per-slice kernel and, for a uniformly
+    staged pool, the multi-slice-fetch kernel."""
+    from pilosa_tpu.parallel.mesh import (
+        compile_serve_count_coarse_pallas,
+        compile_serve_count_coarse_pallas_uniform)
+
+    tree = nary(op, leaves)
+    w = (chip.pool(),) * leaves
+    mask = chip.sliced(np.int32)
+    text, _ = compiled(
+        compile_serve_count_coarse_pallas(chip.mesh, tree, leaves),
+        w, *chip.starts_valid(leaves), mask)
+    assert kernels_in(text) == 1
+    text, _ = compiled(
+        compile_serve_count_coarse_pallas_uniform(chip.mesh, tree, leaves, 1),
+        w, chip.repl(np.int32, leaves), mask)
+    assert kernels_in(text) == 1
+
+
+@pytest.mark.parametrize("op,leaves", [("and", 2), ("or", 2), ("and", 8)])
+def test_padded_batch_pallas(chip, op, leaves):
+    """Every multi-request group runs at _MAX_BATCH: the identity-batch
+    kernel and its uniform twin."""
+    from pilosa_tpu.parallel.mesh import (
+        compile_serve_count_coarse_pallas_batch,
+        compile_serve_count_coarse_pallas_uniform)
+    from pilosa_tpu.parallel.serve import MeshManager
+
+    b = MeshManager._MAX_BATCH
+    tree = nary(op, leaves)
+    w = (chip.pool(),) * leaves
+    mask = chip.sliced(np.int32)
+    text, _ = compiled(
+        compile_serve_count_coarse_pallas_batch(chip.mesh, tree, leaves, b),
+        w, *chip.starts_valid(leaves * b), mask)
+    assert kernels_in(text) == 1
+    text, _ = compiled(
+        compile_serve_count_coarse_pallas_uniform(chip.mesh, tree, leaves, b),
+        w, chip.repl(np.int32, leaves * b), mask)
+    assert kernels_in(text) == 1
+
+
+@pytest.mark.parametrize("name,leaf_map,uniques", [
+    ("28-of-8", PAIRS28, 8),          # every pair of the 8 headline rows
+    ("16-of-8", PAIRS28[:16], 8),     # the widest group the batcher forms
+    ("16-of-11", tuple((i, i + 1) for i in range(10))
+     + tuple((i, i + 2) for i in range(6)), 11),  # most uniques the
+    #                                   aliased-argument budget admits
+])
+def test_shared_read_pallas(chip, name, leaf_map, uniques):
+    """The shared-read programs. The uniform one was refused at 28-of-8
+    before PR 21 (17.63 MB of a 16 MB scoped VMEM window): its budget
+    counted the operand blocks and not the fold temporaries."""
+    from pilosa_tpu.parallel.mesh import (
+        compile_serve_count_batch_shared_pallas,
+        compile_serve_count_batch_shared_pallas_uniform)
+
+    w = (chip.pool(),) * uniques
+    mask = chip.sliced(np.int32)
+    text, _ = compiled(
+        compile_serve_count_batch_shared_pallas(
+            chip.mesh, AND2, leaf_map, uniques),
+        w, *chip.starts_valid(uniques), mask)
+    assert kernels_in(text) == 1
+    text, _ = compiled(
+        compile_serve_count_batch_shared_pallas_uniform(
+            chip.mesh, AND2, leaf_map, uniques),
+        w, chip.repl(np.int32, uniques), mask)
+    assert kernels_in(text) == 1
+
+
+@pytest.mark.parametrize("program", ["coarse_b16", "shared_28_of_8",
+                                     "general_b16", "fused_8", "general_8"])
+def test_xla_count_programs(chip, program):
+    """The XLA twins and the programs with no Pallas form (the fused
+    lone count, the general container gather): what `auto` serves when
+    the calibrator picks XLA. Their refusal would be HBM, at compile
+    time, where every aliased leaf operand is billed as its own pool."""
+    from pilosa_tpu.parallel import mesh as M
+
+    mask = chip.sliced(np.int32)
+    w = chip.pool()
+    if program == "coarse_b16":
+        fn = M.compile_serve_count_coarse(chip.mesh, AND2, 2, 16)
+        args = ((w, w), *chip.starts_valid(32), mask)
+    elif program == "shared_28_of_8":
+        fn = M.compile_serve_count_batch_shared(chip.mesh, AND2, PAIRS28, 8)
+        args = ((w,) * 8, *chip.starts_valid(8), mask)
+    elif program == "general_b16":
+        fn = M.compile_serve_count_batch(chip.mesh, AND2, 2, 16)
+        args = ((w, w), *chip.idx_hit(32), mask)
+    elif program == "fused_8":
+        fn = M.compile_serve_count_fused(chip.mesh, nary("andnot", 8), 8)
+        args = ((w,) * 8, chip.repl(np.int32, 8, S, 16),
+                chip.repl(np.uint32, 8, S, 16), chip.repl(np.int32, S))
+    else:
+        fn = M.compile_serve_count(chip.mesh, nary("or", 8), 8)
+        args = ((w,) * 8, *chip.idx_hit(8), mask)
+    text, mem = compiled(fn, *args)
+    assert kernels_in(text) == 0
+    # "Used ... of 15.75G hbm" in the compiler's words, G = 2**30.
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < 15.75 * 2**30
+
+
+# -- the sparse path, TopN, BSI, writes --------------------------------------
+
+@pytest.mark.parametrize("kind,backend", [("ss", "pallas"), ("ss", "xla"),
+                                          ("sd", "xla"), ("ds", "xla")])
+def test_sparse_pair_programs(chip, kind, backend):
+    """_sparse_pair_fn's programs over a 3%-dense pool (32 containers a
+    slice, values padded to 2048) against itself and a dense pool."""
+    from pilosa_tpu.parallel.mesh import compile_serve_count_sparse_pair
+
+    sparse = (chip.sliced(np.uint16, 32, 2048), chip.sliced(np.int32, 32))
+    dense = (chip.pool(cap=32),)
+    meta = (chip.repl(np.int32, S, 16), chip.repl(np.uint32, S, 16))
+    text, _ = compiled(
+        compile_serve_count_sparse_pair(chip.mesh, "and", kind,
+                                        backend=backend),
+        dense if kind == "ds" else sparse,
+        dense if kind == "sd" else sparse,
+        *meta, *meta, chip.repl(np.int32, S))
+    assert kernels_in(text) == (1 if backend == "pallas" else 0)
+
+
+@pytest.mark.parametrize("program", ["topn_4096_rows", "topn_src",
+                                     "tanimoto", "bsi_sum_planes",
+                                     "apply_writes"])
+def test_row_count_and_write_programs(chip, program):
+    """TopN over 4,096 rows (plain, with a src tree, tanimoto), the
+    BSI Sum's per-plane counts (a 64-row space), and the incremental
+    write scatter, all over the 960-slice pool."""
+    from pilosa_tpu.parallel import mesh as M
+
+    keys, w, mask = chip.sliced(np.int32, CAP), chip.pool(), \
+        chip.sliced(np.int32)
+    index = M.ShardedIndex(keys=keys, words=w)
+    if program == "topn_4096_rows":
+        fn, args = M.compile_serve_row_counts(chip.mesh, 4096), (index, mask)
+    elif program == "bsi_sum_planes":
+        fn, args = M.compile_serve_row_counts(chip.mesh, 64), (index, mask)
+    elif program == "topn_src":
+        fn = M.compile_serve_row_counts_src(chip.mesh, AND2, 2, 4096)
+        args = (keys, w, (w, w), *chip.idx_hit(2), mask)
+    elif program == "tanimoto":
+        fn = M.compile_serve_row_counts_tanimoto(chip.mesh, ["leaf", 0],
+                                                 1, 4096)
+        args = (keys, w, (w,), *chip.idx_hit(1), mask)
+    else:
+        fn = M.compile_serve_apply_writes(chip.mesh)
+        args = (index, chip.sliced(np.int32, 8), chip.sliced(np.int32, 8),
+                chip.sliced(np.uint32, 8), chip.sliced(np.uint32, 8))
+    compiled(fn, *args)
+
+
+def test_bsi_range_ladder_through_the_count_kernels(chip):
+    """A BSI comparison is lowered to the and/or/andnot tree language
+    (bsi/lower.py) and counted by the same programs: `v >= 45` over a
+    7-plane field is 13 leaves, through the uniform Pallas kernel and
+    the fused XLA program a lone Count(Range(...)) takes. (Every leaf
+    is billed a pool of its own at compile time, so on this pool a
+    ladder of 17 leaves or more is refused by any program: see
+    test_a_refusal_is_recognised_as_one.)"""
+    from pilosa_tpu.bsi import lower as L
+    from pilosa_tpu.bsi.field import FieldSchema
+    from pilosa_tpu.parallel.mesh import (
+        compile_serve_count_coarse_pallas_uniform, compile_serve_count_fused)
+    from pilosa_tpu.parallel.plan import _tree_signature
+
+    leaves: list = []
+    shape = L.to_shape(L.cond_tree(FieldSchema("v", -100, 100), ">=", 45),
+                       "f", "bsi.v", leaves)
+    n = len(leaves)
+    assert n == 13, n
+    sig = _tree_signature(shape)
+    w, mask = (chip.pool(),) * n, chip.sliced(np.int32)
+    text, _ = compiled(
+        compile_serve_count_coarse_pallas_uniform(chip.mesh, sig, n, 1),
+        w, chip.repl(np.int32, n), mask)
+    assert kernels_in(text) == 1
+    compiled(compile_serve_count_fused(chip.mesh, sig, n), w,
+             chip.repl(np.int32, n, S, 16), chip.repl(np.uint32, n, S, 16),
+             chip.repl(np.int32, S))
+
+
+# -- four chips --------------------------------------------------------------
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_four_chip_program_reduces_over_the_interconnect(chip, four,
+                                                         backend):
+    """The same serve program on the 2x2: an all-reduce joins the
+    shards' limbs, and each device is billed a quarter of the pool."""
+    from pilosa_tpu.parallel import mesh as M
+
+    def build(c):
+        mask = c.sliced(np.int32)
+        if backend == "pallas":
+            return (M.compile_serve_count_coarse_pallas_uniform(
+                c.mesh, AND2, 2, 16),
+                (c.pool(), c.pool()), c.repl(np.int32, 32), mask)
+        return (M.compile_serve_count_coarse(c.mesh, AND2, 2, 16),
+                (c.pool(), c.pool()), *c.starts_valid(32), mask)
+
+    one_text, one_mem = compiled(*build(chip))
+    text, mem = compiled(*build(four))
+    assert "all-reduce" in text
+    assert kernels_in(text) == kernels_in(one_text)
+    pool_bytes = 2 * S * CAP * 2048 * 4
+    assert abs(one_mem.argument_size_in_bytes - pool_bytes) < 0.01 * pool_bytes
+    assert abs(4 * mem.argument_size_in_bytes - pool_bytes) \
+        < 0.01 * pool_bytes
+
+
+# -- what a refusal looks like ------------------------------------------------
+
+@pytest.mark.parametrize("leaves,slices,space", [(64, 64, "vmem"),
+                                                 (24, 960, "hbm")])
+def test_a_refusal_is_recognised_as_one(chip, leaves, slices, space):
+    """Two programs the chip's compiler does refuse, to hold the serve
+    layer's classifier to the installed compiler's real words: 64
+    operand blocks overflow scoped VMEM, and 24 aliased leaves of the
+    1 GB pool overflow HBM in XLA's compile-time accounting (every
+    tree of 17 leaves or more over this pool does, in any program).
+    Both say RESOURCE_EXHAUSTED; neither is a device OOM."""
+    import jax
+
+    from pilosa_tpu.ops.kernels import coarse_count_per_slice
+    from pilosa_tpu.parallel.serve import (_is_compile_refusal,
+                                           _is_resource_exhausted)
+
+    tree = nary("and", leaves)
+    w = chip.repl(np.uint32, slices, CAP, 2048)
+    with pytest.raises(jax.errors.JaxRuntimeError) as err:
+        compiled(lambda ws, st: coarse_count_per_slice(ws, st, tree),
+                 (w,) * leaves, chip.repl(np.int32, leaves, slices))
+    msg = str(err.value)
+    assert "RESOURCE_EXHAUSTED" in msg and f"memory space {space}" in msg
+    assert _is_compile_refusal(err.value)
+    assert not _is_resource_exhausted(err.value)

@@ -7,20 +7,21 @@ answers bit-exact against the host fold (Count, TopN row counts, BSI
 Sum), drives a read/topn/bsi mix through an Executor on the
 multi-device mesh and checks the locality-tier ledger (every
 collective records tier="ici", nothing records tier="http" — there is
-no ring here to fall back to), then writes the MULTICHIP_r06-style
-artifact.
+no ring here to fall back to), then writes its JSON artifact, which
+names the backend it ran on.
 
 The ">= 4x single-device QPS" acceptance is ENFORCED only where the
 parallel capacity physically exists: a TPU backend, or a CPU host with
 at least as many cores as forced devices. On a small CPU box the N
 forced host devices time-share the same cores, so the measured speedup
-is recorded (with "enforced": false) but does not fail the run —
-mirroring the "skipped" convention of the earlier MULTICHIP rounds.
+is recorded (with "enforced": false) but does not fail the run.
 
-Standalone (re-execs itself onto an 8-device CPU mesh when no
-accelerator is present) so CI and bench.py can both shell out to it:
+Standalone for CI (forces an 8-device CPU mesh when JAX_PLATFORMS does
+not name an accelerator); bench.py calls main() in its own process,
+because a process that holds the chips must not start a child that
+needs them:
 
-    python tools/multichip_bench.py --out MULTICHIP_r06.json
+    python tools/multichip_bench.py --out MULTICHIP.json
 """
 
 import argparse
@@ -35,8 +36,11 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 def _force_devices(n: int) -> None:
     """Force an n-device CPU mesh BEFORE jax import, unless the
-    environment already provides devices (a real TPU, or an outer
-    harness that set XLA_FLAGS itself)."""
+    environment already provides devices (a real TPU, an outer
+    harness that set XLA_FLAGS itself, or a caller that has already
+    imported jax and so chosen them)."""
+    if "jax" in sys.modules:
+        return
     flags = os.environ.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" in flags:
         return
@@ -55,9 +59,9 @@ def _timed_qps(fn, iters: int) -> float:
     return iters / max(time.monotonic() - t0, 1e-9)
 
 
-def main() -> int:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--out", default="MULTICHIP_r06.json")
+    ap.add_argument("--out", default="MULTICHIP.json")
     ap.add_argument("--devices", type=int, default=8)
     ap.add_argument("--slices", type=int, default=16)
     ap.add_argument("--containers", type=int, default=8,
@@ -67,7 +71,7 @@ def main() -> int:
     ap.add_argument("--bsi-cols", type=int, default=128,
                     help="BSI values per slice")
     ap.add_argument("--min-speedup", type=float, default=4.0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     _force_devices(args.devices)
     # The scaling sections time the DENSE collective path (full-pool
@@ -80,7 +84,7 @@ def main() -> int:
     import numpy as np
 
     import jax
-    from pilosa_tpu import SLICE_WIDTH
+    from pilosa_tpu import SLICE_WIDTH, jaxrt
     from pilosa_tpu.bsi import FieldSchema
     from pilosa_tpu.core import Holder
     from pilosa_tpu.executor import Executor
@@ -89,6 +93,7 @@ def main() -> int:
     from pilosa_tpu.parallel.serve import MeshManager
     from pilosa_tpu.pql import parse_string
 
+    jaxrt.setup_compile_cache()
     n_dev = len(jax.devices())
     if n_dev < 2:
         # Single-device environment (a lone accelerator the forced-CPU

@@ -116,7 +116,7 @@ def main():
     del devs, whole
 
     # -- dtype/bit-packing lever: does u64->u32 view matter? ----------------
-    # (Transfers are bytes; this checks the relay isn't dtype-sensitive.)
+    # (Transfers are bytes; this checks the put isn't dtype-sensitive.)
     sub = words[: max(1, num_slices // 8)]
     t0 = time.perf_counter()
     d = jax.device_put(sub.view(np.uint64))
