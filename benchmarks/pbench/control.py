@@ -1,0 +1,145 @@
+#!/usr/bin/env python3
+"""The control: the plain reference put in the program's place behind the
+same HTTP entry, with one guarantee of the configuration broken. A run
+against it has to come out `correct: false`; that is what shows the comparison
+can fail.
+
+    sound             nothing broken (the harness's own check of itself)
+    stale_writes      a SetBit is acknowledged and never applied: "an
+                      acknowledged SetBit is in the next Count" is broken
+    approximate_topn  TopN from every other slice, doubled: an approximate
+                      answer where the configuration says exact
+    alter_answer      one read in seven answers one too many
+
+(`lost_wal`, the control of the durability look, is not here: it is the
+program itself with its unsafe WAL path on; see harness.PROGRAM_CONTROLS.)
+
+It holds no chip and imports nothing of the program. Started by the harness as
+a child:  control.py <mode> <reference.pickle> <port>
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import pickle
+import re
+import signal
+import sys
+import threading
+from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import numpy as np  # noqa: E402
+
+MODES = ("sound", "stale_writes", "approximate_topn", "alter_answer")
+
+_ROW = re.compile(r"rowID=(\d+)")
+_COL = re.compile(r"columnID=(\d+)")
+_N = re.compile(r"\bn=(\d+)")
+
+
+class Answers:
+    def __init__(self, mode: str, ref, approx=None):
+        self.mode, self.ref, self.approx = mode, ref, approx
+        self.mu = threading.Lock()
+        self.applied = None
+        self.reads = 0
+        if hasattr(ref, "base"):
+            self.applied = np.zeros(len(ref.keys), dtype=np.int64)
+
+    def _count_key(self, pql: str):
+        rows = [int(r) for r in _ROW.findall(pql)]
+        inner = pql[len("Count("):]
+        if inner.startswith("Bitmap("):
+            return ("R", rows[0])
+        op = inner[:inner.index("(")]
+        if len(rows) == 2:
+            a, b = rows
+            if op == "Difference":
+                return ("D", a, b)
+            return ("I" if op == "Intersect" else "U", min(a, b), max(a, b))
+        if op == "Difference":
+            return ("DA", rows[0])
+        return ("IA",) if op == "Intersect" else ("UA",)
+
+    def answer(self, pql: str):
+        if pql.startswith("SetBit("):
+            row = int(_ROW.search(pql).group(1))
+            col = int(_COL.search(pql).group(1))
+            if self.mode != "stale_writes":
+                d = self.ref.delta(row, col)
+                with self.mu:
+                    self.applied += d
+            return True
+        with self.mu:
+            self.reads += 1
+            bump = int(self.mode == "alter_answer" and self.reads % 7 == 0)
+        if pql.startswith("Count("):
+            i = self.ref.index[self._count_key(pql)]
+            with self.mu:
+                return int(self.ref.base[i] + self.applied[i]) + bump
+        if pql.startswith("TopN("):
+            rows = _ROW.findall(pql)
+            key = ("T", int(rows[0]) if rows else None,
+                   int(_N.search(pql).group(1)))
+            ref = self.approx if self.mode == "approximate_topn" else self.ref
+            pairs = ref.answer(key)
+            if bump and pairs:
+                pairs = [(pairs[0][0], pairs[0][1] + 1)] + list(pairs[1:])
+            return [{"id": r, "count": c} for r, c in pairs]
+        raise ValueError(f"the control does not speak {pql[:40]!r}")
+
+
+def main() -> int:
+    mode, ref_path, port = sys.argv[1], sys.argv[2], int(sys.argv[3])
+    assert mode in MODES, mode
+    with open(ref_path, "rb") as f:
+        refs = pickle.load(f)
+    answers = Answers(mode, refs["ref"], refs.get("approx"))
+
+    class Handler(BaseHTTPRequestHandler):
+        protocol_version = "HTTP/1.1"
+
+        def log_message(self, *a):
+            pass
+
+        def _send(self, code: int, obj) -> None:
+            body = json.dumps(obj).encode()
+            self.send_response(code)
+            self.send_header("Content-Type", "application/json")
+            self.send_header("Content-Length", str(len(body)))
+            self.end_headers()
+            self.wfile.write(body)
+
+        def do_GET(self):
+            if self.path.startswith("/debug/vars"):
+                self._send(200, {"control": mode})
+            elif self.path.startswith("/metrics"):
+                self.send_response(200)
+                self.send_header("Content-Length", "0")
+                self.end_headers()
+            else:
+                self._send(200, {"status": "control"})
+
+        def do_POST(self):
+            n = int(self.headers.get("Content-Length") or 0)
+            pql = self.rfile.read(n).decode()
+            try:
+                self._send(200, {"results": [answers.answer(pql)]})
+            except Exception as e:  # noqa: BLE001 - reported to the client
+                self._send(400, {"error": repr(e)})
+
+    ThreadingHTTPServer.request_queue_size = 256
+    httpd = ThreadingHTTPServer(("127.0.0.1", port), Handler)
+    httpd.daemon_threads = True
+    signal.signal(signal.SIGTERM, lambda *a: threading.Thread(
+        target=httpd.shutdown, daemon=True).start())
+    httpd.serve_forever()
+    httpd.server_close()
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
