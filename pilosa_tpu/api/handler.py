@@ -1498,9 +1498,14 @@ class Handler:
         return fams
 
     def _get_expvar(self, pv, params, headers, body) -> Response:
+        # Read together and first: over a window, d(cpu) / d(uptime) is
+        # the cores' worth of CPU this process burned. About 1.0 under
+        # load says the handler threads share one GIL's worth of
+        # Python; well under it says they were blocked (locks, sleeps).
+        cpu_s, up_s = time.process_time(), time.monotonic()
         snap = self.stats.snapshot() if hasattr(self.stats, "snapshot") else {}
-        snap["uptime_seconds"] = round(
-            time.monotonic() - self._start_time, 3)
+        snap["uptime_seconds"] = round(up_s - self._start_time, 3)
+        snap["process_cpu_seconds"] = round(cpu_s, 3)
         snap["version"] = self.version
         # Mesh serving-layer counters (stage/incremental/count/topn/
         # fallback + cumulative timings) — SURVEY.md §5 observability.
@@ -2410,6 +2415,9 @@ class Handler:
                 q = parse_string_cached(query)
             t0 = time.monotonic()
             results = self.executor.execute(index, q, slices or None, opt)
+            # respond: from the executor's return to the profile's
+            # snapshot below — stats tagging and the JSON of the results.
+            rph = obs.profile.phase("respond").start()
             # Per-call-name query stats, visible at /debug/vars
             # (observability parity: reference tag-scoped StatsClient,
             # stats.go:33-54). Remote fan-out legs are skipped so a
@@ -2439,6 +2447,7 @@ class Handler:
                 cs = resp.column_attr_sets.add()
                 cs.id = cid
                 cs.attrs.extend(attrs_to_proto(attrs))
+            rph.stop()
             return _proto_resp(resp)
 
         out = {"results": [_result_to_json(r) for r in results]}
@@ -2450,6 +2459,7 @@ class Handler:
             # happened, so clients don't have to infer it from absence.
             out["partial"] = bool(opt.missing_slices)
             out["missing_slices"] = sorted(set(opt.missing_slices))
+        rph.stop()
         if profile_section:
             prof = obs.profile.current()
             if prof is not None:

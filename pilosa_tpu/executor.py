@@ -816,6 +816,9 @@ class Executor:
         with gph:
             result = self._map_reduce(
                 index, slices, c, opt, map_fn, reduce_fn, batch_fn=batch_fn)
+        # The books of a computed Count: memo and result-cache puts,
+        # route metrics, SLO and cost taps.
+        with obs.profile.phase("account"):
             n = int(result or 0)
             if qkey is not None:
                 # Stored against the PRE-compute epoch (and PRE-compute
@@ -823,25 +826,26 @@ class Executor:
                 # them, so the entry can never validate — stale results
                 # invalidate, they don't serve.
                 self._host_cache.query_put(qkey, qepoch, n, qsepoch, qtoken)
-        cache_tag = None
-        if rc_verify is not None:
-            # Shadow verify: the hit we withheld vs the fresh compute.
-            # A mismatch is an epoch-freshness bug — count it where
-            # the PR-10 machinery already alerts (pilosa_shadow_
-            # mismatch_total) and quarantine the entry.
-            cache_tag = "verify"
-            SHADOW_STATS.inc("checks:result-cache")
-            if int(rc_verify) != n:
-                SHADOW_STATS.inc("mismatch:result-cache")
-                rc.invalidate(rc_key)
-        elif rc_key is not None:
-            cache_tag = "miss"
-            rc.put(rc_key, rc_epoch, n)
-        self._record_route(route, t0,
-                           tier=self._query_tier(opt, route == "mesh"),
-                           call=c,
-                           staged_bytes=max(0, self._h2d_bytes() - h2d0),
-                           cache=cache_tag)
+            cache_tag = None
+            if rc_verify is not None:
+                # Shadow verify: the hit we withheld vs the fresh
+                # compute. A mismatch is an epoch-freshness bug — count
+                # it where the PR-10 machinery already alerts
+                # (pilosa_shadow_mismatch_total) and quarantine the
+                # entry.
+                cache_tag = "verify"
+                SHADOW_STATS.inc("checks:result-cache")
+                if int(rc_verify) != n:
+                    SHADOW_STATS.inc("mismatch:result-cache")
+                    rc.invalidate(rc_key)
+            elif rc_key is not None:
+                cache_tag = "miss"
+                rc.put(rc_key, rc_epoch, n)
+            self._record_route(route, t0,
+                               tier=self._query_tier(opt, route == "mesh"),
+                               call=c,
+                               staged_bytes=max(0, self._h2d_bytes() - h2d0),
+                               cache=cache_tag)
         return n
 
     # Above this fan-out, gathering (fragment, generation) pairs for
@@ -1886,14 +1890,17 @@ class Executor:
 
         def batch_fn(batch_slices):
             try:
-                n = mgr.count(index, shape, leaves, batch_slices,
-                              self._batch_num_slices(index, batch_slices))
+                with obs.profile.phase("mesh_prepare"):
+                    num = self._batch_num_slices(index, batch_slices)
+                n = mgr.count(index, shape, leaves, batch_slices, num)
             except Exception:  # noqa: BLE001 — any device failure → host path
                 self._device_failed("count")
                 return None
             if n is not None and self._shadow_sampled():
-                n = self._shadow_check_count(
-                    index, shape, leaves, batch_slices, n, "mesh")
+                # residual: the check's host fold has its own phase.
+                with obs.profile.residual("account"):
+                    n = self._shadow_check_count(
+                        index, shape, leaves, batch_slices, n, "mesh")
             return n
 
         return batch_fn
@@ -2814,16 +2821,23 @@ class Executor:
 
     def _mapper(self, nodes, index: str, slices: Sequence[int], c: Call,
                 opt: ExecOptions, map_fn, reduce_fn, batch_fn=None):
-        m = self._slices_by_node(nodes, index, slices, opt)
+        with obs.profile.phase("route_slices"):
+            m = self._slices_by_node(nodes, index, slices, opt)
 
         futures = {}
+        local_fut = hand = None
         for node, node_slices in m.items():
             # wrap_ctx: pool workers inherit the active trace span (a
             # fresh contextvars copy per submit), so the gather/fan-out
             # spans attach under this query, not nowhere.
             if node.host == self.host:
-                fut = self._pool.submit(
-                    obs.wrap_ctx(self._mapper_local), node_slices,
+                # pool_handoff: the two thread switches of the local
+                # leg. Entered here and left by the worker as it takes
+                # the leg up; entered again by the worker as it returns
+                # and left below, where wait() wakes.
+                hand = [obs.profile.phase("pool_handoff").start()]
+                fut = local_fut = self._pool.submit(
+                    obs.wrap_ctx(self._local_leg), hand, node_slices,
                     map_fn, reduce_fn, batch_fn, opt.deadline)
             elif not opt.remote:
                 # This group actually pays a cross-node HTTP leg — the
@@ -2850,6 +2864,8 @@ class Executor:
                                  return_when=FIRST_COMPLETED)
             for fut in done:
                 node, node_slices = futures[fut]
+                if fut is local_fut:
+                    hand[0].stop()
                 try:
                     v = fut.result()
                 except Exception as err:
@@ -2888,6 +2904,16 @@ class Executor:
                          slices: Sequence[int], opt: ExecOptions):
         results = self._exec_remote(node, index, Query(calls=[c]), slices, opt)
         return results[0] if results else None
+
+    def _local_leg(self, hand: list, *args):
+        """_mapper_local as _mapper's pool task: closes the hand-off
+        phase _mapper opened at submit, and opens the one _mapper
+        closes when its wait() wakes."""
+        hand[0].stop()
+        try:
+            return self._mapper_local(*args)
+        finally:
+            hand[0] = obs.profile.phase("pool_handoff").start()
 
     def _mapper_local(self, slices: Sequence[int], map_fn, reduce_fn,
                       batch_fn=None, deadline: Optional[float] = None):

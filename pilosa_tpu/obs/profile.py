@@ -11,8 +11,9 @@ peak (a host-bracketed figure; a kernel's roofline share needs a
 device trace).
 
 Same cardinal rule as the tracer: near-free when nobody is looking.
-`phase("x")` with no active profile is one ContextVar read returning a
-shared no-op; byte counters early-return. Device phases are only real
+`phase("x")` with no active profile (and the device-trace gate off) is
+one ContextVar read and one module-global test returning a shared
+no-op; byte counters early-return. Device phases are only real
 when a profile is active — callers gate their `block_until_ready`
 bracketing on `current() is not None`, so the async-dispatch fast path
 is byte-identical when profiling is off (bench.py guards < 2%).
@@ -23,6 +24,19 @@ all threads touching the profile) contributes wall time. Nested or
 concurrent same-name phases — serve._stage wrapping
 mesh.build_sharded_index, or parallel slice workers overlapping —
 therefore never double-count.
+
+Two sinks, one call per seam. With `PILOSA_TPU_JAX_PROFILE` on (the
+gate `trace.jax_scope` has), every phase of EVERY query, profiled or
+not, is also a `jax.profiler.TraceAnnotation("pilosa:<phase>")`, so an
+idle gap of a device trace falls under a named host span on the
+profiler's own clock.
+
+Phases of one query are disjoint in time, so total - sum(phases) is
+the time no seam covers. Where a phase is "the rest" of a region whose
+inner steps have phases of their own (`mesh_prepare` around the locks,
+the refresh and the launch), it is opened with `residual()`: any phase
+entered below it in the same context pauses it, and leaving that phase
+resumes it.
 """
 
 from __future__ import annotations
@@ -32,13 +46,15 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from . import trace as _trace
 from .metrics import Histogram
 
 # The canonical phase set, in pipeline order. to_dict() emits phases in
 # this order (then any ad-hoc extras) so profiles diff cleanly.
-PHASES = ("sched_wait", "parse", "plan", "stage_h2d", "compile",
-          "device_exec", "readback_d2h", "host_fold", "wal_commit",
-          "fanout_remote")
+PHASES = ("sched_wait", "parse", "plan", "route_slices", "pool_handoff",
+          "mesh_prepare", "mesh_lock_wait", "view_refresh", "stage_h2d",
+          "compile", "device_exec", "readback_d2h", "host_fold",
+          "wal_commit", "fanout_remote", "account", "respond")
 
 BYTE_COUNTERS = ("bytes_staged", "bytes_touched_hbm", "bytes_read_back")
 
@@ -56,9 +72,9 @@ monotonic_ns = time.monotonic_ns
 
 class _NoopPhase:
     """Shared do-nothing phase timer returned when no profile is
-    active — the identity of this singleton is itself asserted by
-    tests as proof the fast path pays one ContextVar read and nothing
-    else."""
+    active and the device-trace gate is off — the identity of this
+    singleton is itself asserted by tests as proof the fast path pays
+    one ContextVar read, one global test and nothing else."""
 
     __slots__ = ()
 
@@ -77,33 +93,88 @@ class _NoopPhase:
 
 NOOP_PHASE = _NoopPhase()
 
+# The residual phase running in this context, if any: the one a phase
+# entered here pauses (see residual()). Per context, so per thread of
+# a query; never hold one open across a pool submit.
+_RESIDUAL: "contextvars.ContextVar[Optional[_Phase]]" = \
+    contextvars.ContextVar("pilosa_tpu_residual_phase", default=None)
+
+# jax.profiler.TraceAnnotation, imported when the gate first turns a
+# phase into one (tests put a recording stand-in here).
+_TraceAnnotation = None
+
+
+def _annotation(name: str):
+    global _TraceAnnotation
+    ta = _TraceAnnotation
+    if ta is None:
+        from jax.profiler import TraceAnnotation as ta
+        _TraceAnnotation = ta
+    ann = ta("pilosa:" + name)
+    ann.__enter__()
+    return ann
+
 
 class _Phase:
-    """Context manager for one enter/exit of a named phase."""
+    """One enter/exit of a named phase, into the sinks that are on:
+    the profile (`prof`; None for a query nobody profiles) and, with
+    `annotate`, a TraceAnnotation of the same extent. The annotation
+    is made when the phase starts (it times from its construction) and
+    records on the thread that stops it, so a phase may be entered on
+    one thread and left on another."""
 
-    __slots__ = ("_prof", "_name")
+    __slots__ = ("_prof", "_name", "_annotate", "_residual", "_ann",
+                 "_outer")
 
-    def __init__(self, prof: "QueryProfile", name: str):
+    def __init__(self, prof: "Optional[QueryProfile]", name: str,
+                 annotate: bool = False, residual: bool = False):
         self._prof = prof
         self._name = name
+        self._annotate = annotate
+        self._residual = residual
+        self._ann = None
+        self._outer: Optional[_Phase] = None
 
-    def __enter__(self):
-        self._prof._enter(self._name)
-        return self
+    def _begin(self) -> None:
+        if self._prof is not None:
+            self._prof._enter(self._name)
+        if self._annotate:
+            self._ann = _annotation(self._name)
 
-    def __exit__(self, *exc):
-        self._prof._exit(self._name)
-        return None
+    def _end(self) -> None:
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(None, None, None)
+        if self._prof is not None:
+            self._prof._exit(self._name)
 
     # Explicit form for regions with early returns (mirrors Span
-    # .finish()). stop() is idempotent-safe only pairwise with start().
+    # .finish()). A second stop() of a phase that is not nested in
+    # itself is a no-op.
     def start(self):
-        self._prof._enter(self._name)
+        outer = self._outer = _RESIDUAL.get()
+        if outer is not None:
+            outer._end()  # paused until stop()
+        if outer is not None or self._residual:
+            _RESIDUAL.set(self if self._residual else None)
+        self._begin()
         return self
 
     def stop(self):
-        self._prof._exit(self._name)
+        self._end()
+        outer = self._outer
+        if outer is not None or self._residual:
+            _RESIDUAL.set(outer)
+        if outer is not None:
+            self._outer = None
+            outer._begin()
         return None
+
+    __enter__ = start
+
+    def __exit__(self, *exc):
+        return self.stop()
 
 
 class QueryProfile:
@@ -161,7 +232,7 @@ class QueryProfile:
                                         + now - ent[1])
 
     def phase(self, name: str) -> _Phase:
-        return _Phase(self, name)
+        return _Phase(self, name, _trace.jax_profile_on())
 
     def add_phase_ns(self, name: str, ns: int) -> None:
         """Credit already-measured wall time to a phase (for callers
@@ -302,14 +373,26 @@ def deactivate(token) -> None:
     CURRENT_PROFILE.reset(token)
 
 
-def phase(name: str):
-    """Phase timer on the ambient profile, or the shared no-op when
-    none is active. The inactive case is the fast path: one ContextVar
-    read, no allocation."""
+def phase(name: str, residual: bool = False):
+    """Phase timer into the ambient profile and, with the device-trace
+    gate on, into a `pilosa:<name>` TraceAnnotation; the shared no-op
+    when neither is looking. That case is the fast path: one
+    ContextVar read and one module-global test, no allocation."""
     prof = CURRENT_PROFILE.get()
-    if prof is None:
+    on = _trace._JAX_PROFILE
+    if on is None:
+        on = _trace.jax_profile_on()
+    if prof is None and not on:
         return NOOP_PHASE
-    return _Phase(prof, name)
+    return _Phase(prof, name, on, residual)
+
+
+def residual(name: str):
+    """phase() for "the rest" of a region: any phase entered below it
+    in this context pauses it until that phase is left, so it is
+    credited only with the time no inner phase covers and the phases
+    of a query stay disjoint."""
+    return phase(name, True)
 
 
 def add_bytes(counter: str, n: int) -> None:

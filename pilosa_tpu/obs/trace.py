@@ -300,22 +300,30 @@ def wrap_ctx(fn):
 _JAX_PROFILE: Optional[bool] = None
 
 
-def jax_scope(name: str):
-    """jax.profiler named scope around kernel dispatch, gated behind
-    PILOSA_TPU_JAX_PROFILE so device traces line up with span names.
-    The env gate resolves once per process; off (the default) returns
-    a nullcontext and never imports jax."""
+def jax_profile_on() -> bool:
+    """The device-trace gate, PILOSA_TPU_JAX_PROFILE: resolved once
+    per process; off (the default) never imports jax. profile.phase()
+    shares it, so one switch turns on the launch annotations here and
+    the `pilosa:<phase>` annotations there."""
     global _JAX_PROFILE
     on = _JAX_PROFILE
     if on is None:
         on = os.environ.get("PILOSA_TPU_JAX_PROFILE", "").strip().lower() \
             in ("1", "on", "true", "yes")
+        if on:
+            try:
+                from jax.profiler import TraceAnnotation  # noqa: F401
+            except Exception:
+                on = False
         _JAX_PROFILE = on
-    if not on:
+    return on
+
+
+def jax_scope(name: str):
+    """jax.profiler named scope around kernel dispatch, gated behind
+    PILOSA_TPU_JAX_PROFILE so device traces line up with span names."""
+    if not jax_profile_on():
         return nullcontext()
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:
-        _JAX_PROFILE = False
-        return nullcontext()
+    from jax.profiler import TraceAnnotation
+
     return TraceAnnotation(name)

@@ -162,10 +162,10 @@ def _fold_chunk_fn():
     global _FOLD_CHUNK
     if _FOLD_CHUNK is None:
         donate = (0,) if jax.default_backend() != "cpu" else ()
-        _FOLD_CHUNK = jax.jit(
-            lambda buf, piece, off: lax.dynamic_update_slice(
-                buf, piece, (off, 0, 0)),
-            donate_argnums=donate)
+        def fold_chunk(buf, piece, off):
+            return lax.dynamic_update_slice(buf, piece, (off, 0, 0))
+
+        _FOLD_CHUNK = jax.jit(fold_chunk, donate_argnums=donate)
     return _FOLD_CHUNK
 
 
@@ -692,10 +692,10 @@ def compile_serve_count_sparse_pair(mesh: Mesh, op: str, kind: str,
     )
 
     @jax.jit
-    def run(pool_a, pool_b, idx_a, hit_a, idx_b, hit_b, mask):
+    def count_sparse_pair(pool_a, pool_b, idx_a, hit_a, idx_b, hit_b, mask):
         return fn(pool_a, pool_b, idx_a, hit_a, idx_b, hit_b, mask)
 
-    return run
+    return count_sparse_pair
 
 
 def _local_pools(keys, words):
@@ -864,10 +864,10 @@ def compile_mesh_count(mesh: Mesh, tree_shape, num_leaves: int,
     )
 
     @jax.jit
-    def run(index: ShardedIndex, leaf_ids):
+    def mesh_count(index: ShardedIndex, leaf_ids):
         return fn(index.keys, index.words, leaf_ids)
 
-    return run
+    return mesh_count
 
 
 # -- exact TopN over the mesh ------------------------------------------------
@@ -896,10 +896,10 @@ def compile_mesh_topn(mesh: Mesh, num_rows: int, k: int):
     )
 
     @jax.jit
-    def run(index: ShardedIndex):
+    def mesh_topn(index: ShardedIndex):
         return fn(index.keys, index.words)
 
-    return run
+    return mesh_topn
 
 
 # -- device-side write application -------------------------------------------
@@ -971,11 +971,11 @@ def compile_mesh_apply_writes(mesh: Mesh):
     )
 
     @jax.jit
-    def run(index: ShardedIndex, slot, word, mask):
+    def mesh_apply_writes(index: ShardedIndex, slot, word, mask):
         keys, words = fn(index.keys, index.words, slot, word, mask)
         return ShardedIndex(keys=keys, words=words)
 
-    return run
+    return mesh_apply_writes
 
 
 def compile_mesh_step(mesh: Mesh, tree_shape, num_leaves: int,
@@ -1014,12 +1014,12 @@ def compile_mesh_step(mesh: Mesh, tree_shape, num_leaves: int,
     )
 
     @jax.jit
-    def run(index: ShardedIndex, slot, word, mask, leaf_ids):
+    def mesh_step(index: ShardedIndex, slot, word, mask, leaf_ids):
         keys, words, count, top_vals, top_ids = fn(
             index.keys, index.words, slot, word, mask, leaf_ids)
         return ShardedIndex(keys=keys, words=words), count, top_vals, top_ids
 
-    return run
+    return mesh_step
 
 
 # -- serving-path kernels ----------------------------------------------------
@@ -1087,12 +1087,13 @@ def _gather_leaf_blocks(words_t, idx_t, hit_t, i):
     kernel folds its tree over — the gather indexing and absent-row
     semantics cannot drift between the count, batch, src, and tanimoto
     programs."""
-    w = words_t[i]
-    cap = w.shape[1]
-    wflat = w.reshape(w.shape[0] * cap, w.shape[2])
-    base = (jnp.arange(w.shape[0], dtype=jnp.int32) * cap)[:, None]
-    blk = wflat[(idx_t[i] + base).reshape(-1)]
-    return blk * hit_t[i].reshape(-1)[:, None]
+    with jax.named_scope("gather_leaves"):
+        w = words_t[i]
+        cap = w.shape[1]
+        wflat = w.reshape(w.shape[0] * cap, w.shape[2])
+        base = (jnp.arange(w.shape[0], dtype=jnp.int32) * cap)[:, None]
+        blk = wflat[(idx_t[i] + base).reshape(-1)]
+        return blk * hit_t[i].reshape(-1)[:, None]
 
 
 def coarse_row_starts(keys_host: np.ndarray, dense_id: int):
@@ -1148,15 +1149,16 @@ def _gather_leaf_rows(words_t, start_t, valid_t, i):
     whole-row gather from the pool viewed as (S, cap/16, 16*W), zeroed
     where the slice holds no part of the row (valid == 0). The coarse
     counterpart of _gather_leaf_blocks."""
-    w = words_t[i]
-    s_l, cap = w.shape[0], w.shape[1]
-    wr = w.reshape(s_l, cap // ROW_SPAN, ROW_SPAN * w.shape[2])
+    with jax.named_scope("gather_leaves"):
+        w = words_t[i]
+        s_l, cap = w.shape[0], w.shape[1]
+        wr = w.reshape(s_l, cap // ROW_SPAN, ROW_SPAN * w.shape[2])
 
-    def one(wrow, st):
-        return wrow[st]
+        def one(wrow, st):
+            return wrow[st]
 
-    g = jax.vmap(one)(wr, start_t[i])
-    return g * valid_t[i][:, None]
+        g = jax.vmap(one)(wr, start_t[i])
+        return g * valid_t[i][:, None]
 
 
 def _limb_psum(per_bs):
@@ -1197,8 +1199,10 @@ def compile_serve_count_coarse(mesh: Mesh, tree_shape, num_leaves: int,
                     words_t, start_flat[b * num_leaves:(b + 1) * num_leaves],
                     valid_flat[b * num_leaves:(b + 1) * num_leaves], i)
 
-            pc = lax.population_count(fold_tree(tree, leaf))  # (S_l, 16W)
-            return pc.sum(axis=1, dtype=jnp.uint32)
+            blk = fold_tree(tree, leaf)                       # (S_l, 16W)
+            with jax.named_scope("popcount"):
+                return lax.population_count(blk).sum(
+                    axis=1, dtype=jnp.uint32)
 
         per_slice = jnp.stack([one(b) for b in range(batch)])  # (B, S_l)
         per_slice = jnp.where(mask[None, :] != 0, per_slice, jnp.uint32(0))
@@ -1220,10 +1224,10 @@ def compile_serve_count_coarse(mesh: Mesh, tree_shape, num_leaves: int,
     )
 
     @jax.jit
-    def run(words_t, start_flat, valid_flat, mask):
+    def count_coarse(words_t, start_flat, valid_flat, mask):
         return fn(words_t, start_flat, valid_flat, mask)
 
-    return run
+    return count_coarse
 
 
 def compile_serve_count_coarse_pallas(mesh: Mesh, tree_shape,
@@ -1278,10 +1282,10 @@ def compile_serve_count_coarse_pallas(mesh: Mesh, tree_shape,
     )
 
     @jax.jit
-    def run(words_t, start_flat, valid_flat, mask):
+    def count_coarse_pallas(words_t, start_flat, valid_flat, mask):
         return fn(words_t, start_flat, valid_flat, mask)
 
-    return run
+    return count_coarse_pallas
 
 
 def compile_serve_count_coarse_pallas_uniform(mesh: Mesh, tree_shape,
@@ -1330,10 +1334,10 @@ def compile_serve_count_coarse_pallas_uniform(mesh: Mesh, tree_shape,
     )
 
     @jax.jit
-    def run(words_t, starts, mask):
+    def count_coarse_pallas_uniform(words_t, starts, mask):
         return fn(tuple(words_t), starts, mask)
 
-    return run
+    return count_coarse_pallas_uniform
 
 
 def compile_serve_count_batch_shared(mesh: Mesh, tree_shape,
@@ -1388,16 +1392,19 @@ def compile_serve_count_batch_shared(mesh: Mesh, tree_shape,
             # The barrier forces the U blocks to
             # materialize once (U * 128 KB, VMEM-resident) before the
             # B folds consume them.
-            blocks = list(lax.optimization_barrier(tuple(
-                wr_t[u][s, start_st[u, s]]
-                * valid_st[u, s].astype(jnp.uint32)
-                for u in range(num_unique))))
+            with jax.named_scope("gather_leaves"):
+                blocks = list(lax.optimization_barrier(tuple(
+                    wr_t[u][s, start_st[u, s]]
+                    * valid_st[u, s].astype(jnp.uint32)
+                    for u in range(num_unique))))
 
             live = (mask[s] != 0).astype(jnp.uint32)
             outs = []
             for b in range(batch):
                 blk = fold_tree(tree, lambda i: blocks[leaf_map[b][i]])
-                pc = lax.population_count(blk).sum(dtype=jnp.uint32) * live
+                with jax.named_scope("popcount"):
+                    pc = lax.population_count(blk).sum(
+                        dtype=jnp.uint32) * live
                 outs.append(pc)
             per_slice = jnp.stack(outs)          # (B,) uint32
             lo = (per_slice & jnp.uint32(0xFFFF)).astype(jnp.int32)
@@ -1427,10 +1434,10 @@ def compile_serve_count_batch_shared(mesh: Mesh, tree_shape,
     )
 
     @jax.jit
-    def run(words_t, start_t, valid_t, mask):
+    def count_batch_shared(words_t, start_t, valid_t, mask):
         return fn(words_t, start_t, valid_t, mask)
 
-    return run
+    return count_batch_shared
 
 
 def compile_serve_count_coarse_pallas_batch(mesh: Mesh, tree_shape,
@@ -1479,11 +1486,11 @@ def compile_serve_count_coarse_pallas_batch(mesh: Mesh, tree_shape,
     )
 
     @jax.jit
-    def run(words_t, start_flat, valid_flat, mask):
+    def count_coarse_pallas_batch(words_t, start_flat, valid_flat, mask):
         return fn(tuple(words_t), tuple(start_flat), tuple(valid_flat),
                   mask)
 
-    return run
+    return count_coarse_pallas_batch
 
 
 def compile_serve_count_batch_shared_pallas(mesh: Mesh, tree_shape,
@@ -1530,10 +1537,10 @@ def compile_serve_count_batch_shared_pallas(mesh: Mesh, tree_shape,
     )
 
     @jax.jit
-    def run(words_t, start_t, valid_t, mask):
+    def count_batch_shared_pallas(words_t, start_t, valid_t, mask):
         return fn(words_t, start_t, valid_t, mask)
 
-    return run
+    return count_batch_shared_pallas
 
 
 def compile_serve_count_batch_shared_pallas_uniform(
@@ -1573,10 +1580,10 @@ def compile_serve_count_batch_shared_pallas_uniform(
     )
 
     @jax.jit
-    def run(words_t, starts, mask):
+    def count_batch_shared_pallas_uniform(words_t, starts, mask):
         return fn(tuple(words_t), starts, mask)
 
-    return run
+    return count_batch_shared_pallas_uniform
 
 
 def _segment_rows(pc, dense, num_rows):
@@ -1629,9 +1636,11 @@ def compile_serve_count(mesh: Mesh, tree_shape, num_leaves: int):
         def leaf(i):
             return _gather_leaf_blocks(words_t, idx_t, hit_t, i)
 
-        pc = lax.population_count(fold_tree(tree, leaf))  # (S*16, 2048)
-        per_slice = pc.sum(axis=1, dtype=jnp.uint32).reshape(
-            s_l, ROW_SPAN).sum(axis=1, dtype=jnp.uint32)
+        blk = fold_tree(tree, leaf)                       # (S*16, 2048)
+        with jax.named_scope("popcount"):
+            pc = lax.population_count(blk)
+            per_slice = pc.sum(axis=1, dtype=jnp.uint32).reshape(
+                s_l, ROW_SPAN).sum(axis=1, dtype=jnp.uint32)
         per_slice = jnp.where(mask != 0, per_slice, jnp.uint32(0))
         lo = lax.psum((per_slice & jnp.uint32(0xFFFF)).astype(jnp.int32).sum(),
                       SLICE_AXIS)
@@ -1649,10 +1658,10 @@ def compile_serve_count(mesh: Mesh, tree_shape, num_leaves: int):
     )
 
     @jax.jit
-    def run(words_t, idx_t, hit_t, mask):
+    def count(words_t, idx_t, hit_t, mask):
         return fn(words_t, idx_t, hit_t, mask)
 
-    return run
+    return count
 
 
 def compile_serve_count_fused(mesh: Mesh, tree_shape, num_leaves: int):
@@ -1695,9 +1704,11 @@ def compile_serve_count_fused(mesh: Mesh, tree_shape, num_leaves: int):
         def leaf(i):
             return _gather_leaf_blocks(words_t, idx_l, hit_l, i)
 
-        pc = lax.population_count(fold_tree(tree, leaf))
-        per_slice = pc.sum(axis=1, dtype=jnp.uint32).reshape(
-            s_l, ROW_SPAN).sum(axis=1, dtype=jnp.uint32)
+        blk = fold_tree(tree, leaf)
+        with jax.named_scope("popcount"):
+            pc = lax.population_count(blk)
+            per_slice = pc.sum(axis=1, dtype=jnp.uint32).reshape(
+                s_l, ROW_SPAN).sum(axis=1, dtype=jnp.uint32)
         per_slice = jnp.where(mask_l != 0, per_slice, jnp.uint32(0))
         lo = lax.psum((per_slice & jnp.uint32(0xFFFF)).astype(jnp.int32).sum(),
                       SLICE_AXIS)
@@ -1712,10 +1723,10 @@ def compile_serve_count_fused(mesh: Mesh, tree_shape, num_leaves: int):
     )
 
     @jax.jit
-    def run(words_t, idx_all, hit_all, mask):
+    def count_fused(words_t, idx_all, hit_all, mask):
         return fn(words_t, idx_all, hit_all, mask)
 
-    return run
+    return count_fused
 
 
 def compile_serve_count_batch(mesh: Mesh, tree_shape, num_leaves: int,
@@ -1744,9 +1755,11 @@ def compile_serve_count_batch(mesh: Mesh, tree_shape, num_leaves: int,
                     words_t, idx_flat[b * num_leaves:(b + 1) * num_leaves],
                     hit_flat[b * num_leaves:(b + 1) * num_leaves], i)
 
-            pc = lax.population_count(fold_tree(tree, leaf))
-            return pc.sum(axis=1, dtype=jnp.uint32).reshape(
-                s_l, ROW_SPAN).sum(axis=1, dtype=jnp.uint32)
+            blk = fold_tree(tree, leaf)
+            with jax.named_scope("popcount"):
+                return lax.population_count(blk).sum(
+                    axis=1, dtype=jnp.uint32).reshape(
+                        s_l, ROW_SPAN).sum(axis=1, dtype=jnp.uint32)
 
         per_slice = jnp.stack([one(b) for b in range(batch)])  # (B, S_l)
         per_slice = jnp.where(mask[None, :] != 0, per_slice, jnp.uint32(0))
@@ -1768,10 +1781,10 @@ def compile_serve_count_batch(mesh: Mesh, tree_shape, num_leaves: int,
     )
 
     @jax.jit
-    def run(words_t, idx_flat, hit_flat, mask):
+    def count_batch(words_t, idx_flat, hit_flat, mask):
         return fn(words_t, idx_flat, hit_flat, mask)
 
-    return run
+    return count_batch
 
 
 def compile_serve_row_counts_src(mesh: Mesh, tree_shape, num_leaves: int,
@@ -1824,10 +1837,10 @@ def compile_serve_row_counts_src(mesh: Mesh, tree_shape, num_leaves: int,
     )
 
     @jax.jit
-    def run(keys, words, src_words_t, src_idx_t, src_hit_t, mask):
+    def row_counts_src(keys, words, src_words_t, src_idx_t, src_hit_t, mask):
         return fn(keys, words, src_words_t, src_idx_t, src_hit_t, mask)
 
-    return run
+    return row_counts_src
 
 
 def compile_serve_row_counts_tanimoto(mesh: Mesh, tree_shape,
@@ -1901,10 +1914,10 @@ def compile_serve_row_counts_tanimoto(mesh: Mesh, tree_shape,
     )
 
     @jax.jit
-    def run(keys, words, src_words_t, src_idx_t, src_hit_t, mask):
+    def row_counts_tanimoto(keys, words, src_words_t, src_idx_t, src_hit_t, mask):
         return fn(keys, words, src_words_t, src_idx_t, src_hit_t, mask)
 
-    return run
+    return row_counts_tanimoto
 
 
 def compile_serve_row_counts(mesh: Mesh, num_rows: int):
@@ -1935,10 +1948,10 @@ def compile_serve_row_counts(mesh: Mesh, num_rows: int):
     )
 
     @jax.jit
-    def run(index: ShardedIndex, mask):
+    def row_counts(index: ShardedIndex, mask):
         return fn(index.keys, index.words, mask)
 
-    return run
+    return row_counts
 
 
 def pack_mutation_batches(per_slice, num_slices: int, capacity: int):
@@ -1988,12 +2001,12 @@ def compile_serve_apply_writes(mesh: Mesh):
     )
 
     @jax.jit
-    def run(index: ShardedIndex, slot, word, set_mask, clear_mask):
+    def apply_writes(index: ShardedIndex, slot, word, set_mask, clear_mask):
         keys, words = fn(index.keys, index.words, slot, word,
                          set_mask, clear_mask)
         return ShardedIndex(keys=keys, words=words)
 
-    return run
+    return apply_writes
 
 
 def default_mesh(n_devices: Optional[int] = None) -> Mesh:

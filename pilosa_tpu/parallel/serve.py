@@ -324,6 +324,23 @@ def _reraise_shared(what: str, err: BaseException):
     raise RuntimeError(f"{what} failed: {err}") from err
 
 
+class _held:
+    """`with _held(lock):` is `with lock:`, with the time the caller is
+    blocked in acquire under its profile phase `mesh_lock_wait`."""
+
+    __slots__ = ("_lock",)
+
+    def __init__(self, lock):
+        self._lock = lock
+
+    def __enter__(self):
+        with profile.phase("mesh_lock_wait"):
+            self._lock.acquire()
+
+    def __exit__(self, *exc):
+        self._lock.release()
+
+
 class _CountRequest:
     """One pending count in the dynamic batch queue. coarse_t holds a
     per-leaf (starts, valid) device pair when the leaf is
@@ -1295,8 +1312,10 @@ class MeshManager:
             return None
 
     def _refresh_locked(self, key, num_slices: int) -> Optional[StagedView]:
-        index, frame, view = key
-        with self._mu:
+        # Profile phases: the wait for _mu, then `view_refresh` for the
+        # epoch check and, after a write, the walk and the patch
+        # (residual: a restage below is stage_h2d's).
+        with _held(self._mu), profile.residual("view_refresh"):
             # Epoch pair read UNDER _mu, before any staleness
             # inspection: a write that lands mid-walk bumps the pair
             # past `ep`, so stamping `ep` after the walk can never mark
@@ -1331,170 +1350,188 @@ class MeshManager:
                 fresh = self._stage(key, num_slices)
                 fresh.validated_epoch = ep
                 return fresh
+            # What the first Count after a write pays, and no profiled
+            # request of the benchmark crosses: counted.
+            t0 = time.monotonic()
+            try:
+                return self._refresh_walk(key, sv, ep, num_slices)
+            finally:
+                self.stats.inc("refresh_walks")
+                self.stats.inc("refresh_walk_us",
+                               int((time.monotonic() - t0) * 1e6))
 
-            def restage():
-                f = self._stage(key, num_slices)
-                f.validated_epoch = ep
-                return f
+    def _refresh_walk(self, key, sv: StagedView, ep,
+                      num_slices: int) -> StagedView:
+        """The slow side of _refresh_locked (call under _mu): the
+        process has mutated since `sv` was validated, so walk the
+        slices' generations and bring the staged image up to the epoch
+        pair `ep` — nothing to do, an incremental scatter, or a
+        restage."""
+        index, frame, view = key
 
-            pending: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
-            new_gens = list(sv.slice_gens)
-            for s in range(num_slices):
-                frag = self.holder.fragment(index, frame, view, s)
-                staged = sv.slice_gens[s]
-                if frag is None:
-                    if staged is None:
-                        continue
-                    return restage()  # fragment deleted
-                if staged is None or staged[0] is not frag:
-                    # New fragment object (appeared, or the index was
-                    # deleted and recreated): generations from a
-                    # different object are meaningless — restage.
-                    return restage()
-                staged_gen = staged[1]
-                with frag._mu:
-                    gen = frag.generation
-                    if gen == staged_gen:
-                        continue
-                    entries = frag.log_since(staged_gen)
-                if entries is None or any(e[2] for e in entries):
-                    return restage()
-                pending[s] = fold_log_entries(entries)
-                new_gens[s] = (frag, gen)
+        def restage():
+            f = self._stage(key, num_slices)
+            f.validated_epoch = ep
+            return f
 
-            if not pending:
-                sv.validated_epoch = ep
-                return sv
-            if sv.sparse is not None:
-                # Sorted-array pools have no scatter path (an insert
-                # shifts every value after it), so any pending write on
-                # a sparse/mixed view restages. The pools are 10-100x
-                # smaller than the dense image of the same slices, so
-                # restage IS the cheap path here — and re-running the
-                # pick (with hysteresis) is what lets a densifying
-                # slice eventually convert back to packed words.
+        pending: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
+        new_gens = list(sv.slice_gens)
+        for s in range(num_slices):
+            frag = self.holder.fragment(index, frame, view, s)
+            staged = sv.slice_gens[s]
+            if frag is None:
+                if staged is None:
+                    continue
+                return restage()  # fragment deleted
+            if staged is None or staged[0] is not frag:
+                # New fragment object (appeared, or the index was
+                # deleted and recreated): generations from a
+                # different object are meaningless — restage.
+                return restage()
+            staged_gen = staged[1]
+            with frag._mu:
+                gen = frag.generation
+                if gen == staged_gen:
+                    continue
+                entries = frag.log_since(staged_gen)
+            if entries is None or any(e[2] for e in entries):
+                return restage()
+            pending[s] = fold_log_entries(entries)
+            new_gens[s] = (frag, gen)
+
+        if not pending:
+            sv.validated_epoch = ep
+            return sv
+        if sv.sparse is not None:
+            # Sorted-array pools have no scatter path (an insert
+            # shifts every value after it), so any pending write on
+            # a sparse/mixed view restages. The pools are 10-100x
+            # smaller than the dense image of the same slices, so
+            # restage IS the cheap path here — and re-running the
+            # pick (with hysteresis) is what lets a densifying
+            # slice eventually convert back to packed words.
+            self.stats.inc("refresh_pick_restage")
+            return restage()
+        # Cost gate: incremental scatter vs full
+        # restage, decided from MEASURED costs on THIS backend —
+        # the view's own last stage time vs an EWMA of recent
+        # incremental applies. Which side wins depends on the
+        # backend and the pool (on a small CPU pool the restage is
+        # the cheaper one), so a hard-wired incremental would be
+        # the wrong policy somewhere.
+        # First incremental runs unmeasured (no EWMA yet) and seeds
+        # the estimate; decisions surface in /debug/vars.
+        if self.deterministic_gate:
+            # SPMD mode (ADVICE r4): every rank executes the same
+            # descriptor stream, but measured timings are per-rank —
+            # a measured gate could pick restage on one rank and
+            # incremental on another, and if a restage shrinks
+            # capacity the shapes diverge and the fingerprint gate
+            # host-falls-back every collective for this view
+            # forever. Decide from replicated state only: restage
+            # every fixed number of incremental applies (bounds
+            # capacity creep the scatters can't reclaim), otherwise
+            # incremental. Same stream -> same counter -> same pick
+            # on every rank.
+            if sv.inc_count >= self._DET_RESTAGE_EVERY:
                 self.stats.inc("refresh_pick_restage")
                 return restage()
-            # Cost gate: incremental scatter vs full
-            # restage, decided from MEASURED costs on THIS backend —
-            # the view's own last stage time vs an EWMA of recent
-            # incremental applies. Which side wins depends on the
-            # backend and the pool (on a small CPU pool the restage is
-            # the cheaper one), so a hard-wired incremental would be
-            # the wrong policy somewhere.
-            # First incremental runs unmeasured (no EWMA yet) and seeds
-            # the estimate; decisions surface in /debug/vars.
-            if self.deterministic_gate:
-                # SPMD mode (ADVICE r4): every rank executes the same
-                # descriptor stream, but measured timings are per-rank —
-                # a measured gate could pick restage on one rank and
-                # incremental on another, and if a restage shrinks
-                # capacity the shapes diverge and the fingerprint gate
-                # host-falls-back every collective for this view
-                # forever. Decide from replicated state only: restage
-                # every fixed number of incremental applies (bounds
-                # capacity creep the scatters can't reclaim), otherwise
-                # incremental. Same stream -> same counter -> same pick
-                # on every rank.
-                if sv.inc_count >= self._DET_RESTAGE_EVERY:
-                    self.stats.inc("refresh_pick_restage")
-                    return restage()
-            else:
-                # Per-VIEW incremental estimate (ADVICE r4): comparing a
-                # per-view stage time against a manager-global EWMA let
-                # cheap scatters measured on a small view drive repeated
-                # full restages of a large one — both sides of the gate
-                # must cost the same pool.
-                inc_est = sv.inc_ewma_s
-                # Periodic restage PROBE — the symmetric re-exploration:
-                # a stale stage-cost sample (e.g. a slow COLD first
-                # stage) would otherwise freeze the gate on incremental
-                # forever, since restaging is the only event that
-                # re-measures stage cost. Probing when cumulative
-                # incremental spend reaches 20x the stage estimate
-                # bounds probe overhead at ~5% while re-calibrating
-                # quickly when restage is genuinely cheap.
-                probe = (sv.last_stage_s is not None
-                         and sv.inc_spend_s > 20.0 * sv.last_stage_s)
-                if probe or (inc_est is not None
-                             and sv.last_stage_s is not None
-                             and sv.last_stage_s < inc_est):
-                    self.stats.inc("refresh_pick_restage")
-                    if probe:
-                        self.stats.inc("refresh_probe_restage")
-                    elif inc_est is not None:
-                        # Decay the incremental estimate on a GATE-chosen
-                        # restage: one anomalous slow scatter sample must
-                        # not freeze the gate on restage forever — the
-                        # decayed EWMA (inherited by the fresh view in
-                        # _stage) eventually re-admits an incremental,
-                        # which re-measures reality. (A PROBE carries no
-                        # evidence against incremental, so it must not
-                        # bias the estimate.)
-                        sv.inc_ewma_s = inc_est * 0.9
-                    return restage()
-            t_inc = time.monotonic()
-            per_slice = {}
-            try:
-                for s, (pos, val) in pending.items():
-                    per_slice[s] = plan_slice_mutations(
-                        sv.keys_host[s], sv.row_ids, pos, val)
-            except KeyError:
+        else:
+            # Per-VIEW incremental estimate (ADVICE r4): comparing a
+            # per-view stage time against a manager-global EWMA let
+            # cheap scatters measured on a small view drive repeated
+            # full restages of a large one — both sides of the gate
+            # must cost the same pool.
+            inc_est = sv.inc_ewma_s
+            # Periodic restage PROBE — the symmetric re-exploration:
+            # a stale stage-cost sample (e.g. a slow COLD first
+            # stage) would otherwise freeze the gate on incremental
+            # forever, since restaging is the only event that
+            # re-measures stage cost. Probing when cumulative
+            # incremental spend reaches 20x the stage estimate
+            # bounds probe overhead at ~5% while re-calibrating
+            # quickly when restage is genuinely cheap.
+            probe = (sv.last_stage_s is not None
+                     and sv.inc_spend_s > 20.0 * sv.last_stage_s)
+            if probe or (inc_est is not None
+                         and sv.last_stage_s is not None
+                         and sv.last_stage_s < inc_est):
+                self.stats.inc("refresh_pick_restage")
+                if probe:
+                    self.stats.inc("refresh_probe_restage")
+                elif inc_est is not None:
+                    # Decay the incremental estimate on a GATE-chosen
+                    # restage: one anomalous slow scatter sample must
+                    # not freeze the gate on restage forever — the
+                    # decayed EWMA (inherited by the fresh view in
+                    # _stage) eventually re-admits an incremental,
+                    # which re-measures reality. (A PROBE carries no
+                    # evidence against incremental, so it must not
+                    # bias the estimate.)
+                    sv.inc_ewma_s = inc_est * 0.9
                 return restage()
-            batches = pack_mutation_batches(
-                per_slice, sv.padded_slices, sv.keys_host.shape[1])
-            if self._apply_fn is None:
-                self._apply_fn = compile_serve_apply_writes(self.mesh)
-            # The jitted apply recompiles on any NEW batch/pool shape
-            # (mutation_batch_width doubles, a different capacity) —
-            # a sample carrying a one-off XLA compile must not feed
-            # the EWMA or the gate would flip to restage on costs the
-            # steady state never pays. Shape-novelty mirrors exactly
-            # what jit keys compilation on.
-            shapes = (tuple(sv.sharded.words.shape),
-                      tuple(tuple(np.shape(b)) for b in batches))
-            fresh_compile = shapes not in self._apply_shapes
-            self._apply_shapes.add(shapes)
-            self._purge_memo(sv.sharded.words)
-            sp = span("incremental", index=index, frame=frame, view=view)
-            with jax_scope("pilosa:apply_writes"):
-                sv.sharded = self._apply_fn(sv.sharded, *batches)
-            self._views_gen += 1
-            sp.finish()
-            sv.slice_gens = new_gens
-            sv.validated_epoch = ep
-            sv.inc_count += 1
-            self.stats.inc("incremental")
-            self.stats.inc("refresh_pick_incremental")
-            if not fresh_compile:
-                # Like staging, measure to DEVICE completion on the
-                # measurement worker — host dispatch alone is a
-                # near-constant floor that says nothing about the
-                # scatter's real cost.
-                def on_inc(dt, ok=True, sv=sv):
-                    if not ok:
-                        # A failed scatter's time-to-exception says
-                        # nothing about incremental cost — feeding it
-                        # to the EWMA would make incrementals look
-                        # artificially cheap. Skip the sample; the
-                        # stage side keeps the gate decidable.
-                        return
-                    with self._mu:
-                        sv.inc_ewma_s = (
-                            dt if sv.inc_ewma_s is None
-                            else 0.5 * (dt + sv.inc_ewma_s))
-                        # Manager-global EWMA survives only as an
-                        # observability gauge (/debug/vars) — the gate
-                        # reads the per-view estimate.
-                        self._inc_ewma_s = (
-                            dt if self._inc_ewma_s is None
-                            else 0.5 * (dt + self._inc_ewma_s))
-                        self.stats["inc_ewma_us"] = \
-                            int(self._inc_ewma_s * 1e6)
-                        sv.inc_spend_s += dt
+        t_inc = time.monotonic()
+        per_slice = {}
+        try:
+            for s, (pos, val) in pending.items():
+                per_slice[s] = plan_slice_mutations(
+                    sv.keys_host[s], sv.row_ids, pos, val)
+        except KeyError:
+            return restage()
+        batches = pack_mutation_batches(
+            per_slice, sv.padded_slices, sv.keys_host.shape[1])
+        if self._apply_fn is None:
+            self._apply_fn = compile_serve_apply_writes(self.mesh)
+        # The jitted apply recompiles on any NEW batch/pool shape
+        # (mutation_batch_width doubles, a different capacity) —
+        # a sample carrying a one-off XLA compile must not feed
+        # the EWMA or the gate would flip to restage on costs the
+        # steady state never pays. Shape-novelty mirrors exactly
+        # what jit keys compilation on.
+        shapes = (tuple(sv.sharded.words.shape),
+                  tuple(tuple(np.shape(b)) for b in batches))
+        fresh_compile = shapes not in self._apply_shapes
+        self._apply_shapes.add(shapes)
+        self._purge_memo(sv.sharded.words)
+        sp = span("incremental", index=index, frame=frame, view=view)
+        with jax_scope("pilosa:apply_writes"):
+            sv.sharded = self._apply_fn(sv.sharded, *batches)
+        self._views_gen += 1
+        sp.finish()
+        sv.slice_gens = new_gens
+        sv.validated_epoch = ep
+        sv.inc_count += 1
+        self.stats.inc("incremental")
+        self.stats.inc("refresh_pick_incremental")
+        if not fresh_compile:
+            # Like staging, measure to DEVICE completion on the
+            # measurement worker — host dispatch alone is a
+            # near-constant floor that says nothing about the
+            # scatter's real cost.
+            def on_inc(dt, ok=True, sv=sv):
+                if not ok:
+                    # A failed scatter's time-to-exception says
+                    # nothing about incremental cost — feeding it
+                    # to the EWMA would make incrementals look
+                    # artificially cheap. Skip the sample; the
+                    # stage side keeps the gate decidable.
+                    return
+                with self._mu:
+                    sv.inc_ewma_s = (
+                        dt if sv.inc_ewma_s is None
+                        else 0.5 * (dt + sv.inc_ewma_s))
+                    # Manager-global EWMA survives only as an
+                    # observability gauge (/debug/vars) — the gate
+                    # reads the per-view estimate.
+                    self._inc_ewma_s = (
+                        dt if self._inc_ewma_s is None
+                        else 0.5 * (dt + self._inc_ewma_s))
+                    self.stats["inc_ewma_us"] = \
+                        int(self._inc_ewma_s * 1e6)
+                    sv.inc_spend_s += dt
 
-                self._measure_async(sv.sharded.words, t_inc, on_inc)
-            return sv
+            self._measure_async(sv.sharded.words, t_inc, on_inc)
+        return sv
 
     def invalidate(self, index: Optional[str] = None):
         """Drop staged views (all, or one index's)."""
@@ -1627,7 +1664,7 @@ class MeshManager:
         exceeds the budget."""
         if not pins:
             return
-        with self._mu:
+        with _held(self._mu):
             for sv in pins:
                 if sv.pins > 0:
                     sv.pins -= 1
@@ -1649,7 +1686,7 @@ class MeshManager:
         eviction pin per staged view used, held until the caller's
         _release_pins — the unlocked execution window must not have its
         images evicted-and-restaged under memory pressure mid-fold."""
-        with self._mu:
+        with _held(self._mu):
             self._use_epoch += 1
             out = self._stage_leaves(index, leaves, num_slices, pins=pins)
             if out is None:
@@ -2634,7 +2671,16 @@ class MeshManager:
         was busy and runs up to _MAX_BATCH queries as one program.
         Dispatch and readback are a fixed cost per program, so
         batching multiplies concurrent throughput while a lone request
-        runs immediately."""
+        runs immediately.
+
+        Profile phases: the locks, the view refresh, staging, compile,
+        the launch and the readback have their own; `mesh_prepare` is
+        the rest of this call (residual: each of those pauses it)."""
+        with profile.residual("mesh_prepare"):
+            return self._count(index, shape, leaves, slices, num_slices)
+
+    def _count(self, index: str, shape, leaves, slices: Sequence[int],
+               num_slices: int) -> Optional[int]:
         t0 = time.monotonic()
         sp = span("dispatch", engine="mesh", leaves=len(leaves),
                   slices=len(slices))
@@ -2678,7 +2724,7 @@ class MeshManager:
                                      kind="count-result")
         if not self.lone_fused:
             sp.tag(kill_switch="lone_fused=off")
-        with self._lone_mu:
+        with _held(self._lone_mu):
             self._counts_inflight += 1
             lone = self._counts_inflight == 1
         if lone:
@@ -2686,7 +2732,7 @@ class MeshManager:
             # first member would see itself alone and take the fused
             # path, stranding the rest in a narrower batch. The burst
             # hint says siblings are right behind — batch instead.
-            with self._burst_mu:
+            with _held(self._burst_mu):
                 if self._burst_hint > 1:
                     lone = False
         pins: list = []
@@ -2716,10 +2762,13 @@ class MeshManager:
                 req.done.wait()
             else:
                 # Batched dispatch runs on the batch thread; from here
-                # the wait IS device execution + readback (the fetcher
-                # sets done after np.asarray). Attributed as
-                # device_exec — the D2H split would need per-request
-                # timestamps on the fetcher, not worth a hot-path field.
+                # the wait is the batch queue (the drain window, and
+                # the programs ahead of this request's), then device
+                # execution + readback (the fetcher sets done after
+                # np.asarray). Attributed as device_exec — the queue
+                # and D2H splits would need per-request timestamps on
+                # the batch and fetch threads, not worth a hot-path
+                # field.
                 with prof.phase("device_exec"):
                     req.done.wait()
                 prof.add_bytes("bytes_touched_hbm",
@@ -2746,7 +2795,7 @@ class MeshManager:
         finally:
             self._release_pins(pins)
             sp.finish()
-            with self._lone_mu:
+            with _held(self._lone_mu):
                 self._counts_inflight -= 1
 
     def _lone_count(self, index: str, shape, leaves,
@@ -2764,7 +2813,7 @@ class MeshManager:
         transient fault straight into quarantine."""
         pins: list = []
         try:
-            with self._mu:
+            with _held(self._mu):
                 self._use_epoch += 1
                 out = self._stage_leaves_host(index, leaves, num_slices,
                                               pins=pins)

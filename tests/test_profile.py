@@ -198,17 +198,28 @@ class TestPeakBandwidth:
         assert a == b  # measured once, cached
 
 
-@pytest.fixture
-def env(tmp_path):
+def _env(tmp_path, **executor_kw):
     holder = Holder(str(tmp_path / "data"))
     holder.open()
     cluster = new_test_cluster(1)
     ex = Executor(holder, host=cluster.nodes[0].host, cluster=cluster,
-                  use_device=False)
+                  **executor_kw)
     handler = Handler(holder, ex, cluster=cluster,
                       host=cluster.nodes[0].host)
     yield holder, handler
     holder.close()
+
+
+@pytest.fixture
+def env(tmp_path):
+    yield from _env(tmp_path, use_device=False)
+
+
+@pytest.fixture
+def mesh_env(tmp_path):
+    """The same node with every Count on the mesh route (cost routing
+    off), as the server of the benchmark's cells runs."""
+    yield from _env(tmp_path, use_device=True, device_min_work=0)
 
 
 def _seed(h, rows=6, slices=16):
@@ -248,9 +259,12 @@ class TestProfileEndpoint:
                      body=b"Count(Bitmap(rowID=0, frame=f))")
         assert "profile" not in r.json()
 
-    def test_phases_cover_90_percent_on_cpu(self, env):
+    @pytest.mark.parametrize("route", ["host-fold", "mesh"])
+    def test_phases_cover_90_percent_on_cpu(self, route, request):
         """The acceptance bar: measured phase times sum to >= 90% of
-        the profile's total. Distinct rows dodge the query memo (a memo
+        the profile's total, on the host-fold route and on the mesh
+        route (where the seams between the handler and the launch have
+        phases of their own). Distinct rows dodge the query memo (a memo
         hit is ~all fixed overhead). One clean sample is the claim —
         retry with early exit, because any single measurement can be
         stretched by suite-wide scheduler noise. 32 slices per row
@@ -258,7 +272,8 @@ class TestProfileEndpoint:
         (parse/plan bookkeeping), which is what the unprofiled gap is
         made of — at 16 slices a busy suite run sits just under the
         bar across every retry."""
-        _, h = env
+        _, h = request.getfixturevalue(
+            "mesh_env" if route == "mesh" else "env")
         _seed(h, rows=12, slices=32)
         # Warm: first Count pays one-time costs (backend probe, pools).
         h.handle("POST", "/index/i/query",
@@ -271,6 +286,7 @@ class TestProfileEndpoint:
                          .encode(),
                          params={"profile": "true"})
             prof = r.json()["profile"]
+            assert ("device_exec" in prof["phases_us"]) == (route == "mesh")
             covs.append(sum(prof["phases_us"].values()) / prof["total_us"])
             if covs[-1] >= 0.90:
                 break
