@@ -816,6 +816,16 @@ class Handler:
                     fam.add(v, {"replica": pick,
                                 "staleness": sclass or "strict"})
                 fams.append(fam)
+        placed = getattr(self.executor, "placement_stats", None)
+        if placed is not None:
+            fams.append(prom.MetricFamily(
+                "pilosa_route_owner_decisions_total", "counter",
+                "Owner-ladder decisions the executor's slice routing "
+                "made: one per partition and placement ring of a "
+                "strict read, one per slice of a bounded-staleness "
+                "spread. Over pilosa_read_replica_total: decisions "
+                "per slice placed.").add(
+                    placed.get("owner_decisions", 0)))
         rc = getattr(self.executor, "result_cache", None)
         if rc is not None:
             events = rc.stats.copy()

@@ -335,6 +335,10 @@ class Executor:
         # strict|bounded) -> pilosa_read_replica_total{replica,
         # staleness} at /metrics.
         self.read_stats = obs.StatMap()
+        # "owner_decisions": owner-ladder climbs _slices_by_node made
+        # (one per partition and ring of a strict read, one per slice
+        # of a bounded spread) -> pilosa_route_owner_decisions_total.
+        self.placement_stats = obs.StatMap()
 
     def set_spmd(self, spmd):
         """Wire the SPMD descriptor plane (rank 0 of a multi-host
@@ -2702,8 +2706,17 @@ class Executor:
         (`[cluster] ici-hosts`) is folded into the LOCAL node's group —
         its shard is already addressable through this node's mesh, and
         the collective reduces over the interconnect — so only slices
-        owned by hosts OUTSIDE the pod pay the HTTP ring."""
-        local_node = (self.cluster.node_by_host(self.host)
+        owned by hosts OUTSIDE the pod pay the HTTP ring.
+
+        The owner ladder is climbed once per (partition, placement
+        ring), lazily, over state read once here, and not once per
+        slice: a cluster has `partition_n` partitions and a headline
+        query 960 slices. Per slice stays what differs per slice: the
+        ring of a slice handed off mid-resize, and the replica spread
+        of a bounded-staleness read (its p2c sample and epoch check).
+        `read_stats` is bumped once per label, as the split ends."""
+        cluster = self.cluster
+        local_node = (cluster.node_by_host(self.host)
                       if self.ici_hosts else None)
         if local_node is not None and local_node not in nodes:
             # e.g. a re-split that excluded this node: don't route an
@@ -2716,60 +2729,19 @@ class Executor:
         read_bound = (opt.staleness
                       if opt is not None and not opt.remote else 0.0)
         sclass = "bounded" if read_bound > 0 else "strict"
+        partial = opt is not None and opt.partial
+        prefer = self.host if self.prefer_local_reads else None
+        ici_hosts = self.ici_hosts or None
+        serving_ring, target_ring, handed = cluster.placement_rings(index)
+        partitions = cluster.partition_table(index)
         m = {}
-        for slice_ in slices:
-            owners = [o for o in self.cluster.fragment_nodes(index, slice_)
-                      if o in nodes]
-            if opt is not None and opt.partial:
-                # Membership-aware degradation: a JOINING node hasn't
-                # received its slices yet and a DOWN node can't answer,
-                # so in partial mode route only to serving replicas
-                # (ACTIVE/LEAVING) and report the slice missing when
-                # none remain — never hang on a non-serving owner.
-                serving = [o for o in owners if o.state in SERVING_STATES]
-                if not serving:
-                    opt.missing_slices.append(slice_)
-                    continue
-                owners = serving
-            elif not owners:
-                raise SliceUnavailableError()
-            # Bounded reads first try the follower-spread ladder:
-            # pick_read_replica over in-sync replicas (breaker-closed,
-            # epoch staleness within the client's bound, p2c by
-            # gossiped queue depth). An empty candidate set falls DOWN
-            # the ladder to the strict owner pick — never sideways to
-            # a staler replica — and the fallback is counted.
-            pick = None
-            if read_bound > 0 and len(owners) > 1:
-                pick = pick_read_replica(
-                    owners, breaker,
-                    staleness_ok=lambda h, s=slice_:
-                        self.epochs.staleness_ok_slice(
-                            h, index, s, read_bound),
-                    queue_depth=self.epochs.queue_depth,
-                    prefer=self.host,
-                    ici_hosts=self.ici_hosts or None,
-                    node_ok=self.peer_health_ok)
-            if pick is not None:
-                # "follower" = spread away from the ring primary
-                # (owners[0] is ring order) — the label that proves
-                # replicas actually absorb read load.
-                self.read_stats.inc(
-                    ("follower|" if pick.host != owners[0].host
-                     else "owner|") + sclass)
-            else:
-                self.read_stats.inc(
-                    ("fallback_owner|" if read_bound > 0
-                     and len(owners) > 1 else "owner|") + sclass)
-                # Prefer replicas the status-poll daemon currently
-                # sees UP AND whose circuit breaker is closed; a slice
-                # whose owners are all marked DOWN/open still tries
-                # one (liveness is advisory — the reactive re-split
-                # below is the authority, executor.go:1140-1151).
-                pick = preferred_owner(
-                    owners, breaker,
-                    prefer=self.host if self.prefer_local_reads else None,
-                    ici_hosts=self.ici_hosts or None)
+        # partition -> where its slices go, one dict per ring: m's
+        # list of the picked node, None (no serving owner, partial
+        # mode), or the owners as a tuple (spread per slice).
+        on_serving, on_target = {}, {}
+        spread = {}  # label -> slices placed by the per-slice spread
+
+        def group(pick):
             if (local_node is not None and pick.host != self.host
                     and pick.host in self.ici_hosts):
                 # ICI-tier slice: serve it from the local mesh dispatch
@@ -2777,7 +2749,91 @@ class Executor:
                 if opt is not None:
                     opt.used_ici = True
                 pick = local_node
-            m.setdefault(pick, []).append(slice_)
+            return m.setdefault(pick, [])
+
+        def decide(partition, ring):
+            owners = [o for o in cluster.partition_nodes(partition, ring)
+                      if o in nodes]
+            if partial:
+                # Membership-aware degradation: a JOINING node hasn't
+                # received its slices yet and a DOWN node can't answer,
+                # so in partial mode route only to serving replicas
+                # (ACTIVE/LEAVING) and report the slice missing when
+                # none remain — never hang on a non-serving owner.
+                owners = [o for o in owners if o.state in SERVING_STATES]
+                if not owners:
+                    return None
+            elif not owners:
+                raise SliceUnavailableError()
+            if read_bound > 0 and len(owners) > 1:
+                return tuple(owners)
+            # Prefer replicas the status-poll daemon currently sees UP
+            # AND whose circuit breaker is closed; a slice whose owners
+            # are all marked DOWN/open still tries one (liveness is
+            # advisory — the reactive re-split below is the authority,
+            # executor.go:1140-1151).
+            return group(preferred_owner(owners, breaker, prefer=prefer,
+                                         ici_hosts=ici_hosts))
+
+        def spread_pick(owners, slice_):
+            # Bounded reads first try the follower-spread ladder:
+            # pick_read_replica over in-sync replicas (breaker-closed,
+            # epoch staleness within the client's bound, p2c by
+            # gossiped queue depth). An empty candidate set falls DOWN
+            # the ladder to the strict owner pick — never sideways to
+            # a staler replica — and the fallback is counted.
+            pick = pick_read_replica(
+                owners, breaker,
+                staleness_ok=lambda h: self.epochs.staleness_ok_slice(
+                    h, index, slice_, read_bound),
+                queue_depth=self.epochs.queue_depth,
+                prefer=self.host, ici_hosts=ici_hosts,
+                node_ok=self.peer_health_ok)
+            if pick is not None:
+                # "follower" = spread away from the ring primary
+                # (owners[0] is ring order) — the label that proves
+                # replicas actually absorb read load.
+                label = ("follower|" if pick.host != owners[0].host
+                         else "owner|") + sclass
+            else:
+                label = "fallback_owner|" + sclass
+                pick = preferred_owner(owners, breaker, prefer=prefer,
+                                       ici_hosts=ici_hosts)
+            spread[label] = spread.get(label, 0) + 1
+            group(pick).append(slice_)
+
+        try:
+            for slice_ in slices:
+                partition = partitions[slice_]
+                if handed and slice_ in handed:
+                    decided, ring = on_target, target_ring
+                else:
+                    decided, ring = on_serving, serving_ring
+                dest = decided.get(partition, decided)
+                if dest is decided:  # not decided yet (None is a decision)
+                    dest = decided[partition] = decide(partition, ring)
+                if dest.__class__ is list:
+                    dest.append(slice_)
+                elif dest is None:
+                    opt.missing_slices.append(slice_)
+                else:
+                    spread_pick(dest, slice_)
+        finally:
+            # Every slice the spread did not place went to the strict
+            # ring pick: one label. Counted also when an unowned slice
+            # raises, for the slices placed before it.
+            spread_n = sum(spread.values())
+            owner_n = sum(len(v) for v in m.values()) - spread_n
+            if owner_n:
+                label = "owner|" + sclass
+                spread[label] = spread.get(label, 0) + owner_n
+            for label, n in spread.items():
+                self.read_stats.inc(label, n)
+            climbs = spread_n + sum(
+                dest.__class__ is not tuple for decided in
+                (on_serving, on_target) for dest in decided.values())
+            if climbs:
+                self.placement_stats.inc("owner_decisions", climbs)
         return m
 
     def _map_reduce(self, index: str, slices: Sequence[int], c: Call,

@@ -20,6 +20,12 @@ from typing import Dict, List, Optional, Set, Tuple
 DEFAULT_PARTITION_N = 16
 DEFAULT_REPLICA_N = 1
 
+# Bounds on PartitionTable: slices kept per table (2^20 slices = 2^40
+# columns; a slice beyond that hashes on every read), and tables kept
+# per cluster (index names reach the executor from the wire).
+PARTITION_TABLE_SLICES = 1 << 20
+PARTITION_TABLES = 1024
+
 # Membership lifecycle: JOINING -> ACTIVE -> LEAVING -> DOWN. ACTIVE
 # serializes as "UP" — the reference's wire literal, which every status
 # consumer already speaks. JOINING nodes are in the TARGET ring (they
@@ -57,6 +63,33 @@ def fnv64a(data: bytes) -> int:
         h ^= b
         h = (h * _FNV64_PRIME) & _MASK64
     return h
+
+
+def partition_of(index: str, slice_: int, partition_n: int) -> int:
+    """(index, slice) -> partition id via fnv64a over index bytes +
+    big-endian slice (reference cluster.go:198-207)."""
+    data = index.encode() + int(slice_).to_bytes(8, "big")
+    return fnv64a(data) % partition_n
+
+
+class PartitionTable(dict):
+    """slice -> partition of one index under one `partition_n`: a miss
+    hashes once and keeps the answer. `partition_of` is pure, so nothing
+    ever invalidates an entry; a read takes no lock, and two threads
+    that miss the same slice store the same value (one dict store each,
+    atomic under the GIL)."""
+
+    __slots__ = ("index", "partition_n")
+
+    def __init__(self, index: str, partition_n: int):
+        super().__init__()
+        self.index, self.partition_n = index, partition_n
+
+    def __missing__(self, slice_: int) -> int:
+        p = partition_of(self.index, slice_, self.partition_n)
+        if len(self) < PARTITION_TABLE_SLICES:
+            self[slice_] = p
+        return p
 
 
 class Node:
@@ -155,6 +188,7 @@ class Cluster:
         # ring until then, so queries keep answering mid-migration.
         self._handoff: Set[Tuple[str, int]] = set()
         self._handoff_mu = threading.Lock()
+        self._partition_tables: Dict[Tuple[str, int], PartitionTable] = {}
 
     # -- membership ----------------------------------------------------------
 
@@ -269,10 +303,33 @@ class Cluster:
     # -- placement -----------------------------------------------------------
 
     def partition(self, index: str, slice_: int) -> int:
-        """(index, slice) -> partition id via fnv64a over index bytes +
-        big-endian slice (reference cluster.go:198-207)."""
-        data = index.encode() + int(slice_).to_bytes(8, "big")
-        return fnv64a(data) % self.partition_n
+        return partition_of(index, slice_, self.partition_n)
+
+    def partition_table(self, index: str) -> PartitionTable:
+        """`partition(index, ·)` as a table, for a caller that asks it
+        for many slices on every query. Keyed by (index, partition_n),
+        so a table is never read under another `partition_n`."""
+        key = (index, self.partition_n)
+        table = self._partition_tables.get(key)
+        if table is None:
+            if len(self._partition_tables) >= PARTITION_TABLES:
+                self._partition_tables = {}
+            table = self._partition_tables.setdefault(
+                key, PartitionTable(*key))
+        return table
+
+    def placement_rings(self, index: str
+                        ) -> Tuple[List[Node], List[Node], frozenset]:
+        """What `_placement_ring` answers slice by slice, read once:
+        the ring of a slice not handed off, the ring of a handed-off
+        one, and the slices of `index` the ledger holds (one lock).
+        Outside a resize both rings are the node list and the set is
+        empty."""
+        if not self.resizing():
+            return self.nodes, self.nodes, frozenset()
+        with self._handoff_mu:
+            handed = frozenset(s for i, s in self._handoff if i == index)
+        return self.serving_ring(), self.target_ring(), handed
 
     def _owners_over(self, ring: List[Node],
                      partition_id: int) -> List[Node]:

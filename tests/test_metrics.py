@@ -315,6 +315,39 @@ class TestMetricsEndpoint:
         # Existing ExpvarStats call-sites export for free.
         assert "pilosa_query_Count_total" in names
 
+    def test_route_owner_decisions_counter(self, env):
+        """A strict read over 960 slices on one node climbs the owner
+        ladder once per partition (16), not once per slice, and says so
+        beside pilosa_read_replica_total."""
+        holder, h = env
+        _seed(h)
+
+        def scrape():
+            samples, types, _ = parse_exposition(
+                h.handle("GET", "/metrics").body.decode())
+            assert types["pilosa_route_owner_decisions_total"] == "counter"
+            return {name: sum(float(v) for n, _, v in samples if n == name)
+                    for name in ("pilosa_route_owner_decisions_total",
+                                 "pilosa_read_replica_total")}
+
+        first = scrape()  # on /metrics before any multi-slice read
+        col = 959 * (1 << 20) + 3
+        assert h.handle(
+            "POST", "/index/i/query",
+            body=f"SetBit(rowID=1, frame=f, columnID={col})".encode()
+        ).status == 200
+        seen = [first]
+        for row in (1, 2):
+            assert h.handle(
+                "POST", "/index/i/query",
+                body=f"Count(Bitmap(rowID={row}, frame=f))".encode()
+            ).json() == {"results": [2 if row == 1 else 0]}
+            seen.append(scrape())
+        for before, after in zip(seen, seen[1:]):
+            grew = {k: after[k] - before[k] for k in after}
+            assert grew["pilosa_read_replica_total"] == 960
+            assert 1 <= grew["pilosa_route_owner_decisions_total"] <= 16
+
     def test_fragment_gauges_cached_by_interval(self, env):
         holder, h = env
         _seed(h)
