@@ -6,7 +6,8 @@ can fail.
 
     sound             nothing broken (the harness's own check of itself)
     stale_writes      a SetBit is acknowledged and never applied: "an
-                      acknowledged SetBit is in the next Count" is broken
+                      acknowledged SetBit is in the next Count" (and the
+                      next TopN) is broken
     approximate_topn  TopN from every other slice, doubled: an approximate
                       answer where the configuration says exact
     alter_answer      one read in seven answers one too many
@@ -14,8 +15,9 @@ can fail.
 (`lost_wal`, the control of the durability look, is not here: it is the
 program itself with its unsafe WAL path on; see harness.PROGRAM_CONTROLS.)
 
-It holds no chip and imports nothing of the program. Started by the harness as
-a child:  control.py <mode> <reference.pickle> <port>
+It serves from the reference's `live()` tables, whichever reference the
+configuration names, holds no chip and imports nothing of the program.
+Started by the harness as a child:  control.py <mode> <reference.pickle> <port>
 """
 
 from __future__ import annotations
@@ -31,8 +33,6 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import numpy as np  # noqa: E402
-
 MODES = ("sound", "stale_writes", "approximate_topn", "alter_answer")
 
 _ROW = re.compile(r"rowID=(\d+)")
@@ -44,10 +44,8 @@ class Answers:
     def __init__(self, mode: str, ref, approx=None):
         self.mode, self.ref, self.approx = mode, ref, approx
         self.mu = threading.Lock()
-        self.applied = None
+        self.live = ref.live()  # the tables, with the writes it applies
         self.reads = 0
-        if hasattr(ref, "base"):
-            self.applied = np.zeros(len(ref.keys), dtype=np.int64)
 
     def _count_key(self, pql: str):
         rows = [int(r) for r in _ROW.findall(pql)]
@@ -69,23 +67,22 @@ class Answers:
             row = int(_ROW.search(pql).group(1))
             col = int(_COL.search(pql).group(1))
             if self.mode != "stale_writes":
-                d = self.ref.delta(row, col)
                 with self.mu:
-                    self.applied += d
+                    self.live.set_bit(row, col)
             return True
         with self.mu:
             self.reads += 1
             bump = int(self.mode == "alter_answer" and self.reads % 7 == 0)
         if pql.startswith("Count("):
-            i = self.ref.index[self._count_key(pql)]
             with self.mu:
-                return int(self.ref.base[i] + self.applied[i]) + bump
+                return self.live.answer(self._count_key(pql)) + bump
         if pql.startswith("TopN("):
             rows = _ROW.findall(pql)
             key = ("T", int(rows[0]) if rows else None,
                    int(_N.search(pql).group(1)))
-            ref = self.approx if self.mode == "approximate_topn" else self.ref
-            pairs = ref.answer(key)
+            with self.mu:
+                pairs = (self.approx if self.mode == "approximate_topn"
+                         else self.live).answer(key)
             if bump and pairs:
                 pairs = [(pairs[0][0], pairs[0][1] + 1)] + list(pairs[1:])
             return [{"id": r, "count": c} for r, c in pairs]

@@ -1,21 +1,30 @@
 """Data from --seed: fragments on disk for the server, reference tables for
-the harness, one slice per job in a pool of workers.
+the harness, one slice per job in a pool of workers, in two passes.
 
-The fragments go through the repo's own roaring serializer (footer and all),
-so the server's ordinary open path loads and verifies them: that is loading
-the data, as a user's import would. The reference side of each job is numpy
-alone (`reference.py`).
+The first pass is set-up: the fragments go through the repo's own roaring
+serializer (footer and all), so the server's ordinary open path loads and
+verifies them: that is loading the data, as a user's import would. The second
+pass is the reference's and numpy alone: each slice's words are made again
+from the seed and the reference the configuration names (`names.reference`)
+tabulates its share from them. It is timed by itself (`ref.tabulated_s`) and
+the harness leaves those seconds out of `setup_s`.
+
+A frame kind (a module `pbench/kinds/<kind>.py`) gives the harness
+`generate(config, seed, data_dir, plan) -> reference` and
+`stage_query(frame_name)`, the query that stages the kind's view; `Kind` here
+builds both from how one slice is made.
 """
 
 from __future__ import annotations
 
 import multiprocessing
 import os
-from typing import Dict, List, Optional, Sequence
+import time
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from . import reference
+from . import names, reference
 
 VIEW = "standard"
 
@@ -40,32 +49,42 @@ def dense_words(seed: int, slice_: int, n_rows: int) -> np.ndarray:
     return rng.integers(0, 2**64, size=(n_rows * 16, 1024), dtype=np.uint64)
 
 
-def write_candidates(seed: int, n_columns: int, n: int) -> np.ndarray:
-    """Columns a run may write, in the order it takes them: distinct,
-    uniform over the index."""
-    rng = np.random.default_rng([seed, 102])
-    cols = rng.integers(0, n_columns, size=2 * n + 16, dtype=np.int64)
+def _first_distinct(cols: np.ndarray, n: int) -> np.ndarray:
     _, first = np.unique(cols, return_index=True)
     return cols[np.sort(first)][:n]
 
 
-def _dense_job(seed: int, s: int, data_dir: str, index: str, frame: dict,
-               locals_: Sequence[int]) -> dict:
-    from pilosa_tpu.roaring.bitmap import Container
+def write_candidates(seed: int, n_columns: int, n: int) -> np.ndarray:
+    """Columns a run may write, in the order it takes them: distinct,
+    uniform over the index."""
+    rng = np.random.default_rng([seed, 102])
+    return _first_distinct(
+        rng.integers(0, n_columns, size=2 * n + 16, dtype=np.int64), n)
 
+
+def block0_candidates(seed: int, n_columns: int, n: int) -> np.ndarray:
+    """The same over the columns a mixed frame populates: the first 65,536
+    of every slice, where the recipe puts its one container a row."""
+    rng = np.random.default_rng([seed, 102])
+    size = 2 * n + 16
+    return _first_distinct(
+        (rng.integers(0, n_columns >> 20, size=size, dtype=np.int64) << 20)
+        + rng.integers(0, 65536, size=size, dtype=np.int64), n)
+
+
+def _dense_slice(seed: int, s: int, data_dir: Optional[str], index: str,
+                 frame: dict) -> Tuple[Sequence[int], Optional[np.ndarray]]:
     n_rows = int(frame["rows"])
     words = dense_words(seed, s, n_rows)
+    if data_dir is None:
+        return range(n_rows), words.reshape(n_rows, -1)
+    from pilosa_tpu.roaring.bitmap import Container
+
     _write_fragment(
         frag_path(data_dir, index, frame["name"], s),
         [r * 16 + b for r in range(n_rows) for b in range(16)],
         [Container(bitmap=words[i]) for i in range(len(words))])
-    return {
-        "counts": reference.slice_counts(
-            words.reshape(n_rows, -1), n_rows),
-        "kept": {(s << 20) + int(c): reference.column_bits(words, n_rows,
-                                                          int(c))
-                 for c in locals_},
-    }
+    return range(n_rows), None
 
 
 def mixed_containers(seed: int, s: int, frame: dict):
@@ -92,34 +111,33 @@ def mixed_containers(seed: int, s: int, frame: dict):
     return rows, out
 
 
-def _mixed_job(seed: int, s: int, data_dir: str, index: str, frame: dict,
-               src_rows: Sequence[int]) -> dict:
+def _mixed_slice(seed: int, s: int, data_dir: Optional[str], index: str,
+                 frame: dict) -> Tuple[Sequence[int], Optional[np.ndarray]]:
+    rows, conts = mixed_containers(seed, s, frame)
+    if data_dir is None:
+        return rows, np.stack([reference.container_words(v, b)
+                               for v, b in conts])
     from pilosa_tpu.roaring.bitmap import Container
 
-    rows, conts = mixed_containers(seed, s, frame)
     _write_fragment(
         frag_path(data_dir, index, frame["name"], s),
         [int(r) * 16 for r in rows],
         [Container(array=v) if b is None else Container(bitmap=b)
          for v, b in conts])
-    counts = [len(v) if b is None else int(np.bitwise_count(b).sum())
-              for v, b in conts]
-    by_src: Dict[int, Dict[int, int]] = {}
-    present = sorted(set(int(r) for r in rows) & set(src_rows))
-    if present:
-        words = np.stack([reference.container_words(v, b)
-                          for v, b in conts])
-        where = {int(r): i for i, r in enumerate(rows)}
-        for x in present:
-            inter = np.bitwise_count(words & words[where[x]]).sum(axis=1)
-            by_src[x] = {int(r): int(c) for r, c in zip(rows, inter) if c}
-    return {"rows": [int(r) for r in rows], "counts": counts,
-            "by_src": by_src}
+    return rows, None
 
 
-def _job(args) -> dict:
-    kind = args[0]
-    return (_dense_job if kind == "dense" else _mixed_job)(*args[1:])
+def _job(args) -> Tuple[int, Optional[dict]]:
+    """One slice in a worker. With a data directory, the fragment on disk;
+    without, the reference's share of the slice, from the same words made
+    again from the seed."""
+    slice_words, config, seed, s, data_dir, locals_, src_rows = args
+    frame = config["frame"]
+    rows, words = slice_words(seed, s, data_dir, config["index"], frame)
+    if data_dir is not None:
+        return s, None
+    return s, names.reference(config).slice_part(frame, rows, words, locals_,
+                                                 src_rows)
 
 
 def create_schema(data_dir: str, index: str, frame: str) -> None:
@@ -133,50 +151,56 @@ def create_schema(data_dir: str, index: str, frame: str) -> None:
     h.close()
 
 
-def generate(config: dict, seed: int, data_dir: str, *,
-             write_columns: Optional[np.ndarray] = None,
-             src_rows: Sequence[int] = (), approx: bool = False):
-    """Write the configuration's frame and return its reference:
-    a `CountReference` for a dense frame, a `TopNReference` for a mixed.
-    With `approx` (the control's) a mixed frame returns a pair: the exact
-    reference and one counted over every other slice and doubled."""
-    index, frame, slices = config["index"], config["frame"], \
-        int(config["slices"])
-    create_schema(data_dir, index, frame["name"])
-    kind = frame["kind"]
-    if kind == "dense":
+class Kind:
+    """A frame kind of this module: how one slice is made
+    (`slice_words(seed, slice, data_dir, index, frame)` writes the fragment,
+    or with no `data_dir` returns the slice's row ids and their words instead;
+    a module-level function, since it goes to the workers), which columns a
+    run may write, and the query that stages the view. A module under `kinds/`
+    may build its own from these."""
+
+    def __init__(self, slice_words: Callable, candidates: Callable,
+                 stage_query: Callable):
+        self.slice_words = slice_words
+        self.candidates = candidates
+        self.stage_query = stage_query
+
+    def generate(self, config: dict, seed: int, data_dir: str, plan, *,
+                 approx: bool = False):
+        """Write the configuration's frame and return its reference, with the
+        seconds its tabulation took as `tabulated_s`. With `approx` (the
+        control's) a pair: the exact reference and one counted over every
+        other slice and doubled."""
+        index, frame, slices = config["index"], config["frame"], \
+            int(config["slices"])
+        create_schema(data_dir, index, frame["name"])
+        updates = len(plan.updates())
+        candidates = self.candidates(seed, slices << 20, 3 * updates + 64) \
+            if updates else ()
         by_slice: Dict[int, List[int]] = {}
-        for c in (write_columns if write_columns is not None else ()):
+        for c in candidates:
             by_slice.setdefault(int(c) >> 20, []).append(int(c) & 0xFFFFF)
-        jobs = [("dense", seed, s, data_dir, index, frame,
-                 by_slice.get(s, ())) for s in range(slices)]
-    elif kind == "mixed":
-        src = tuple(sorted(set(int(r) for r in src_rows)))
-        jobs = [("mixed", seed, s, data_dir, index, frame, src)
-                for s in range(slices)]
-    else:
-        raise ValueError(f"unknown frame kind {kind!r}")
-    n = max(1, min(len(jobs), (os.cpu_count() or 2) - 1, 12))
-    with multiprocessing.get_context("spawn").Pool(n) as pool:
-        parts = pool.map(_job, jobs, chunksize=4)
-    if kind == "dense":
-        base = np.sum([p["counts"] for p in parts], axis=0)
-        kept: dict = {}
-        for p in parts:
-            kept.update(p["kept"])
-        return reference.CountReference(int(frame["rows"]), base, kept)
-    exact = _topn_reference(parts, 1)
-    return (exact, _topn_reference(parts[::2], 2)) if approx else exact
+        src = tuple(plan.src_rows())
+        jobs = [(self.slice_words, config, seed, s, where,
+                 by_slice.get(s, ()), src)
+                for where in (data_dir, None) for s in range(slices)]
+        n = max(1, min(slices, (os.cpu_count() or 2) - 1, 12))
+        with multiprocessing.get_context("spawn").Pool(n) as pool:
+            pool.map(_job, jobs[:slices], chunksize=4)
+            t0 = time.monotonic()  # from here on it is the reference's time
+            parts = pool.map(_job, jobs[slices:], chunksize=4)
+        ref = names.reference(config)
+        exact = ref.assemble(frame, parts, candidates)
+        also = ref.assemble(frame, parts[::2], candidates, weight=2) \
+            if approx else None
+        exact.tabulated_s = time.monotonic() - t0
+        return (exact, also) if approx else exact
 
 
-def _topn_reference(parts, weight: int) -> reference.TopNReference:
-    totals: Dict[int, int] = {}
-    by_src: Dict[int, Dict[int, int]] = {}
-    for p in parts:
-        for r, c in zip(p["rows"], p["counts"]):
-            totals[r] = totals.get(r, 0) + weight * c
-        for x, row in p["by_src"].items():
-            acc = by_src.setdefault(x, {})
-            for r, c in row.items():
-                acc[r] = acc.get(r, 0) + weight * c
-    return reference.TopNReference(totals, by_src)
+def _count_row0(frame: str):
+    return f'Count(Bitmap(rowID=0, frame="{frame}"))', ("R", 0), "count"
+
+
+def _topn5(frame: str):
+    return f'TopN(frame="{frame}", n=5)', ("T", None, 5), "topn"
+

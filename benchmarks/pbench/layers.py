@@ -39,6 +39,8 @@ import os
 import statistics
 from typing import Callable, Dict, List, Optional
 
+import numpy as np
+
 from .window import READ_KINDS
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -69,6 +71,18 @@ def read_bytes_needed(key: tuple, n_rows: int, slices: int) -> int:
     return leaves * slices * ROW_SPAN * CONTAINER_BYTES
 
 
+ARRAY_MAX_VALUES = 4096  # roaring: above it a container is a bitmap
+
+
+def container_bytes_needed(cardinalities) -> int:
+    """HBM bytes the generated containers of those cardinalities hold as
+    roaring keeps them, which is what a read of them has to move whatever
+    kernel serves it: an array container 2 B a value (u16), a bitmap container
+    (over 4,096 values) 8 KB. Not the dense staging's 8 KB for every one."""
+    n = np.asarray(cardinalities, dtype=np.int64)
+    return int(np.where(n > ARRAY_MAX_VALUES, CONTAINER_BYTES, 2 * n).sum())
+
+
 def dig(obj, path: str):
     for part in path.split("."):
         if not isinstance(obj, dict) or part not in obj:
@@ -96,7 +110,7 @@ class Context:
 
     def __init__(self, *, vars_before, vars_after, prom_before, prom_after,
                  log, trace, device_kind, config, keys=None, lone_hits=None,
-                 window=None):
+                 window=None, bytes_of=None):
         self.vars_before, self.vars_after = vars_before, vars_after
         self.prom_before, self.prom_after = prom_before, prom_after
         self.log, self.trace = log, trace
@@ -104,6 +118,7 @@ class Context:
         self.keys = keys or {}      # (seq, request index) -> reference key
         self.lone_hits = lone_hits  # {(seq, request index)} the memo answered
         self.window = window or {}  # reduce_window() of the log
+        self.bytes_of = bytes_of    # the reference's bytes_needed(key)
 
 
 def _at(before, after, at: str):
@@ -131,22 +146,23 @@ def _profiled(ctx: Context, of: str):
 
 
 def _hbm_roofline_share(ctx: Context) -> Optional[float]:
-    """Bytes the traced window's device-answered reads need, over the
-    device's busy time, over the chip's peak. Only where one query is in
-    flight at a time (`lone_hits` is the harness's account of which reads the
-    whole-query memo answered, checked against the program's counter)."""
+    """Bytes the traced window's device-answered reads need (the reference's
+    `bytes_needed(key)`, whatever kind of frame it is), over the device's busy
+    time, over the chip's peak. Only where one query is in flight at a time
+    (`lone_hits` is the harness's account of which reads a whole-query memo
+    answered, checked against the program's counters)."""
     tr = ctx.trace
-    if tr is None or ctx.lone_hits is None or "t0" not in tr:
+    if tr is None or ctx.lone_hits is None or ctx.bytes_of is None \
+            or "t0" not in tr:
         return None
-    frame = ctx.config["frame"]
-    n_rows, slices = int(frame["rows"]), int(ctx.config["slices"])
     need = 0
     for d in ctx.log:
         if not d.ok or not (tr["t0"] <= d.t_send and d.t_done <= tr["t1"]):
             continue
         for j, (pql, *_rest) in enumerate(d.requests):
-            if pql.startswith("Count(") and (d.seq, j) not in ctx.lone_hits:
-                need += read_bytes_needed(ctx.keys[(d.seq, j)], n_rows, slices)
+            if not pql.startswith("SetBit(") \
+                    and (d.seq, j) not in ctx.lone_hits:
+                need += ctx.bytes_of(ctx.keys[(d.seq, j)])
     if not need or tr["busy_s"] <= 0:
         return None
     peak = peak_for(ctx.device_kind)["hbm_bytes_per_s"]

@@ -16,6 +16,7 @@ from typing import Dict, List, Optional, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
+WINDOW_MARK = "pbench:window"   # the annotation the traced server holds open
 MAX_GAPS = 200          # only the longest gaps are attributed
 TOP = 10
 
@@ -112,27 +113,63 @@ def _attribute(gap: Tuple[int, int], hosts: List[list]) -> str:
     return "unattributed"
 
 
+def window_of(trace: dict, devs, window_s: float) -> Tuple[int, int]:
+    """The traced window on the trace's own clock: the span of the
+    `pbench:window` annotation, which the traced server holds open from the
+    moment tracing has started to the moment it is told to stop. The profiler
+    goes on recording until `stop_trace` has taken effect, so a device that is
+    never idle shows more busy time than the window is long unless the ops
+    are cut to it. A trace without the mark (one made by hand) is taken to
+    begin at its first device op and to last `window_s`."""
+    marks = [(s, d) for name, s, d in host_events(trace)
+             if name == WINDOW_MARK and d > 0]
+    if marks:
+        start, dur = max(marks, key=lambda m: m[1])
+        return start, start + dur
+    start = min(s for _, events in devs for _, s, _ in events)
+    return start, start + int(round(window_s * 1e9))
+
+
+def _clip(events: List[list], lo: int, hi: int) -> List[list]:
+    """The part of each event that lies inside [lo, hi)."""
+    out = []
+    for name, s, d in events:
+        a, b = max(s, lo), min(s + d, hi)
+        if b > a:
+            out.append([name, a, b - a])
+    return out
+
+
 def reduce(trace: dict, window_s: float) -> Optional[dict]:
     """busy_s (mean over the device planes of the union of their op
-    intervals), idle share of `window_s`, the top device ops by time and the
-    longest idle gaps by host activity. None where no device op ran."""
+    intervals, cut to the traced window), the window's length, the idle share
+    of it, the top device ops by time and the longest idle gaps by host
+    activity. None where no device op ran in the window."""
     devs = device_lines(trace)
     if not devs or not any(events for _, events in devs):
         return None
+    lo, hi = window_of(trace, devs, window_s)
+    devs = [(plane, _clip(events, lo, hi)) for plane, events in devs]
+    if not any(events for _, events in devs):
+        return None
+    window_s = (hi - lo) / 1e9
     busy_ns: List[int] = []
     op_ns: Dict[str, int] = {}
     for _, events in devs:
-        spans = _union([(s, s + d) for _, s, d in events if d > 0])
+        spans = _union([(s, s + d) for _, s, d in events])
         busy_ns.append(sum(b - a for a, b in spans))
         for name, _, d in events:
             op_ns[name] = op_ns.get(name, 0) + d
     busy_s = sum(busy_ns) / len(busy_ns) / 1e9
-    # Gaps on the first device plane (one chip: the only one).
-    spans = _union([(s, s + d) for _, s, d in devs[0][1] if d > 0])
+    # Gaps on the first device plane (one chip: the only one), the window's
+    # two ends among them.
+    spans = [(lo, lo)] + _union([(s, s + d) for _, s, d in devs[0][1]]) \
+        + [(hi, hi)]
     gaps = [(spans[i][1], spans[i + 1][0]) for i in range(len(spans) - 1)
             if spans[i + 1][0] > spans[i][1]]
     gaps.sort(key=lambda g: g[0] - g[1])
-    hosts = sorted(host_events(trace), key=lambda e: -e[2])
+    hosts = sorted((e for e in host_events(trace) if e[0] != WINDOW_MARK),
+                   key=lambda e: -e[2])
     gap_ns: Dict[str, int] = {}
     for g in gaps[:MAX_GAPS]:
         name = _attribute(g, hosts)

@@ -279,7 +279,7 @@ def test_judge_owes_acknowledged_writes_and_allows_overlapping_ones(dense2):
              (("R", 2), 10.5, 12.0, None),   # sent before the ack: either
              (("R", 2), 11.5, 12.0, None),   # sent after the ack: owed
              (("R", 3), 11.5, 12.0, None)]   # another row: untouched
-    got = ref.judge(reads, write)
+    got = ref.ranges(reads, write)
     assert got[:4] == [(b, b), (b, b + 1), (b, b + 1), (b + 1, b + 1)]
     j = ref.index[("R", 3)]
     assert got[4] == (int(base[j]), int(base[j]))
@@ -328,6 +328,38 @@ def test_reduce_on_a_hand_made_trace():
     assert gaps["PjitFunction_run"] == pytest.approx(0.035)
     assert xplane.reduce({"planes": [{"name": "/host:CPU", "lines": []}]},
                          1.0) is None
+
+
+def test_reduce_cuts_the_ops_to_the_marked_window():
+    """The profiler records past the window's end; a device that is never
+    idle must not read busier than the window is long."""
+    ms = 1_000_000
+    ops = [["fusion.1", t * ms, 10 * ms] for t in range(0, 130, 10)]
+    trace = {"planes": [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Ops", "events": ops + [["copy.2", 145 * ms, 5 * ms]]}]},
+        {"name": "/host:CPU", "lines": [{"name": "tracer", "events": [
+            [xplane.WINDOW_MARK, 15 * ms, 100 * ms]]},
+            {"name": "python", "events": [["pilosa:plan", 70 * ms, 60 * ms]]}]},
+    ]}
+    out = xplane.reduce(trace, 0.0999)
+    assert out["window_s"] == pytest.approx(0.100)
+    assert out["busy_s"] == pytest.approx(0.100)
+    assert out["busy_s"] <= out["window_s"]
+    assert out["idle_share"] == pytest.approx(0.0, abs=1e-9)
+    assert out["device_ops"] == [["fusion.1", pytest.approx(0.100)]]
+    assert out["idle_gaps"] == []
+    # An idle stretch at either end of the window is a gap too, and the mark
+    # itself is never what a gap is charged to.
+    trace["planes"][0]["lines"][0]["events"] = [["fusion.1", 40 * ms, 10 * ms]]
+    out = xplane.reduce(trace, 0.1)
+    assert out["busy_s"] == pytest.approx(0.010)
+    assert dict(out["idle_gaps"]) == {
+        "unattributed": pytest.approx(0.025),
+        "pilosa:plan": pytest.approx(0.065)}
+    # No op inside the window: nothing to report.
+    trace["planes"][0]["lines"][0]["events"] = [["fusion.1", 120 * ms, 10 * ms]]
+    assert xplane.reduce(trace, 0.1) is None
 
 
 def test_reduce_on_a_recorded_trace():
@@ -434,10 +466,13 @@ def test_roofline_goes_loudly_when_the_memo_account_is_off(counted, kept,
                          done(2, "Count(b)")]}
     ctx = harness._layer_context(
         {"frame": {"kind": "dense", "rows": 8}, "slices": 2},
-        {"clients": 1}, P(), phases, None, {},
+        {"clients": 1}, P(),
+        reference.CountReference(8, np.zeros(141, np.int64), {}, slices=2),
+        phases, None, {},
         ({"host_cache": {"query_hit": 5}},
          {"host_cache": {"query_hit": 5 + counted}}), ({}, {}), "TPU v5 lite")
     assert (ctx.lone_hits == {(1, 0)}) if kept else ctx.lone_hits is None
+    assert ctx.bytes_of(("I", 0, 1)) == 2 * 2 * 131072
     assert ("roofline left out" in capsys.readouterr().err) is not kept
 
 
@@ -583,6 +618,25 @@ def test_program_on_the_cpu_sound_and_broken(fault, control, want, number,
         assert (cmp_[name]["value"] >= 1) == (name == number)
     if number == "lost_writes":
         assert cmp_[name]["value"] == cmp_[name]["of"]
+    # Set-up is everything up to the window less the reference's own pass.
+    run = json.loads(open(os.path.join(str(tmp_path), "runs.jsonl"))
+                     .readlines()[-1])
+    marks = run["marks_s"]
+    assert 0 < marks["reference_s"] < marks["generate"]
+    assert run["setup_s"] == pytest.approx(
+        marks["warm"] - marks["reference_s"], abs=0.5)
+    assert out["metrics"]["setup_s"]["value"] == run["setup_s"]
+
+
+@pytest.mark.parametrize("toml,want", [
+    ("benchmarks/configs/seg-1b.toml", "xla"),
+    ("benchmarks/configs/topn-1b.toml", "xla"),
+    ("tests/benchmarks/fixture/configs/topn-fixture.toml", None)])
+def test_a_configuration_names_its_count_backend(toml, want):
+    """The cells' servers pin the backend (the boot's `auto` pick is a coin on
+    a v5e and decides the herd's state); a pinned server calibrates nothing,
+    so the harness reads the device once the first query has staged."""
+    assert harness._pinned_backend(os.path.join(REPO, toml)) == want
 
 
 # -- the look at the disk after the kill ---------------------------------------------
