@@ -1098,6 +1098,19 @@ class MeshManager:
                         f"eviction: {e2}", reason="oom") from e2
                 raise
 
+    def _note_placement(self, words) -> None:
+        """Gauges of where the pool just staged lies: the size of the
+        serving mesh, and the pool bytes on its emptiest and its
+        fullest device (a device of the mesh that holds no shard
+        counts 0). /debug/vars mesh.*, /metrics pilosa_mesh_*."""
+        per_device = dict.fromkeys(self.mesh.devices.flat, 0)
+        for shard in words.addressable_shards:
+            per_device[shard.device] = (per_device.get(shard.device, 0)
+                                        + int(shard.data.nbytes))
+        self.stats.set("devices", len(per_device))
+        self.stats.set("shard_bytes_min", min(per_device.values()))
+        self.stats.set("shard_bytes_max", max(per_device.values()))
+
     def _stage_once(self, key, num_slices: int) -> StagedView:
         index, frame, view = key
         fault.point("mesh.stage", index=index, frame=frame, view=view,
@@ -1164,6 +1177,7 @@ class MeshManager:
         self.stats.set("h2d_chunk_slices",
                        stage_io.get("h2d_chunk_slices", 0))
         self.stats.set("h2d_chunks", stage_io.get("h2d_chunks", 0))
+        self._note_placement(sharded.words)
         if "h2d_fallback" in stage_io:
             # build_sharded_index could not place shards per device and
             # shipped the whole pool through one sharded device_put.
@@ -2677,7 +2691,13 @@ class MeshManager:
         the launch and the readback have their own; `mesh_prepare` is
         the rest of this call (residual: each of those pauses it)."""
         with profile.residual("mesh_prepare"):
-            return self._count(index, shape, leaves, slices, num_slices)
+            out = self._count(index, shape, leaves, slices, num_slices)
+        prof = profile.current()
+        if prof is not None and out is not None:
+            # What the answer ran on: a ?profile=true reader sees a
+            # server that came up on one of four chips.
+            prof.tag(devices=int(self.mesh.devices.size))
+        return out
 
     def _count(self, index: str, shape, leaves, slices: Sequence[int],
                num_slices: int) -> Optional[int]:

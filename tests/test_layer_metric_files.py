@@ -64,7 +64,9 @@ def test_metric_file_reads_what_the_program_records(name):
     entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
     assert entry["better"] == spec["better"] == "lower"
     assert entry["source"] == spec["source"]
-    assert entry["workloads"] == ["seg-1b.lone1", "seg-1b.herd64"]
+    # Contains, not equals: a later cell whose reads take the same path
+    # is listed by appending to the end (seg-2b-x4.herd64, PR 29).
+    assert entry["workloads"][:2] == ["seg-1b.lone1", "seg-1b.herd64"]
 
 
 @pytest.mark.parametrize("name", sorted(WANT))

@@ -383,6 +383,47 @@ def test_four_chip_program_reduces_over_the_interconnect(chip, four,
         < 0.01 * pool_bytes
 
 
+@pytest.mark.parametrize("program,n", [("coarse", 2), ("coarse", 8),
+                                       ("fused", 8), ("apply_writes", 1)])
+def test_seg_2b_x4_programs_over_the_sharded_pool(four, program, n):
+    """What benchmarks/configs/seg-2b-x4 serves, at its size: 1,920
+    slices sharded over the 2x2, 480 a device. The herd's count_coarse
+    at batch 1 (xla, 2 and 8 leaves), the lone fused count, the write
+    scatter: a device is billed its quarter of the 2 GB pool once for
+    each of the program's n pool operands, and the counts join in
+    all-reduces."""
+    from pilosa_tpu.parallel import mesh as M
+
+    s2 = 1920
+    w, mask = four.sliced(np.uint32, CAP, 2048, s=s2), \
+        four.sliced(np.int32, s=s2)
+    if program == "coarse":
+        fn = M.compile_serve_count_coarse(four.mesh, nary("or", n), n, 1)
+        args = ((w,) * n, tuple(four.sliced(np.int32, s=s2)
+                                for _ in range(n)),
+                tuple(four.sliced(np.uint32, s=s2) for _ in range(n)), mask)
+    elif program == "fused":
+        fn = M.compile_serve_count_fused(four.mesh, nary("andnot", n), n)
+        args = ((w,) * n, four.repl(np.int32, n, s2, 16),
+                four.repl(np.uint32, n, s2, 16), four.repl(np.int32, s2))
+    else:
+        fn = M.compile_serve_apply_writes(four.mesh)
+        index = M.ShardedIndex(keys=four.sliced(np.int32, CAP, s=s2),
+                               words=w)
+        args = (index,) + tuple(four.sliced(t, 8, s=s2) for t in (
+            np.int32, np.int32, np.uint32, np.uint32))
+    text, mem = compiled(fn, *args)
+    quarter = s2 // 4 * CAP * 2048 * 4
+    assert quarter == 503_316_480
+    # The compiler bills every aliased leaf operand as a pool of its own.
+    assert n * quarter <= mem.argument_size_in_bytes \
+        < 1.01 * n * quarter + 2**20
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < 15.75 * 2**30
+    if program != "apply_writes":
+        assert "all-reduce" in text
+
+
 # -- what a refusal looks like ------------------------------------------------
 
 @pytest.mark.parametrize("leaves,slices,space", [(64, 64, "vmem"),
