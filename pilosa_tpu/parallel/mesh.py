@@ -1100,9 +1100,14 @@ def coarse_row_starts(keys_host: np.ndarray, dense_id: int):
     """Host-side COARSE eligibility check for one leaf row: when every
     slice holds the row's 16 containers as one contiguous, 16-aligned
     run (or holds none of them), the serving kernels can gather the row
-    as ONE (16*CONTAINER_WORDS)-word run per slice instead of 16
-    separate container gathers (the gain is not measured on the
-    attached chip).
+    as ONE run per slice, named by one start index instead of 16
+    container indices and 16 hit flags. What that buys under the xla
+    backend is the host side (an (S,) pair a leaf to resolve, cache and
+    upload) and the shared-read program's in-place slice; on the device
+    the coarse program gathers the same 16 containers the general one
+    does (_gather_leaf_rows). On a v5e at 960 slices a 2-leaf coarse
+    launch takes 1.55 ms and an 8-leaf one 6.09 ms (7.28 and 29.0 while
+    the row was read through a re-laid pool; chip runs, PR 30).
 
     This is the data-adaptive dispatch the reference does by container
     TYPE (roaring.go:1270-1351 array/bitmap kernel table) done instead
@@ -1145,20 +1150,21 @@ def coarse_row_starts(keys_host: np.ndarray, dense_id: int):
 
 
 def _gather_leaf_rows(words_t, start_t, valid_t, i):
-    """One coarse leaf's (S_local, 16*CONTAINER_WORDS) row runs: a
-    whole-row gather from the pool viewed as (S, cap/16, 16*W), zeroed
-    where the slice holds no part of the row (valid == 0). The coarse
-    counterpart of _gather_leaf_blocks."""
-    with jax.named_scope("gather_leaves"):
-        w = words_t[i]
-        s_l, cap = w.shape[0], w.shape[1]
-        wr = w.reshape(s_l, cap // ROW_SPAN, ROW_SPAN * w.shape[2])
-
-        def one(wrow, st):
-            return wrow[st]
-
-        g = jax.vmap(one)(wr, start_t[i])
-        return g * valid_t[i][:, None]
+    """One coarse leaf's (S_local*16, CONTAINER_WORDS) blocks: the row's
+    16 containers of every local slice, read from the pool in the
+    layout it was staged in. _gather_leaf_blocks fed the run's own
+    indices (start*16 + 0..15) and hit = valid, so a slice that holds
+    no part of the row (valid == 0) gathers zeros by the same rule as
+    an absent container. The pool is never viewed as (S, cap/16, 16*W):
+    on the chip the two minor dimensions are tiled, so that reshape is
+    no view but a copy of the whole pool for every leaf of every launch
+    (1 GB, 3.06 ms at 960 slices: 79% of seg-1b.herd64's device time in
+    the ledger's PR 29 lines; coarse_row_starts has the launch times
+    since)."""
+    span = jnp.arange(ROW_SPAN, dtype=jnp.int32)
+    idx = start_t[i][:, None] * ROW_SPAN + span[None, :]     # (S_l, 16)
+    hit = jnp.broadcast_to(valid_t[i][:, None], idx.shape)
+    return _gather_leaf_blocks((words_t[i],), (idx,), (hit,), 0)
 
 
 def _limb_psum(per_bs):
@@ -1199,10 +1205,11 @@ def compile_serve_count_coarse(mesh: Mesh, tree_shape, num_leaves: int,
                     words_t, start_flat[b * num_leaves:(b + 1) * num_leaves],
                     valid_flat[b * num_leaves:(b + 1) * num_leaves], i)
 
-            blk = fold_tree(tree, leaf)                       # (S_l, 16W)
+            blk = fold_tree(tree, leaf)                    # (S_l*16, W)
             with jax.named_scope("popcount"):
                 return lax.population_count(blk).sum(
-                    axis=1, dtype=jnp.uint32)
+                    axis=1, dtype=jnp.uint32).reshape(
+                        s_l, ROW_SPAN).sum(axis=1, dtype=jnp.uint32)
 
         per_slice = jnp.stack([one(b) for b in range(batch)])  # (B, S_l)
         per_slice = jnp.where(mask[None, :] != 0, per_slice, jnp.uint32(0))
@@ -1241,11 +1248,14 @@ def compile_serve_count_coarse_pallas(mesh: Mesh, tree_shape,
     general Pallas kernel's (L, S, 16) SMEM tables force slab
     launches that each pay a dispatch; the coarse form's
     per-(leaf, slice) state is ONE signed int, so any S fits one
-    launch). The XLA gather path materializes each gathered row copy
-    back to HBM before combining. Selected when the count backend
-    resolves to Pallas (PILOSA_TPU_COUNT_BACKEND, or the calibrated
-    "auto"); differential coverage runs in interpret mode on the CPU
-    mesh, and tests/test_tpu_compile.py compiles it for the chip."""
+    launch). The XLA gather path writes each leaf's gathered rows back
+    to HBM (S_local * 128 KB a leaf: 126 MB at 960 slices) before the
+    fold and the popcount read them again; since PR 30 that is all it
+    materializes, no longer a copy of the pool. Selected when the count
+    backend resolves to Pallas (PILOSA_TPU_COUNT_BACKEND, or the
+    calibrated "auto"); differential coverage runs in interpret mode on
+    the CPU mesh, and tests/test_tpu_compile.py compiles it for the
+    chip."""
     from ..ops.kernels import coarse_count_per_slice
 
     sig = json.dumps(_tree_signature(tree_shape))
@@ -1358,6 +1368,9 @@ def compile_serve_count_batch_shared(mesh: Mesh, tree_shape,
     device analog of the reference's per-fragment row cache serving
     many queries from one materialized row (fragment.go:332-367 +
     BitmapCache) — except the "cache" is one scan step's VMEM block.
+    The scan is bound by its 960 sequential steps, not by bytes: 16
+    pairs of 8 rows take 20.6 ms on a v5e, 21 us a step (62.0 ms until
+    PR 30, when the scan read from a re-laid copy of each pool).
 
     leaf_map is STATIC: leaf_map[b] gives, per leaf position of the
     tree, the unique-leaf index it reads. The compile cache key must
@@ -1376,25 +1389,26 @@ def compile_serve_count_batch_shared(mesh: Mesh, tree_shape,
 
     def per_shard(words_t, start_t, valid_t, mask):
         s_l = words_t[0].shape[0]
-        w = ROW_SPAN * words_t[0].shape[2]
-        wr_t = tuple(
-            wt.reshape(s_l, wt.shape[1] // ROW_SPAN, w) for wt in words_t)
         start_st = jnp.stack(start_t)            # (U, S_l)
         valid_st = jnp.stack(valid_t)            # (U, S_l)
 
         def step(acc, s):
-            # Gather each UNIQUE leaf's whole-row run for slice s —
-            # read once, used by every query below. The barrier is the
-            # load-bearing part: without it XLA is free to fuse (i.e.
-            # DUPLICATE) each cheap dynamic-slice gather into every
-            # consuming fold, re-reading HBM per query and silently
-            # degenerating this program to the plain batch's traffic.
-            # The barrier forces the U blocks to
-            # materialize once (U * 128 KB, VMEM-resident) before the
-            # B folds consume them.
+            # Slice each UNIQUE leaf's whole-row run for slice s out of
+            # the pool as it was staged (16 containers from start*16; a
+            # (S, cap/16, 16*W) view of the pool is a copy of it on the
+            # chip, see _gather_leaf_rows) — read once, used by every
+            # query below. The barrier is the load-bearing part:
+            # without it XLA is free to fuse (i.e. DUPLICATE) each
+            # cheap dynamic-slice gather into every consuming fold,
+            # re-reading HBM per query and silently degenerating this
+            # program to the plain batch's traffic. The barrier forces
+            # the U blocks to materialize once (U * 128 KB,
+            # VMEM-resident) before the B folds consume them.
             with jax.named_scope("gather_leaves"):
                 blocks = list(lax.optimization_barrier(tuple(
-                    wr_t[u][s, start_st[u, s]]
+                    lax.dynamic_slice(
+                        words_t[u], (s, start_st[u, s] * ROW_SPAN, 0),
+                        (1, ROW_SPAN, words_t[u].shape[2]))
                     * valid_st[u, s].astype(jnp.uint32)
                     for u in range(num_unique))))
 

@@ -1354,6 +1354,110 @@ class TestCoarseGather:
         got = q(e, "i", pql)[0]
         assert got == q(host, "i", pql)[0] == first + 1
 
+    # -- the xla coarse programs themselves, fed a pool directly ---------
+
+    PAIRS = ((0, 1), (0, 2), (1, 2), (2, 0))
+
+    @staticmethod
+    def coarse_program(program, mesh, tree, pairs):
+        """(fn, pool operands, the unique row each (start, valid) slot
+        reads, the queries) for the three shapes the serving layer
+        launches: a lone Count, a padded group of 16 and a shared-read
+        group."""
+        from pilosa_tpu.parallel import mesh as M
+
+        if program == "shared":
+            return (M.compile_serve_count_batch_shared(mesh, tree, pairs, 3),
+                    3, (0, 1, 2), pairs)
+        batch = 1 if program == "batch1" else 16
+        queries = (pairs * 4)[:batch]
+        return (M.compile_serve_count_coarse(mesh, tree, 2, batch),
+                2, tuple(r for qr in queries for r in qr), queries)
+
+    @pytest.mark.parametrize("program", ["batch1", "batch16", "shared"])
+    @pytest.mark.parametrize("cap", [32, 128])
+    @pytest.mark.parametrize("devices", [1, 4])
+    def test_coarse_rows_match_numpy(self, devices, cap, program):
+        """_gather_leaf_rows against numpy on a pool of random words: a
+        row's run starts somewhere else in every slice, one slice holds
+        no part of row 1 (valid == 0) and one is masked out."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from pilosa_tpu.parallel import mesh as M
+
+        s, w = 8, M.CONTAINER_WORDS
+        rng = np.random.default_rng(cap * 10 + devices)
+        pool = rng.integers(0, 2**32, (s, cap, w), dtype=np.uint32)
+        starts = rng.integers(0, cap // M.ROW_SPAN, (3, s)).astype(np.int32)
+        valid = np.ones((3, s), np.uint32)
+        valid[1, 5] = 0
+        mask = np.ones(s, np.int32)
+        mask[2] = 0
+        assert (starts != starts[:, :1]).any(axis=1).all()  # not uniform
+
+        mesh = M.default_mesh(devices)
+        put = lambda a: jax.device_put(  # noqa: E731
+            a, NamedSharding(mesh, P(M.SLICE_AXIS)))
+        tree = ["andnot", ["leaf", 0], ["leaf", 1]]
+        fn, n_words, slot_rows, queries = self.coarse_program(
+            program, mesh, tree, self.PAIRS)
+        limbs = np.asarray(fn((put(pool),) * n_words,
+                              tuple(put(starts[r]) for r in slot_rows),
+                              tuple(put(valid[r]) for r in slot_rows),
+                              put(mask)))
+
+        def run(r, sl):
+            st = int(starts[r, sl]) * M.ROW_SPAN
+            return pool[sl, st:st + M.ROW_SPAN] * valid[r, sl]
+
+        assert limbs.shape == (2, len(queries))
+        for j, (a, b) in enumerate(queries):
+            want = sum(int(np.bitwise_count(run(a, sl) & ~run(b, sl)).sum())
+                       for sl in range(s) if mask[sl])
+            assert M.combine_count(limbs[:, j]) == want, (j, a, b)
+
+    @pytest.mark.parametrize("program", ["batch1", "batch16", "shared"])
+    def test_coarse_programs_never_reshape_the_pool_minor(self, program):
+        """On the chip the pool's two minor dimensions are tiled, so a
+        reshape that changes the last one is a copy of the whole pool,
+        for every leaf of every launch (PR 30 took it out). The chip's
+        compiler says so in tests/test_tpu_compile.py; this guard runs
+        where no topology can be described: in the programs' jaxprs
+        every reshape of a pool-shaped operand keeps the last
+        dimension."""
+        import jax
+        from pilosa_tpu.parallel import mesh as M
+
+        s, cap, w = 8, 32, M.CONTAINER_WORDS
+        mesh = M.default_mesh(1)
+        fn, n_words, slot_rows, _ = self.coarse_program(
+            program, mesh, ["and", ["leaf", 0], ["leaf", 1]], self.PAIRS)
+        sds = jax.ShapeDtypeStruct
+        jaxpr = jax.make_jaxpr(fn)(
+            (sds((s, cap, w), np.uint32),) * n_words,
+            tuple(sds((s,), np.int32) for _ in slot_rows),
+            tuple(sds((s,), np.uint32) for _ in slot_rows),
+            sds((s,), np.int32))
+
+        def eqns(jp):
+            for eqn in jp.eqns:
+                yield eqn
+                for v in eqn.params.values():
+                    for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            yield from eqns(sub)
+
+        seen = list(eqns(jaxpr.jaxpr))
+        reads = [e for e in seen if any(
+            getattr(v.aval, "shape", ()) == (s, cap, w) for v in e.invars)]
+        assert reads, "no equation reads the pool: the walk is broken"
+        assert any(e.primitive.name in ("gather", "dynamic_slice")
+                   for e in seen)
+        for e in reads:
+            if e.primitive.name == "reshape":
+                assert e.outvars[0].aval.shape[-1] == w, e
+
 
 class TestTopNThresholdDivergence:
     """The DOCUMENTED deviation (serve.top_n docstring): the device

@@ -87,9 +87,9 @@ class Chip:
     def pool(self, cap=CAP):
         return self.sliced(np.uint32, cap, 2048)
 
-    def starts_valid(self, n):
-        return (tuple(self.sliced(np.int32) for _ in range(n)),
-                tuple(self.sliced(np.uint32) for _ in range(n)))
+    def starts_valid(self, n, s=S):
+        return (tuple(self.sliced(np.int32, s=s) for _ in range(n)),
+                tuple(self.sliced(np.uint32, s=s) for _ in range(n)))
 
     def idx_hit(self, n):
         return (tuple(self.sliced(np.int32, 16) for _ in range(n)),
@@ -422,6 +422,80 @@ def test_seg_2b_x4_programs_over_the_sharded_pool(four, program, n):
         < 15.75 * 2**30
     if program != "apply_writes":
         assert "all-reduce" in text
+
+
+# -- the xla coarse programs read the pool where it lies ----------------------
+
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
+                "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+                "f64": 8}
+# Instructions that hand a buffer on without moving a byte of it.
+_NO_BYTES_MOVE = {"parameter", "bitcast", "tuple", "get-tuple-element",
+                  "while"}
+
+
+def instructions(text):
+    """(name, opcode, bytes of the largest array in the result) of every
+    instruction of an HLO module's text, fused computations included."""
+    import re
+
+    for line in text.splitlines():
+        head, eq, rest = line.partition(" = ")
+        if not eq or not head.strip().startswith(("%", "ROOT ")):
+            continue
+        if rest.startswith("("):                 # a tuple-typed result
+            depth = 0
+            for end, ch in enumerate(rest):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    break
+            typ, rest = rest[:end + 1], rest[end + 1:].lstrip()
+        else:
+            typ, _, rest = rest.partition(" ")
+        sizes = [_DTYPE_BYTES[dt] * int(np.prod([int(d) for d in
+                                                 dims.split(",") if d] or [1]))
+                 for dt, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]", typ)
+                 if dt in _DTYPE_BYTES]
+        yield head.split()[-1], rest.partition("(")[0], max(sizes, default=0)
+
+
+@pytest.mark.parametrize("program,leaves,batch,devices", [
+    ("coarse", 2, 1, 1), ("coarse", 8, 1, 1), ("coarse", 2, 16, 1),
+    ("shared_28_of_8", 8, 28, 1), ("coarse", 2, 1, 4), ("coarse", 8, 1, 4)])
+def test_xla_coarse_programs_do_not_copy_the_pool(chip, four, program,
+                                                  leaves, batch, devices):
+    """Until PR 30 both programs viewed the pool as (S, cap/16, 16*W) to
+    pick a row with one index; the pool's two minor dimensions are
+    tiled T(8,128) on the chip, so XLA made that view a copy of the
+    whole pool for every leaf operand of every launch (`reshape.N
+    u32[960,8,32768]`, 1 GB and 3.06 ms each: 79% of seg-1b.herd64's
+    device time in the ledger's PR 29 line). Held here: no instruction
+    that moves bytes has a result as large as one device's share of a
+    pool operand, and the program's temporaries are the gathered rows
+    (leaves x S_local x 128 KB) at batch 1, under one pool at batch 16
+    and under 16 MB in the shared-read scan, which slices the pool in
+    place. One chip at 960 slices; the 2x2 at seg-2b-x4's 1,920."""
+    from pilosa_tpu.parallel import mesh as M
+
+    c = chip if devices == 1 else four
+    s = S * 2 if devices == 4 else S
+    w, mask = c.sliced(np.uint32, CAP, 2048, s=s), c.sliced(np.int32, s=s)
+    slots = leaves if program != "coarse" else leaves * batch
+    args = ((w,) * leaves, *c.starts_valid(slots, s=s), mask)
+    if program == "coarse":
+        fn = M.compile_serve_count_coarse(c.mesh, nary("and", leaves),
+                                          leaves, batch)
+    else:
+        fn = M.compile_serve_count_batch_shared(c.mesh, AND2, PAIRS28, leaves)
+    text, mem = compiled(fn, *args)
+    shard = s // devices * CAP * 2048 * 4
+    copies = [(name, op, size) for name, op, size in instructions(text)
+              if size >= shard and op not in _NO_BYTES_MOVE]
+    assert not copies, copies
+    rows = leaves * (s // devices) * 16 * 2048 * 4
+    limit = (16 * 2**20 if program != "coarse"
+             else 1.1 * rows if batch == 1 else shard)
+    assert mem.temp_size_in_bytes < limit, (mem.temp_size_in_bytes, limit)
 
 
 # -- what a refusal looks like ------------------------------------------------
