@@ -667,8 +667,7 @@ def run_mesh(args, emit, n_chips: int) -> None:
 
     from pilosa_tpu.core import Holder
     from pilosa_tpu.executor import Executor
-    from pilosa_tpu.parallel.mesh import (compile_serve_count_fused,
-                                          default_mesh)
+    from pilosa_tpu.parallel.mesh import compile_serve_count, default_mesh
     from pilosa_tpu.parallel.plan import _lower_tree, _tree_signature
     from pilosa_tpu.parallel.serve import MeshManager
     from pilosa_tpu.pql import parse_string
@@ -756,8 +755,9 @@ def run_mesh(args, emit, n_chips: int) -> None:
                     words_t, idx_all, hit_all, first = \
                         mgr._stage_leaves_host(INDEX, leaves, args.slices)
                     mask = mgr._mask_for(first, slices)
-                hlo = compile_serve_count_fused(
-                    mgr.mesh, _tree_signature(lowered), len(leaves)).lower(
+                hlo = compile_serve_count(
+                    mgr.mesh, _tree_signature(lowered), len(leaves),
+                    host_meta=True).lower(
                     words_t, idx_all, hit_all, mask).compile().as_text()
                 check("all-reduce" in hlo,
                       "the compiled count program holds no all-reduce")

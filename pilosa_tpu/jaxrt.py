@@ -3,8 +3,8 @@ kept, what compiling has cost so far, and which devices answered.
 
 One place for all three because every entry point that compiles needs
 the same answers before its first compile: the server
-(`ctl.main server`), `chip_smoke.py`, `bench.py` and
-`tools/multichip_bench.py` call `setup_compile_cache()`; `/debug/vars`
+(`ctl.main server`) and `chip_smoke.py` call `setup_compile_cache()`;
+`/debug/vars`
 serves `snapshot()` so a client that never imports JAX can still name
 the platform its answers came from.
 

@@ -10,8 +10,7 @@ memo cache key on), so two queries differing only in row ids aggregate
 into one row.
 
 Recording is on the query fast path, so it is one small lock hold and
-a handful of dict increments — bench.py's `fleet_overhead` section
-guards the delta at < 1% of the lone-query fast path. Retention is a
+a handful of dict increments. Retention is a
 recency ring (LRU of `ring` shapes): a signature unseen since the ring
 wrapped is evicted, and the eviction count is exported so a churning
 shape population is visible.

@@ -16,7 +16,7 @@ one ContextVar read and one module-global test returning a shared
 no-op; byte counters early-return. Device phases are only real
 when a profile is active — callers gate their `block_until_ready`
 bracketing on `current() is not None`, so the async-dispatch fast path
-is byte-identical when profiling is off (bench.py guards < 2%).
+is byte-identical when profiling is off.
 
 Phase accounting is a per-phase *union of intervals*: each phase keeps
 an active-entry depth, and only the outermost enter/exit pair (across

@@ -8,7 +8,7 @@ Design constraints, in order:
    unconditionally; when no trace is active that is one ContextVar
    read returning a shared no-op singleton. The serving fast path
    (PR 1's fused lone count) must not pay for observability it isn't
-   using — bench.py guards the traced/untraced delta at < 3%.
+   using.
 2. Thread-safe by construction, not by locking the hot path. Span
    ids come from itertools.count (atomic in CPython), span lists grow
    by list.append (atomic under the GIL), and the only real lock is

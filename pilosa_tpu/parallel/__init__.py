@@ -49,8 +49,6 @@ _MESH_NAMES = (
     "compile_mesh_topn",
     "compile_serve_apply_writes",
     "compile_serve_count",
-    "compile_serve_count_batch",
-    "compile_serve_count_coarse",
     "compile_serve_count_batch_shared",
     "coarse_row_starts",
     "compile_serve_row_counts",
@@ -84,8 +82,6 @@ __all__ = [
     "combine_count",
     "compile_serve_apply_writes",
     "compile_serve_count",
-    "compile_serve_count_batch",
-    "compile_serve_count_coarse",
     "compile_serve_count_batch_shared",
     "coarse_row_starts",
     "compile_serve_row_counts",

@@ -1105,7 +1105,7 @@ def coarse_row_starts(keys_host: np.ndarray, dense_id: int):
     backend is the host side (an (S,) pair a leaf to resolve, cache and
     upload) and the shared-read program's in-place slice; on the device
     the coarse program gathers the same 16 containers the general one
-    does (_gather_leaf_rows). On a v5e at 960 slices a 2-leaf coarse
+    does (_row_run_blocks). On a v5e at 960 slices a 2-leaf coarse
     launch takes 1.55 ms and an 8-leaf one 6.09 ms (7.28 and 29.0 while
     the row was read through a re-laid pool; chip runs, PR 30).
 
@@ -1149,22 +1149,20 @@ def coarse_row_starts(keys_host: np.ndarray, dense_id: int):
     return starts, present.astype(np.uint32)
 
 
-def _gather_leaf_rows(words_t, start_t, valid_t, i):
-    """One coarse leaf's (S_local*16, CONTAINER_WORDS) blocks: the row's
-    16 containers of every local slice, read from the pool in the
-    layout it was staged in. _gather_leaf_blocks fed the run's own
-    indices (start*16 + 0..15) and hit = valid, so a slice that holds
-    no part of the row (valid == 0) gathers zeros by the same rule as
-    an absent container. The pool is never viewed as (S, cap/16, 16*W):
-    on the chip the two minor dimensions are tiled, so that reshape is
-    no view but a copy of the whole pool for every leaf of every launch
-    (1 GB, 3.06 ms at 960 slices: 79% of seg-1b.herd64's device time in
-    the ledger's PR 29 lines; coarse_row_starts has the launch times
+def _row_run_blocks(start, valid):
+    """One coarse leaf's (start, valid) runs as the (idx, hit) that
+    _gather_leaf_blocks reads: the run's own container indices
+    (start*16 + 0..15) and hit = valid, so a slice that holds no part
+    of the row (valid == 0) gathers zeros by the same rule as an absent
+    container. The pool is never viewed as (S, cap/16, 16*W): on the
+    chip the two minor dimensions are tiled, so that reshape is no view
+    but a copy of the whole pool for every leaf of every launch (1 GB,
+    3.06 ms at 960 slices: 79% of seg-1b.herd64's device time in the
+    ledger's PR 29 lines; coarse_row_starts has the launch times
     since)."""
     span = jnp.arange(ROW_SPAN, dtype=jnp.int32)
-    idx = start_t[i][:, None] * ROW_SPAN + span[None, :]     # (S_l, 16)
-    hit = jnp.broadcast_to(valid_t[i][:, None], idx.shape)
-    return _gather_leaf_blocks((words_t[i],), (idx,), (hit,), 0)
+    idx = start[:, None] * ROW_SPAN + span[None, :]          # (S_l, 16)
+    return idx, jnp.broadcast_to(valid[:, None], idx.shape)
 
 
 def _limb_psum(per_bs):
@@ -1181,29 +1179,62 @@ def _limb_psum(per_bs):
     return jnp.stack([lo, hi])
 
 
-def compile_serve_count_coarse(mesh: Mesh, tree_shape, num_leaves: int,
-                               batch: int = 1):
-    """Jit a masked Count (batch >= 1) where EVERY leaf is a coarse
-    whole-row run (coarse_row_starts eligible). Signature mirrors
-    compile_serve_count_batch with (starts, valid) per leaf instead of
-    (idx, hit):
-      fn(words_t (L,), start_flat (batch*L,) of (S,) int32,
-         valid_flat (batch*L,) of (S,) uint32, mask (S,))
-      -> (2, batch) [lo, hi] limb columns ((2,) squeezed is NOT done —
-      batch=1 still returns (2, 1); callers index [:, 0]).
+def compile_serve_count(mesh: Mesh, tree_shape, num_leaves: int,
+                        batch: int = 1, runs: bool = False,
+                        host_meta: bool = False):
+    """Jit the XLA masked Count: `batch` independent queries of one
+    tree shape over PER-LEAF pools (a served tree may span frames and
+    time-quantum views), evaluated in ONE device program. Dispatch and
+    readback are a fixed cost per program, so the serving layer
+    coalesces concurrent same-shape queries (serve.MeshManager batch
+    loop) and amortizes it. Every form is one body: resolve each leaf
+    to (idx, hit), fold the tree over _gather_leaf_blocks, popcount,
+    mask, _limb_psum. The parameters say what differs:
+
+    runs: how a leaf is named. False: by its 16 containers, (S, 16)
+      int32 WITHIN-SLICE indices and (S, 16) uint32 presence flags
+      (resolve_row_indices). True: by one whole-row run a slice, (S,)
+      int32 row-run index and (S,) uint32 presence flag
+      (coarse_row_starts; every leaf must be eligible).
+    host_meta: where that metadata and the mask come from. False:
+      sharded device arrays the caller caches per (view, row), as flat
+      row-major [b][l] tuples of batch*num_leaves. True (batch == 1,
+      the lone query): REPLICATED host arrays stacked (L, S, ...) that
+      ride the one jitted call's argument transfer, each shard slicing
+      out its local block in-program — the chained path uploads each
+      leaf's metadata and the mask as device operations of their own
+      before the launch, here the whole query is one dispatch + one
+      fetch. At 960 slices the metadata is ~120 KB a leaf, noise
+      against the pool.
+
+    Returns fn(words_t (L,) of (S, cap_i, 2048) sharded words, meta_a,
+    meta_b, mask (S,) int32 slice-ownership) -> (2, batch) [lo, hi]
+    limb columns, (2,) with host_meta; combine with combine_count.
+    Per-slice counts are uint32 (safe to 2^32 bits/slice); the lo-limb
+    sum is int32-safe to 32k slices (~34T columns). The jitted
+    function's name is what a device trace prints: count_fused
+    (host_meta), count_coarse (runs), count_batch.
     """
+    assert batch == 1 or not host_meta, "host metadata is the lone form"
     sig = json.dumps(_tree_signature(tree_shape))
     tree = json.loads(sig)
     from ..ops.bitops import fold_tree
 
-    def per_shard(words_t, start_flat, valid_flat, mask):
+    def per_shard(words_t, meta_a, meta_b, mask):
         s_l = words_t[0].shape[0]
+        if host_meta:
+            off = lax.axis_index(SLICE_AXIS) * s_l
+            meta_a = lax.dynamic_slice_in_dim(meta_a, off, s_l, axis=1)
+            meta_b = lax.dynamic_slice_in_dim(meta_b, off, s_l, axis=1)
+            mask = lax.dynamic_slice_in_dim(mask, off, s_l, axis=0)
 
         def one(b):
             def leaf(i):
-                return _gather_leaf_rows(
-                    words_t, start_flat[b * num_leaves:(b + 1) * num_leaves],
-                    valid_flat[b * num_leaves:(b + 1) * num_leaves], i)
+                k = b * num_leaves + i
+                idx, hit = meta_a[k], meta_b[k]
+                if runs:
+                    idx, hit = _row_run_blocks(idx, hit)
+                return _gather_leaf_blocks((words_t[i],), (idx,), (hit,), 0)
 
             blk = fold_tree(tree, leaf)                    # (S_l*16, W)
             with jax.named_scope("popcount"):
@@ -1213,34 +1244,31 @@ def compile_serve_count_coarse(mesh: Mesh, tree_shape, num_leaves: int,
 
         per_slice = jnp.stack([one(b) for b in range(batch)])  # (B, S_l)
         per_slice = jnp.where(mask[None, :] != 0, per_slice, jnp.uint32(0))
-        lo = lax.psum(
-            (per_slice & jnp.uint32(0xFFFF)).astype(jnp.int32).sum(axis=1),
-            SLICE_AXIS)
-        hi = lax.psum((per_slice >> 16).astype(jnp.int32).sum(axis=1),
-                      SLICE_AXIS)
-        return jnp.stack([lo, hi])
+        limbs = _limb_psum(per_slice)
+        return limbs[:, 0] if host_meta else limbs
 
+    meta = P() if host_meta else (P(SLICE_AXIS),) * (batch * num_leaves)
     fn = shard_map(
         per_shard,
         mesh=mesh,
-        in_specs=((P(SLICE_AXIS),) * num_leaves,
-                  (P(SLICE_AXIS),) * (batch * num_leaves),
-                  (P(SLICE_AXIS),) * (batch * num_leaves),
-                  P(SLICE_AXIS)),
+        in_specs=((P(SLICE_AXIS),) * num_leaves, meta, meta,
+                  P() if host_meta else P(SLICE_AXIS)),
         out_specs=P(),
     )
 
-    @jax.jit
-    def count_coarse(words_t, start_flat, valid_flat, mask):
-        return fn(words_t, start_flat, valid_flat, mask)
+    def count(words_t, meta_a, meta_b, mask):
+        return fn(words_t, meta_a, meta_b, mask)
 
-    return count_coarse
+    count.__name__ = count.__qualname__ = (
+        "count_fused" if host_meta
+        else "count_coarse" if runs else "count_batch")
+    return jax.jit(count)
 
 
 def compile_serve_count_coarse_pallas(mesh: Mesh, tree_shape,
                                       num_leaves: int,
                                       interpret: bool = False):
-    """Pallas twin of compile_serve_count_coarse (batch=1): identical
+    """Pallas twin of compile_serve_count(runs=True) at batch 1: identical
     call contract — fn(words_t (L,), start_flat (L,) of (S,) int32,
     valid_flat (L,) of (S,) uint32, mask (S,)) -> (2, 1) limb column —
     but the fold+popcount runs as ONE pallas_call per shard streaming
@@ -1271,12 +1299,7 @@ def compile_serve_count_coarse_pallas(mesh: Mesh, tree_shape,
         per_slice = coarse_count_per_slice(
             tuple(words_t), starts, tree,
             interpret=interpret)[0].astype(jnp.uint32)
-        lo = lax.psum(
-            (per_slice & jnp.uint32(0xFFFF)).astype(jnp.int32).sum(),
-            SLICE_AXIS)
-        hi = lax.psum((per_slice >> 16).astype(jnp.int32).sum(),
-                      SLICE_AXIS)
-        return jnp.stack([lo, hi]).reshape(2, 1)
+        return _limb_psum(per_slice[None, :])
 
     fn = shard_map(
         per_shard,
@@ -1357,7 +1380,7 @@ def compile_serve_count_batch_shared(mesh: Mesh, tree_shape,
     shape over U unique coarse leaves, reading each unique leaf's data
     ONCE per slice instead of once per query.
 
-    The plain batch program (compile_serve_count_coarse) makes every
+    The plain batch program (compile_serve_count, runs=True) makes every
     query gather its own leaves: a batch of B two-leaf queries over U
     unique rows moves B*2 row-reads of HBM traffic. Here a lax.scan
     walks the local slices; each step gathers the U unique row-runs for
@@ -1379,7 +1402,7 @@ def compile_serve_count_batch_shared(mesh: Mesh, tree_shape,
     Returns fn(words_t (U,), start_t (U,) of (S,) int32 row-run
     indices, valid_t (U,) of (S,) uint32, mask (S,) int32)
     -> (2, B) [lo, hi] limb columns (same contract as
-    compile_serve_count_coarse).
+    compile_serve_count's runs form).
     """
     sig = json.dumps(_tree_signature(tree_shape))
     tree = json.loads(sig)
@@ -1396,7 +1419,7 @@ def compile_serve_count_batch_shared(mesh: Mesh, tree_shape,
             # Slice each UNIQUE leaf's whole-row run for slice s out of
             # the pool as it was staged (16 containers from start*16; a
             # (S, cap/16, 16*W) view of the pool is a copy of it on the
-            # chip, see _gather_leaf_rows) — read once, used by every
+            # chip, see _row_run_blocks) — read once, used by every
             # query below. The barrier is the load-bearing part:
             # without it XLA is free to fuse (i.e. DUPLICATE) each
             # cheap dynamic-slice gather into every consuming fold,
@@ -1457,7 +1480,7 @@ def compile_serve_count_batch_shared(mesh: Mesh, tree_shape,
 def compile_serve_count_coarse_pallas_batch(mesh: Mesh, tree_shape,
                                             num_leaves: int, batch: int,
                                             interpret: bool = False):
-    """Pallas twin of compile_serve_count_coarse for batch > 1 — the
+    """Pallas twin of compile_serve_count(runs=True) for batch > 1 — the
     plain (no leaf sharing assumed) herd-group program. Same call
     contract: fn(words_t (L,), start_flat (B*L,) of (S,) int32,
     valid_flat (B*L,) of (S,) uint32, mask (S,)) -> (2, B).
@@ -1621,184 +1644,6 @@ def _src_block_per_container(keys, src_blk, s_l):
     valid = keys != INVALID_KEY
     sub = jnp.where(valid, keys % ROW_SPAN, 0)
     return jnp.take_along_axis(src_blk3, sub[:, :, None], axis=1), valid
-
-
-def compile_serve_count(mesh: Mesh, tree_shape, num_leaves: int):
-    """Jit a masked Count over a bitmap-op tree with PER-LEAF pools and
-    HOST-RESOLVED container indices.
-
-    Each leaf is one flat gather from its own view's pool — a served
-    tree may span frames and time-quantum views. Returns
-      fn(words_t: tuple per leaf of (S, cap_i, 2048) sharded words,
-         idx_t:   tuple per leaf of (S, 16) int32 flat gather indices
-                  (resolve_row_indices, cached on device by the caller),
-         hit_t:   tuple per leaf of (S, 16) uint32 presence masks,
-         mask (S,) int32 slice-ownership mask)
-      -> (lo, hi) int32 limbs; combine with combine_count.
-
-    Per-slice counts are uint32 (safe to 2^32 bits/slice); the lo-limb
-    sum is int32-safe to 32k slices (~34T columns). Returns one (2,)
-    [lo, hi] array (see combine_count).
-    """
-    sig = json.dumps(_tree_signature(tree_shape))
-    tree = json.loads(sig)
-    from ..ops.bitops import fold_tree
-
-    def per_shard(words_t, idx_t, hit_t, mask):
-        s_l = words_t[0].shape[0]
-
-        def leaf(i):
-            return _gather_leaf_blocks(words_t, idx_t, hit_t, i)
-
-        blk = fold_tree(tree, leaf)                       # (S*16, 2048)
-        with jax.named_scope("popcount"):
-            pc = lax.population_count(blk)
-            per_slice = pc.sum(axis=1, dtype=jnp.uint32).reshape(
-                s_l, ROW_SPAN).sum(axis=1, dtype=jnp.uint32)
-        per_slice = jnp.where(mask != 0, per_slice, jnp.uint32(0))
-        lo = lax.psum((per_slice & jnp.uint32(0xFFFF)).astype(jnp.int32).sum(),
-                      SLICE_AXIS)
-        hi = lax.psum((per_slice >> 16).astype(jnp.int32).sum(), SLICE_AXIS)
-        return jnp.stack([lo, hi])
-
-    fn = shard_map(
-        per_shard,
-        mesh=mesh,
-        in_specs=((P(SLICE_AXIS),) * num_leaves,
-                  (P(SLICE_AXIS),) * num_leaves,
-                  (P(SLICE_AXIS),) * num_leaves,
-                  P(SLICE_AXIS)),
-        out_specs=P(),
-    )
-
-    @jax.jit
-    def count(words_t, idx_t, hit_t, mask):
-        return fn(words_t, idx_t, hit_t, mask)
-
-    return count
-
-
-def compile_serve_count_fused(mesh: Mesh, tree_shape, num_leaves: int):
-    """compile_serve_count with HOST-ARG metadata: the whole query is
-    ONE dispatch.
-
-    The chained serving path uploads each leaf's gather metadata as its
-    own jax.device_put (idx, hit, possibly coarse starts) and the mask
-    as another before launching the count program — a distinct
-    cold-metadata query pays leaf-count + 2 separate device operations,
-    each a dispatch of its own. Here idx/hit/mask are taken
-    as REPLICATED host arrays that ride the one jitted call's argument
-    transfer, and each shard slices out its local block in-program, so
-    a lone query is exactly one dispatch + one fetch.
-
-    Returns
-      fn(words_t: tuple per leaf of (S, cap_i, 2048) sharded words,
-         idx_all (L, S, 16) int32, hit_all (L, S, 16) uint32 — stacked
-         resolve_row_indices outputs, host numpy is fine,
-         mask (S,) int32 host slice-ownership mask)
-      -> (2,) [lo, hi] limbs; combine with combine_count.
-
-    The (L, S, 16) metadata is replicated to every device — at 960
-    slices that is ~120 KB/leaf, noise against the pool itself — and
-    the per-shard dynamic_slice is free relative to the gathers it
-    feeds. Compiled programs are cached by the serving layer's
-    compiled-plan LRU keyed on (tree shape, fragment widths, backend).
-    """
-    sig = json.dumps(_tree_signature(tree_shape))
-    tree = json.loads(sig)
-    from ..ops.bitops import fold_tree
-
-    def per_shard(words_t, idx_all, hit_all, mask):
-        s_l = words_t[0].shape[0]
-        off = lax.axis_index(SLICE_AXIS) * s_l
-        idx_l = lax.dynamic_slice_in_dim(idx_all, off, s_l, axis=1)
-        hit_l = lax.dynamic_slice_in_dim(hit_all, off, s_l, axis=1)
-        mask_l = lax.dynamic_slice_in_dim(mask, off, s_l, axis=0)
-
-        def leaf(i):
-            return _gather_leaf_blocks(words_t, idx_l, hit_l, i)
-
-        blk = fold_tree(tree, leaf)
-        with jax.named_scope("popcount"):
-            pc = lax.population_count(blk)
-            per_slice = pc.sum(axis=1, dtype=jnp.uint32).reshape(
-                s_l, ROW_SPAN).sum(axis=1, dtype=jnp.uint32)
-        per_slice = jnp.where(mask_l != 0, per_slice, jnp.uint32(0))
-        lo = lax.psum((per_slice & jnp.uint32(0xFFFF)).astype(jnp.int32).sum(),
-                      SLICE_AXIS)
-        hi = lax.psum((per_slice >> 16).astype(jnp.int32).sum(), SLICE_AXIS)
-        return jnp.stack([lo, hi])
-
-    fn = shard_map(
-        per_shard,
-        mesh=mesh,
-        in_specs=((P(SLICE_AXIS),) * num_leaves, P(), P(), P()),
-        out_specs=P(),
-    )
-
-    @jax.jit
-    def count_fused(words_t, idx_all, hit_all, mask):
-        return fn(words_t, idx_all, hit_all, mask)
-
-    return count_fused
-
-
-def compile_serve_count_batch(mesh: Mesh, tree_shape, num_leaves: int,
-                              batch: int):
-    """Batched compile_serve_count: `batch` independent queries of the
-    same tree shape evaluate in ONE device program.
-
-    Dispatch and readback are a fixed cost per program, so the
-    serving layer coalesces concurrent same-shape queries
-    (serve.MeshManager batch loop) and amortizes it. Returns
-      fn(words_t (L,), idx_flat (batch*L,), hit_flat (batch*L,),
-         mask (S,)) -> (2, batch) [lo, hi] limb columns
-    where idx_flat/hit_flat are row-major [b][l] per-leaf (S, 16)
-    arrays (resolve_row_indices outputs).
-    """
-    sig = json.dumps(_tree_signature(tree_shape))
-    tree = json.loads(sig)
-    from ..ops.bitops import fold_tree
-
-    def per_shard(words_t, idx_flat, hit_flat, mask):
-        s_l = words_t[0].shape[0]
-
-        def one(b):
-            def leaf(i):
-                return _gather_leaf_blocks(
-                    words_t, idx_flat[b * num_leaves:(b + 1) * num_leaves],
-                    hit_flat[b * num_leaves:(b + 1) * num_leaves], i)
-
-            blk = fold_tree(tree, leaf)
-            with jax.named_scope("popcount"):
-                return lax.population_count(blk).sum(
-                    axis=1, dtype=jnp.uint32).reshape(
-                        s_l, ROW_SPAN).sum(axis=1, dtype=jnp.uint32)
-
-        per_slice = jnp.stack([one(b) for b in range(batch)])  # (B, S_l)
-        per_slice = jnp.where(mask[None, :] != 0, per_slice, jnp.uint32(0))
-        lo = lax.psum(
-            (per_slice & jnp.uint32(0xFFFF)).astype(jnp.int32).sum(axis=1),
-            SLICE_AXIS)
-        hi = lax.psum((per_slice >> 16).astype(jnp.int32).sum(axis=1),
-                      SLICE_AXIS)
-        return jnp.stack([lo, hi])
-
-    fn = shard_map(
-        per_shard,
-        mesh=mesh,
-        in_specs=((P(SLICE_AXIS),) * num_leaves,
-                  (P(SLICE_AXIS),) * (batch * num_leaves),
-                  (P(SLICE_AXIS),) * (batch * num_leaves),
-                  P(SLICE_AXIS)),
-        out_specs=P(),
-    )
-
-    @jax.jit
-    def count_batch(words_t, idx_flat, hit_flat, mask):
-        return fn(words_t, idx_flat, hit_flat, mask)
-
-    return count_batch
 
 
 def compile_serve_row_counts_src(mesh: Mesh, tree_shape, num_leaves: int,

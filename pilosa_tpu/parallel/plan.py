@@ -115,7 +115,7 @@ def _compiled_count(sig: str):
 
 class CompiledPlanCache:
     """LRU of fused single-dispatch serving programs (the lowered
-    PQL-tree → one-XLA-call fast path, mesh.compile_serve_count_fused).
+    PQL-tree → one-XLA-call fast path, mesh.compile_serve_count, host_meta).
 
     Keyed by (tree signature, leaf count, fragment widths — the
     per-leaf staged pool shapes — and backend): jit already keys

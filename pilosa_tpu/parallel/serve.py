@@ -34,7 +34,7 @@ import queue
 import threading
 import time
 from collections import OrderedDict
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -64,10 +64,10 @@ from .mesh import (
     split_bitmaps_by_format,
     compile_serve_apply_writes,
     compile_serve_count,
-    compile_serve_count_batch,
-    compile_serve_count_fused,
     compile_serve_count_batch_shared,
-    compile_serve_count_coarse,
+    compile_serve_count_coarse_pallas,
+    compile_serve_count_coarse_pallas_batch,
+    compile_serve_count_coarse_pallas_uniform,
     compile_serve_row_counts,
     compile_serve_row_counts_src,
     compile_serve_row_counts_tanimoto,
@@ -373,6 +373,17 @@ class _CountRequest:
         return (sig, tuple(id(w) for w in words_t), id(dev_mask))
 
 
+class _Picked(NamedTuple):
+    """What MeshManager._pick_count decided for one deduped group."""
+
+    kind: str                       # general | coarse | uniform | shared
+    width: int                      # columns the program computes
+    program: Callable[[], Callable]  # get-or-compile, run by the launch
+    args: tuple
+    order: List["_CountRequest"]    # request of each leading column
+    counters: Tuple[Tuple[str, int], ...]
+
+
 class MeshManager:
     """Stages holder views onto the device mesh and serves queries.
 
@@ -427,9 +438,10 @@ class MeshManager:
         # peek (stage_infeasible), validated against MUTATION_EPOCH —
         # the O(slices) container-count walk must not run per query.
         self._infeasible_cache: "OrderedDict[tuple, tuple]" = OrderedDict()
-        self._count_fns: Dict[Tuple[str, int], object] = {}
-        self._batch_fns: Dict[tuple, object] = {}
-        self._coarse_fns: Dict[tuple, object] = {}
+        # Compiled count programs, keyed on what _pick_count decided:
+        # (kind, sig, leaves, width, backend, uniform). See
+        # _count_program.
+        self._count_programs: Dict[tuple, object] = {}
         # Shared-read batch programs keyed on (sig, leaf_map, U): used
         # when ALREADY compiled; compiled in the background the first
         # time a composition is seen (policy below) so hot repeated
@@ -467,7 +479,7 @@ class MeshManager:
         # restage per query. Guarded by _mu.
         self._dense_pins: set = set()
         # Fused single-dispatch count programs (mesh.
-        # compile_serve_count_fused), LRU-keyed on (tree shape, leaf
+        # compile_serve_count, host_meta), LRU-keyed on (tree shape, leaf
         # count, fragment widths, backend) — the compiled-plan cache
         # the lone-query fast path serves from.
         self._fused_plans = CompiledPlanCache()
@@ -1799,14 +1811,44 @@ class MeshManager:
         self.stats.inc("compile_us", us)
         return fn
 
-    def _count_fn(self, sig: str, num_leaves: int):
-        """Get-or-compile the unbatched serving-count program — the ONE
-        place the (sig, num_leaves) cache key lives."""
+    def _count_program(self, kind: str, sig: str, num_leaves: int,
+                       width: int, backend: str = "xla",
+                       uniform: bool = False):
+        """Get-or-compile one batched count program — the ONE place
+        the cache key lives, and where a key becomes a builder call.
+        kind "general" (container gather, XLA whatever the backend) or
+        "coarse" (whole-row runs). A coarse program under a Pallas
+        backend is the one-launch streaming kernel at width 1, the
+        identity-map grid kernel above it (both read each leaf row
+        HBM->VMEM once with no gathered intermediate), or with
+        `uniform` the multi-slice-fetch kernel, whose call contract
+        differs (scalar starts + mask, no valid arrays). The key
+        carries the exact backend string: "pallas" and
+        "pallas_interpret" compile different programs, and an env flip
+        between them must not serve the other's."""
+        key = (kind, sig, num_leaves, width, backend, uniform)
+
+        def build():
+            tree = json.loads(sig)
+            if kind == "coarse" and backend != "xla":
+                interpret = backend == "pallas_interpret"
+                if uniform:
+                    return compile_serve_count_coarse_pallas_uniform(
+                        self.mesh, tree, num_leaves, width,
+                        interpret=interpret)
+                if width == 1:
+                    return compile_serve_count_coarse_pallas(
+                        self.mesh, tree, num_leaves, interpret=interpret)
+                return compile_serve_count_coarse_pallas_batch(
+                    self.mesh, tree, num_leaves, width,
+                    interpret=interpret)
+            return compile_serve_count(self.mesh, tree, num_leaves, width,
+                                       runs=kind == "coarse")
+
         return self._get_or_compile(
-            self._count_fns, (sig, num_leaves),
-            lambda: compile_serve_count(self.mesh, json.loads(sig),
-                                        num_leaves),
-            entry="count")
+            self._count_programs, key, build,
+            entry=("coarse" if kind == "coarse"
+                   else "count" if width == 1 else "count_batch"))
 
     # "auto" resolution cache: None = unresolved, else "pallas"/"xla".
     # Process-wide (ops/calibrate.py measures once; its verdict holds
@@ -1857,12 +1899,12 @@ class MeshManager:
             cls._AUTO_BACKEND = b
         return b
 
-    def _uniform_starts(self, coarse_ts):
+    def _uniform_starts(self, coarse_ts, backend: str):
         """(B*L,) int32 scalar starts for the uniform Pallas programs,
         or None when any leaf is non-uniform or the backend isn't
         Pallas. coarse_ts: one coarse_t tuple per request (each leaf's
         (starts, valid, uniform_scalar) from _leaf_arrays)."""
-        if self._count_backend() not in ("pallas", "pallas_interpret"):
+        if backend not in ("pallas", "pallas_interpret"):
             return None
         flat = []
         for ct in coarse_ts:
@@ -1871,63 +1913,6 @@ class MeshManager:
                     return None
                 flat.append(c[2])
         return np.asarray(flat, dtype=np.int32)
-
-    def _coarse_fn(self, sig: str, num_leaves: int, batch: int,
-                   uniform: bool = False):
-        """Get-or-compile the coarse whole-row-gather program.
-
-        Backend dispatch (the kernels.use_pallas analog at the serving
-        layer): PILOSA_TPU_COUNT_BACKEND=pallas routes single coarse
-        queries through the one-launch Pallas streaming kernel
-        (compile_serve_count_coarse_pallas) and herd groups through
-        the identity-map grid kernel
-        (compile_serve_count_coarse_pallas_batch) — both read each
-        leaf row HBM->VMEM once with no gathered intermediate. When
-        every leaf's layout is UNIFORM (one run index across slices —
-        _leaf_arrays detects it host-side), `uniform=True` selects the
-        multi-slice-fetch kernel instead, which amortizes per-step DMA
-        issue cost; its call contract differs (scalar starts +
-        mask, no valid arrays). True leaf-sharing compositions
-        additionally upgrade to the shared program
-        (_shared_compile_*)."""
-        backend = self._count_backend()
-        if backend in ("pallas", "pallas_interpret"):
-            interpret = backend == "pallas_interpret"
-            # The key carries the exact backend string: "pallas" and
-            # "pallas_interpret" compile different programs, and an
-            # env flip between them must not serve the other's.
-            key = (sig, num_leaves, batch, backend, bool(uniform))
-            if uniform:
-                from .mesh import compile_serve_count_coarse_pallas_uniform
-
-                return self._get_or_compile(
-                    self._coarse_fns, key,
-                    lambda: compile_serve_count_coarse_pallas_uniform(
-                        self.mesh, json.loads(sig), num_leaves, batch,
-                        interpret=interpret),
-                    entry="coarse")
-            if batch == 1:
-                from .mesh import compile_serve_count_coarse_pallas
-
-                return self._get_or_compile(
-                    self._coarse_fns, key,
-                    lambda: compile_serve_count_coarse_pallas(
-                        self.mesh, json.loads(sig), num_leaves,
-                        interpret=interpret),
-                    entry="coarse")
-            from .mesh import compile_serve_count_coarse_pallas_batch
-
-            return self._get_or_compile(
-                self._coarse_fns, key,
-                lambda: compile_serve_count_coarse_pallas_batch(
-                    self.mesh, json.loads(sig), num_leaves, batch,
-                    interpret=interpret),
-                entry="coarse")
-        return self._get_or_compile(
-            self._coarse_fns, (sig, num_leaves, batch),
-            lambda: compile_serve_count_coarse(self.mesh, json.loads(sig),
-                                               num_leaves, batch),
-            entry="coarse")
 
     @staticmethod
     def _shared_policy() -> str:
@@ -1939,7 +1924,7 @@ class MeshManager:
         v = os.environ.get("PILOSA_TPU_BATCH_SHARED", "auto").lower()
         return v if v in ("auto", "sync", "off") else "auto"
 
-    def _shared_plan(self, group):
+    def _shared_plan(self, group, backend: str):
         """(key, leaf_map, uniques, ordered_group) for a
         coarse-eligible group, or None when sharing saves no reads
         (every leaf distinct). The leaf map indexes each request's
@@ -1990,7 +1975,6 @@ class MeshManager:
         if arg_bytes > arg_budget:
             return None
         sig = group[0].args[0]
-        backend = self._count_backend()
         # Uniform layout (every unique leaf at ONE row-run index across
         # slices — _leaf_arrays detects it) upgrades the shared program
         # to the multi-slice-fetch kernel. In the KEY because a restage
@@ -2030,23 +2014,17 @@ class MeshManager:
         re-read from the env here: a background build must cache the
         program the key names even if the env flips mid-build. With
         `uniform` (also from the key) the program takes (words_t,
-        scalar starts (U,), mask) — the dispatch site checks the
-        wrapper's .uniform attribute for the contract."""
+        scalar starts (U,), mask) — _pick_count reads the contract off
+        the key."""
         if backend in ("pallas", "pallas_interpret"):
             interpret = backend == "pallas_interpret"
             if uniform:
                 from .mesh import (
                     compile_serve_count_batch_shared_pallas_uniform)
 
-                base = compile_serve_count_batch_shared_pallas_uniform(
+                return compile_serve_count_batch_shared_pallas_uniform(
                     self.mesh, json.loads(tree_sig), leaf_map,
                     num_unique, interpret=interpret)
-
-                def fn(words_t, starts, mask, _base=base):
-                    return _base(words_t, starts, mask)
-
-                fn.uniform = True  # jit wrappers reject attributes
-                return fn
             from .mesh import compile_serve_count_batch_shared_pallas
 
             return compile_serve_count_batch_shared_pallas(
@@ -2112,40 +2090,6 @@ class MeshManager:
 
         threading.Thread(target=build, name="shared-batch-compile",
                          daemon=True).start()
-
-    def _count_call(self, index: str, shape, leaves, slices: Sequence[int],
-                    num_slices: int):
-        """A zero-arg callable running ONE compiled (unbatched) serving
-        count, returning [lo, hi] limbs in the program's native device
-        shape — (2, 1) coarse, (2,) general — the benchmarking entry
-        for the engine rate without queueing/readback. Picks the coarse
-        program when every leaf is eligible, exactly as the batch loop
-        does."""
-        prepared = self._count_args(index, shape, leaves, slices, num_slices)
-        if prepared is None:
-            return None
-        sig, words_t, idx_t, hit_t, coarse_t, dev_mask = prepared
-        if all(c is not None for c in coarse_t):
-            ustarts = self._uniform_starts([coarse_t])
-            if ustarts is not None:
-                # No stat bump: this zero-arg callable is invoked many
-                # times per build (bench best_of), while the group
-                # runner counts per served query — mixing the two would
-                # make coarse_uniform uninterpretable. The runner paths
-                # are the serving truth; this entry stays stats-silent
-                # like it always was. Coarse calls return their native
-                # (2, 1) device shape — a device-side [:, 0] squeeze
-                # would be a second full program dispatch per call;
-                # callers slice host-side.
-                fn = self._coarse_fn(sig, len(idx_t), 1, uniform=True)
-                du = self._device_starts(ustarts)
-                return lambda: fn(words_t, du, dev_mask)
-            fn = self._coarse_fn(sig, len(idx_t), 1)
-            start_flat = tuple(c[0] for c in coarse_t)
-            valid_flat = tuple(c[1] for c in coarse_t)
-            return lambda: fn(words_t, start_flat, valid_flat, dev_mask)
-        fn = self._count_fn(sig, len(idx_t))
-        return lambda: fn(words_t, idx_t, hit_t, dev_mask)
 
     # -- plan quarantine + guarded device execution ---------------------------
 
@@ -2445,9 +2389,102 @@ class MeshManager:
                         r.error = e
                         r.done.set()
 
-    def _run_count_group(self, group: List["_CountRequest"]):
-        import numpy as _np
+    def _pick_count(self, group: List["_CountRequest"],
+                    backend: str) -> "_Picked":
+        """Which program one deduped group runs: the whole decision,
+        nowhere else. `backend` is _count_backend(), read once by the
+        caller. b = len(group); the program is compiled (or found) when
+        the launch calls `program()`, under the launch guard.
 
+          width   1 when b == 1, else _MAX_BATCH: ONE batch width per
+                  shape, padded with repeats of the last request.
+                  Sizing the pad to the group meant a 16-client herd
+                  that fragmented into 13+3 compiled TWO programs, each
+                  a multi-second XLA compile ON THE BATCH THREAD,
+                  fragmenting the next herd into yet more odd widths.
+                  The padding's device cost is the repeated request's
+                  extra gathers; whether it pays on the attached chip
+                  is not measured (ROADMAP D3).
+          general some leaf of some request is not a whole-row run
+                  (coarse_row_starts): the container-gather program,
+                  XLA under every backend. Counts `batched` b if b > 1.
+          shared  every leaf coarse, b > 1, PILOSA_TPU_BATCH_SHARED not
+                  "off", the group shares a leaf within the argument
+                  budget (_shared_plan) and the composition's program
+                  is compiled: cached, or built inline under "sync".
+                  Under "auto" a composition not yet compiled counts a
+                  sighting (_shared_compile_async builds it in the
+                  background from the _shared_seen_min-th) and the
+                  group runs coarse or uniform meanwhile. Exact width
+                  b, columns in the plan's canonical order. Counts
+                  `shared_batch`, `coarse`, `batched` b.
+          uniform every leaf coarse, a Pallas backend, and every leaf
+                  at ONE row-run index across all slices
+                  (_leaf_arrays): scalar starts. Counts
+                  `coarse_uniform`, `coarse` b, `batched` b if b > 1.
+          coarse  every leaf coarse, otherwise: (start, valid) runs,
+                  XLA or the Pallas twin. Counts `coarse` b, `batched`
+                  b if b > 1.
+        """
+        b = len(group)
+        sig, words_t, idx_t, _hit_t, dev_mask = group[0].args
+        n = len(idx_t)
+        width = 1 if b == 1 else self._MAX_BATCH
+        padded = group + [group[-1]] * (width - b)
+        counters = (("batched", b),) if b > 1 else ()
+        if not all(c is not None for r in group for c in r.coarse_t):
+            return _Picked(
+                "general", width,
+                lambda: self._count_program("general", sig, n, width),
+                (words_t, tuple(a for r in padded for a in r.args[2]),
+                 tuple(a for r in padded for a in r.args[3]), dev_mask),
+                group, counters)
+        counters = (("coarse", b),) + counters
+        policy = self._shared_policy() if b > 1 else "off"
+        plan = (self._shared_plan(group, backend)
+                if policy != "off" else None)
+        if plan is not None:
+            key, leaf_map, uniques, ordered = plan
+            shared = self._shared_get(key)
+            if shared is None:
+                if policy == "sync":
+                    shared = self._shared_compile_sync(
+                        key, sig, leaf_map, len(uniques))
+                else:
+                    self._shared_compile_async(
+                        key, sig, leaf_map, len(uniques))
+            if shared is not None:
+                words_u = tuple(u[0] for u in uniques)
+                if key[-1]:  # uniform: scalar starts, no valid arrays
+                    args = (words_u, self._device_starts(np.asarray(
+                        [u[3] for u in uniques], dtype=np.int32)),
+                        dev_mask)
+                else:
+                    args = (words_u, tuple(u[1] for u in uniques),
+                            tuple(u[2] for u in uniques), dev_mask)
+                return _Picked("shared", b, lambda: shared, args, ordered,
+                               (("shared_batch", b),) + counters)
+        ustarts = self._uniform_starts([r.coarse_t for r in padded],
+                                       backend)
+        if ustarts is not None:
+            return _Picked(
+                "uniform", width,
+                lambda: self._count_program("coarse", sig, n, width,
+                                            backend, uniform=True),
+                (words_t, self._device_starts(ustarts), dev_mask),
+                group, (("coarse_uniform", b),) + counters)
+        return _Picked(
+            "coarse", width,
+            lambda: self._count_program("coarse", sig, n, width, backend),
+            (words_t, tuple(c[0] for r in padded for c in r.coarse_t),
+             tuple(c[1] for r in padded for c in r.coarse_t), dev_mask),
+            group, counters)
+
+    def _run_count_group(self, group: List["_CountRequest"]):
+        """One group of the batch loop, one device program: dedupe,
+        _pick_count, one guarded launch, the D2H copy started, and the
+        results handed out by finish() (on a fetch worker when the
+        batch thread calls, inline for a direct caller)."""
         # Identical requests (same leaf arrays AND mask — e.g. many
         # clients polling the same Count) collapse to ONE program slot;
         # only distinct queries consume batch width.
@@ -2475,146 +2512,24 @@ class MeshManager:
                 r.result, r.error = src.result, src.error
                 r.done.set()
 
-        b = len(group)
-        # Whole-row coarse gather when EVERY leaf of EVERY request in
-        # the group is eligible (see coarse_row_starts). Mixed groups
-        # take the
-        # general container-gather program — correctness first.
-        coarse_ok = all(all(c is not None for c in r.coarse_t)
-                        for r in group)
-        if b == 1:
-            sig, words_t, idx_t, hit_t, dev_mask = group[0].args
-            if coarse_ok:
-                # Coarse singles keep their (2, 1) device shape: the
-                # [:, 0] squeeze is a SECOND program dispatch on a
-                # lone query; finish() slices host-side after the fetch.
-                ct = group[0].coarse_t
-                ustarts = self._uniform_starts([ct])
-                if ustarts is not None:
-                    du = self._device_starts(ustarts)
+        sig = group[0].args[0]
+        pick = self._pick_count(group, self._count_backend())
+        # The general batch is the one group launch a device trace
+        # names (the lone paths have scopes of their own).
+        scoped = pick.kind == "general" and pick.width > 1
 
-                    def launch():
-                        fn = self._coarse_fn(sig, len(idx_t), 1,
-                                             uniform=True)
-                        return fn(words_t, du, dev_mask)
+        def launch():
+            with (jax_scope("pilosa:count_batch") if scoped
+                  else contextlib.nullcontext()):
+                return pick.program()(*pick.args)
 
-                    limbs = self._guarded_exec(sig, launch, views=gviews)
-                    self.stats.inc("coarse_uniform")
-                else:
-                    def launch():
-                        fn = self._coarse_fn(sig, len(idx_t), 1)
-                        return fn(words_t, tuple(c[0] for c in ct),
-                                  tuple(c[1] for c in ct), dev_mask)
-
-                    limbs = self._guarded_exec(sig, launch, views=gviews)
-                self.stats.inc("coarse")
-            else:
-                def launch():
-                    fn = self._count_fn(sig, len(idx_t))
-                    return fn(words_t, idx_t, hit_t, dev_mask)
-
-                limbs = self._guarded_exec(sig, launch, views=gviews)
-        else:
-            sig, words_t, _, _, dev_mask = group[0].args
-            num_leaves = len(group[0].args[2])
-            # ONE batch width per shape: every multi-request group runs
-            # the _MAX_BATCH-wide program, padded with repeats of the
-            # last request. Sizing the pad to the group (the old
-            # mutation_batch_width policy) meant a 16-client herd that
-            # fragmented into 13+3 compiled TWO programs — and each
-            # first-seen width paid a multi-second XLA compile ON THE
-            # BATCH THREAD, stalling the pipeline, fragmenting the next
-            # herd into yet more odd widths. The padding's device
-            # cost is the repeated request's extra gathers; whether it
-            # pays on the attached chip is not measured (ROADMAP D3).
-            b_pad = self._MAX_BATCH
-            padded = group + [group[-1]] * (b_pad - b)
-            if coarse_ok:
-                shared = None
-                policy = self._shared_policy()
-                plan = (self._shared_plan(group)
-                        if policy != "off" else None)
-                if plan is not None:
-                    key, leaf_map, uniques, ordered_group = plan
-                    shared = self._shared_get(key)
-                    if shared is None:
-                        if policy == "sync":
-                            shared = self._shared_compile_sync(
-                                key, sig, leaf_map, len(uniques))
-                        else:
-                            self._shared_compile_async(
-                                key, sig, leaf_map, len(uniques))
-                if shared is not None:
-                    if getattr(shared, "uniform", False):
-                        du = self._device_starts(_np.asarray(
-                            [u[3] for u in uniques], dtype=_np.int32))
-
-                        def launch():
-                            return shared(
-                                tuple(u[0] for u in uniques), du,
-                                dev_mask)
-                    else:
-                        def launch():
-                            return shared(
-                                tuple(u[0] for u in uniques),
-                                tuple(u[1] for u in uniques),
-                                tuple(u[2] for u in uniques), dev_mask)
-
-                    limbs = self._guarded_exec(sig, launch, views=gviews)
-                    # shared output columns follow the CANONICAL group
-                    # order; distribute results in that order (exact
-                    # width, no padding)
-                    group = ordered_group
-                    self.stats.inc("shared_batch", b)
-                else:
-                    ustarts = self._uniform_starts(
-                        [r.coarse_t for r in padded])
-                    if ustarts is not None:
-                        du = self._device_starts(ustarts)
-
-                        def launch():
-                            fn = self._coarse_fn(sig, num_leaves, b_pad,
-                                                 uniform=True)
-                            return fn(words_t, du, dev_mask)
-
-                        limbs = self._guarded_exec(sig, launch, views=gviews)
-                        self.stats.inc("coarse_uniform", b)
-                    else:
-                        start_flat = tuple(
-                            r.coarse_t[i][0] for r in padded
-                            for i in range(num_leaves))
-                        valid_flat = tuple(
-                            r.coarse_t[i][1] for r in padded
-                            for i in range(num_leaves))
-
-                        def launch():
-                            fn = self._coarse_fn(sig, num_leaves, b_pad)
-                            return fn(words_t, start_flat, valid_flat,
-                                      dev_mask)
-
-                        limbs = self._guarded_exec(sig, launch, views=gviews)
-                self.stats.inc("coarse", b)
-            else:
-                idx_flat = tuple(r.args[2][i] for r in padded
-                                 for i in range(num_leaves))
-                hit_flat = tuple(r.args[3][i] for r in padded
-                                 for i in range(num_leaves))
-
-                def launch():
-                    fn = self._get_or_compile(
-                        self._batch_fns, (sig, num_leaves, b_pad),
-                        lambda: compile_serve_count_batch(
-                            self.mesh, json.loads(sig), num_leaves,
-                            b_pad),
-                        entry="count_batch")
-                    with jax_scope("pilosa:count_batch"):
-                        return fn(words_t, idx_flat, hit_flat, dev_mask)
-
-                limbs = self._guarded_exec(sig, launch, views=gviews)
-            self.stats.inc("batched", b)
-
-        # Every branch above launched exactly ONE compiled program.
+        limbs = self._guarded_exec(sig, launch, views=gviews)
+        for name, n in pick.counters:
+            self.stats.inc(name, n)
         self.stats.inc("device_dispatches")
+        # Output columns follow pick.order (a shared program's are in
+        # the plan's canonical order; padding columns are not read).
+        group = pick.order
 
         # Start the D2H copy NOW: by the time the program completes,
         # the bytes are already on their way and the worker's
@@ -2632,12 +2547,9 @@ class MeshManager:
         # synchronously below.)
         def finish():
             try:
-                arr = _np.asarray(limbs)
-                if arr.ndim == 1:  # single request: (2,) [lo, hi]
-                    group[0].result = (int(arr[1]) << 16) + int(arr[0])
-                else:
-                    for j, r in enumerate(group):
-                        r.result = (int(arr[1, j]) << 16) + int(arr[0, j])
+                arr = np.asarray(limbs)
+                for j, r in enumerate(group):
+                    r.result = (int(arr[1, j]) << 16) + int(arr[0, j])
             except Exception as e:  # noqa: BLE001 — fail the group
                 # Async execution errors surface HERE (first fetch),
                 # not at dispatch — strike the plan signature so a
@@ -2676,7 +2588,7 @@ class MeshManager:
 
         A LONE count (no other count in flight) takes the fused
         single-dispatch path: gather metadata and mask ride the one
-        jitted call as host arguments (compile_serve_count_fused), so a
+        jitted call as host arguments (compile_serve_count, host_meta), so a
         distinct query pays one dispatch + one fetch instead of the
         chained metadata-upload + program sequence (three dispatches).
 
@@ -2855,8 +2767,9 @@ class MeshManager:
             key = CompiledPlanCache.key(sig, words_t)
             fn = self._fused_plans.get_or_build(
                 key, lambda: self._timed_build(
-                    "fused", lambda: compile_serve_count_fused(
-                        self.mesh, json.loads(sig), len(leaves))))
+                    "fused", lambda: compile_serve_count(
+                        self.mesh, json.loads(sig), len(leaves),
+                        host_meta=True)))
             prof = profile.current()
             if prof is None:
                 # THE fast path: async dispatch, no completion wait —
@@ -3160,8 +3073,9 @@ class MeshManager:
                     fn = self._fused_plans.get_or_build(
                         key, lambda n=len(words_t): self._timed_build(
                             "fused",
-                            lambda: compile_serve_count_fused(
-                                self.mesh, json.loads(sig), n)))
+                            lambda: compile_serve_count(
+                                self.mesh, json.loads(sig), n,
+                                host_meta=True)))
                     tagged = format_signature(sig, "dd")
                     args = (words_t, idx_all, hit_all, gmask)
                 else:
@@ -3344,8 +3258,7 @@ class MeshManager:
                          slices: Sequence[int], num_slices: int,
                          pins=None):
         """(row_ids, zero-arg callable -> (2, padded) DEVICE limb
-        array — async; np.asarray it to materialize) or None; see
-        _count_call for the locking contract. Identical concurrent
+        array — async; np.asarray it to materialize) or None. Identical concurrent
         calls (same staged image, mask, padding) SHARE one in-flight
         device execution — the common shape of a TopN hotspot is many
         clients asking the same frame."""

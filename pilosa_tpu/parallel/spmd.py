@@ -624,7 +624,8 @@ class SpmdServer:
                 ckey = ("count", sig, shapes)
                 compiled = self._compiled.get(ckey)
                 if compiled is None:
-                    fn = self.manager._count_fn(sig, len(idx_t))
+                    fn = self.manager._count_program(
+                        "general", sig, len(idx_t), 1)
                     compiled = fn.lower(words_t, idx_t, hit_t,
                                         mask).compile()
                     self._compiled[ckey] = compiled
@@ -638,7 +639,8 @@ class SpmdServer:
             return None  # every rank skips: no divergent collective
         # Past the gate, all ranks run the identical program; a runtime
         # failure here hits every rank symmetrically.
-        out = combine_count(compiled(words_t, idx_t, hit_t, mask))
+        out = combine_count(
+            np.asarray(compiled(words_t, idx_t, hit_t, mask))[:, 0])
         self.manager.stats["count"] += 1
         return out
 

@@ -739,7 +739,7 @@ def test_coarse_count_shared_uniform_kernel_differential():
 def test_serve_shared_uniform_upgrade(mesh, tmp_path, monkeypatch):
     """End-to-end: a repeated SHARED composition over a uniformly
     staged pool compiles the uniform shared program (key carries
-    uniform=True, wrapper has .uniform) and matches the host."""
+    uniform=True) and matches the host."""
     from pilosa_tpu.core import Holder
     from pilosa_tpu.executor import Executor
     from pilosa_tpu.pql import parse_string

@@ -261,10 +261,10 @@ def test_count_above_int32_from_limbs_to_json(tmp_path, monkeypatch, path):
     lo = want - (hi << 16)
     assert 0xFFFF < lo < 1920 * 0xFFFF and want > 2**31 - 1
     limbs = jnp.asarray([lo, hi], dtype=jnp.int32)
-    monkeypatch.setattr(serve, "compile_serve_count_fused",
-                        lambda *a, **k: lambda *args: limbs)
-    monkeypatch.setattr(serve, "compile_serve_count_coarse",
-                        lambda *a, **k: lambda *args: limbs[:, None])
+    monkeypatch.setattr(
+        serve, "compile_serve_count",
+        lambda *a, host_meta=False, **k: lambda *args: (
+            limbs if host_meta else limbs[:, None]))
     s = Served(opened(tmp_path / "d"), 4)
     s.load(random_rows(2, seed=5, rows=2))
     s.mgr.lone_fused = path == "lone"

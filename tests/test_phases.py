@@ -248,33 +248,45 @@ def test_process_cpu_seconds_in_debug_vars_never_decreases(served):
 
 # -- device programs that can be told apart -------------------------------------
 
-BUILDERS = sorted(n for n, f in inspect.getmembers(pmesh, inspect.isfunction)
-                  if n.startswith("compile_"))
 BUILDER_ARGS = {
     "tree_shape": ["and", ["leaf", 0], ["leaf", 1]], "num_leaves": 2,
     "num_rows": 4, "k": 2, "batch": 2, "leaf_map": ((0, 1), (1, 0)),
     "num_unique": 2, "op": "and", "kind": "ss"}
+# The one xla count builder names its program for the form it builds.
+FORMS = {"compile_serve_count": [
+    ("count_batch", {}), ("count_batch", {"batch": 1}),
+    ("count_coarse", {"runs": True}),
+    ("count_fused", {"host_meta": True, "batch": 1})]}
+PROGRAMS = [
+    (n, want, kw)
+    for n, f in sorted(inspect.getmembers(pmesh, inspect.isfunction))
+    if n.startswith("compile_")
+    for want, kw in FORMS.get(n, [(n.replace("compile_serve_", "").replace(
+        "compile_", ""), {})])]
 
 
-def program_name(builder: str) -> str:
+def program_name(builder: str, form: dict) -> str:
     fn = getattr(pmesh, builder)
     kwargs = {p: BUILDER_ARGS[p] for p in inspect.signature(fn).parameters
               if p in BUILDER_ARGS}
-    return fn(pmesh.default_mesh(), **kwargs).__name__
+    return fn(pmesh.default_mesh(), **{**kwargs, **form}).__name__
 
 
-@pytest.mark.parametrize("builder", BUILDERS)
-def test_a_builders_program_carries_its_name(builder):
+@pytest.mark.parametrize(
+    "builder,want,form", PROGRAMS,
+    ids=[b + "".join(f"-{k}={v}" for k, v in kw.items())
+         for b, _, kw in PROGRAMS])
+def test_a_builders_program_carries_its_name(builder, want, form):
     """`PjitFunction(<name>)` on the host and `jit_<name>` on the device
     are how a trace tells programs apart: each is named for what it
     runs, none `run`."""
-    name = program_name(builder)
-    assert name != "run"
-    assert name == builder.replace("compile_serve_", "").replace(
-        "compile_", "")
+    assert program_name(builder, form) == want != "run"
 
 
 def test_no_two_builders_share_a_program_name():
-    names = [program_name(b) for b in BUILDERS]
-    assert len(BUILDERS) >= 19 and len(set(names)) == len(names)
+    made_by = {}
+    for builder, _, form in PROGRAMS:
+        made_by.setdefault(program_name(builder, form), set()).add(builder)
+    assert len(PROGRAMS) >= 19 and len(made_by) >= 18
+    assert all(len(b) == 1 for b in made_by.values()), made_by
     assert pmesh._fold_chunk_fn().__name__ == "fold_chunk"

@@ -819,15 +819,17 @@ class TestWideCount:
                              words=jax.device_put(words, sharding))
         flat_idx, hit = resolve_row_indices(keys, 0)
         assert hit.all()
-        fn = compile_serve_count(mesh, ["leaf"], 1)
+        fn = compile_serve_count(mesh, ["leaf"], 1)  # -> (2, 1) limbs
         args = ((index.words,), (jax.device_put(flat_idx, sharding),),
                 (jax.device_put(hit, sharding),))
-        assert combine_count(fn(*args, np.ones(s, dtype=np.int32))) \
+        assert combine_count(
+            np.asarray(fn(*args, np.ones(s, dtype=np.int32)))[:, 0]) \
             == s * (1 << 20)
         # Masking half the slices halves the count.
         mask = np.zeros(s, dtype=np.int32)
         mask[: s // 2] = 1
-        assert combine_count(fn(*args, mask)) == (s // 2) * (1 << 20)
+        assert combine_count(np.asarray(fn(*args, mask))[:, 0]) \
+            == (s // 2) * (1 << 20)
 
 
 class TestConcurrentWriteQueryFuzz:
@@ -1188,7 +1190,7 @@ class TestPlanSliceMutations:
 
 class TestCoarseGather:
     """The whole-row coarse-gather fast path (mesh.coarse_row_starts +
-    compile_serve_count_coarse): eligibility detection, correctness vs
+    compile_serve_count, runs=True): eligibility detection, correctness vs
     the host path, and fallback to the general container gather for
     partial/unaligned rows. The gather-granularity analog of the
     reference's container-TYPE kernel dispatch (roaring.go:1270-1351)."""
@@ -1371,14 +1373,14 @@ class TestCoarseGather:
                     3, (0, 1, 2), pairs)
         batch = 1 if program == "batch1" else 16
         queries = (pairs * 4)[:batch]
-        return (M.compile_serve_count_coarse(mesh, tree, 2, batch),
+        return (M.compile_serve_count(mesh, tree, 2, batch, runs=True),
                 2, tuple(r for qr in queries for r in qr), queries)
 
     @pytest.mark.parametrize("program", ["batch1", "batch16", "shared"])
     @pytest.mark.parametrize("cap", [32, 128])
     @pytest.mark.parametrize("devices", [1, 4])
     def test_coarse_rows_match_numpy(self, devices, cap, program):
-        """_gather_leaf_rows against numpy on a pool of random words: a
+        """_row_run_blocks' gather against numpy on a pool of random words: a
         row's run starts somewhere else in every slice, one slice holds
         no part of row 1 (valid == 0) and one is masked out."""
         import jax
@@ -1785,9 +1787,9 @@ class TestAdaptiveSharedBatching:
         assert [r.result for r in group] == want
         assert mgr.stats["shared_batch"] == 0
         assert mgr.stats["batched"] == 3
-        assert any(len(k) == 5 and k[3] == "pallas_interpret"
-                   and k[2] == mgr._MAX_BATCH
-                   for k in mgr._coarse_fns), list(mgr._coarse_fns)
+        assert ("coarse", group[0].args[0], 2, mgr._MAX_BATCH,
+                "pallas_interpret", False) in mgr._count_programs, \
+            list(mgr._count_programs)
 
     def test_shared_pallas_backend_matches(self, holder, monkeypatch):
         """PILOSA_TPU_COUNT_BACKEND=pallas_interpret routes the
@@ -2177,3 +2179,134 @@ class TestAutoBackend:
             monkeypatch.setenv("PILOSA_TPU_COUNT_BACKEND", v)
             assert MeshManager._count_backend() == want
         assert MeshManager._AUTO_BACKEND is None  # auto never resolved
+
+
+class TestPickCount:
+    """MeshManager._pick_count alone, nothing launched or compiled: the
+    decision table in its docstring, as cases. Requests are made of
+    placeholders; only the leaves' coarse triples, the logical leaf keys
+    and the pools' shapes are read."""
+
+    @staticmethod
+    def _group(b, layout):
+        from pilosa_tpu.parallel.serve import _CountRequest
+
+        pool = np.zeros((2, 16, 8), np.uint32)
+        mask = object()
+
+        def leaf(row):
+            if layout == "uniform":        # one run index on every slice
+                return (("st", row), ("va", row), row)
+            return (("st", row), ("va", row), None)
+
+        group = []
+        for q in range(b):
+            rows = (q, q + 1)              # neighbours share a leaf
+            coarse = tuple(leaf(r) for r in rows)
+            if layout == "mixed" and q == b - 1:
+                coarse = (coarse[0], None)  # one partial row in the group
+            req = _CountRequest(
+                "sig", (pool, pool), tuple(("idx", r) for r in rows),
+                tuple(("hit", r) for r in rows), coarse, mask)
+            req.leaf_keys = tuple(("f", "standard", r) for r in rows)
+            group.append(req)
+        return group
+
+    @pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
+    @pytest.mark.parametrize("policy", ["auto", "sync", "off"])
+    @pytest.mark.parametrize("layout", ["runs", "uniform", "mixed"])
+    @pytest.mark.parametrize("b", [1, 3, 16])
+    def test_decision_table(self, monkeypatch, b, layout, policy, backend):
+        import types
+
+        from pilosa_tpu.parallel.serve import MeshManager
+
+        monkeypatch.setenv("PILOSA_TPU_BATCH_SHARED", policy)
+        mgr = MeshManager(None, mesh=types.SimpleNamespace(
+            shape={"slices": 1}))
+        built = []
+        monkeypatch.setattr(
+            mgr, "_build_shared",
+            lambda *key, uniform=False: built.append(
+                key + (uniform,)) or "shared-program")
+        monkeypatch.setattr(mgr, "_device_starts", lambda a: a)
+        asked = []
+        monkeypatch.setattr(
+            mgr, "_count_program",
+            lambda *key, **kw: asked.append(key + tuple(kw.values())))
+        group = self._group(b, layout)
+
+        pick = mgr._pick_count(group, backend)
+
+        pallas = backend == "pallas_interpret"
+        width = 1 if b == 1 else 16
+        batched = [("batched", b)] if b > 1 else []
+        if layout == "mixed":
+            want = ("general", width, batched)
+        elif b > 1 and policy == "sync":
+            want = ("shared", b,
+                    [("shared_batch", b), ("coarse", b), ("batched", b)])
+        elif layout == "uniform" and pallas:
+            want = ("uniform", width,
+                    [("coarse_uniform", b), ("coarse", b)] + batched)
+        else:
+            want = ("coarse", width, [("coarse", b)] + batched)
+        assert (pick.kind, pick.width, list(pick.counters)) == want
+        assert sorted(map(id, pick.order)) == sorted(map(id, group))
+        assert not asked            # nothing compiles before the launch
+        prog = pick.program()
+        if pick.kind == "shared":
+            assert prog == "shared-program"
+            uniform = pallas and layout == "uniform"
+            assert [k[3:] for k in built] == [(backend, uniform)]
+            assert len(pick.args) == (3 if uniform else 4)
+            assert len(pick.args[0]) == b + 1     # unique leaves, not 2b
+        else:
+            assert not built
+            assert asked == [{
+                "general": ("general", "sig", 2, width),
+                "coarse": ("coarse", "sig", 2, width, backend),
+                "uniform": ("coarse", "sig", 2, width, backend, True),
+            }[pick.kind]]
+            slots = 2 * width
+            assert [len(a) for a in pick.args[1:-1]] == (
+                [slots] if pick.kind == "uniform" else [slots, slots])
+            # Padding repeats the last request.
+            last = group[-1]
+            tail = (last.args[2][-1] if pick.kind == "general"
+                    else last.coarse_t[-1][2 if pick.kind == "uniform"
+                                           else 0])
+            assert pick.args[1][-1] == tail
+
+    def test_auto_policy_compiles_a_composition_only_once_seen(
+            self, monkeypatch):
+        """Under `auto` a shared composition runs coarse until its
+        program is in the cache: each group counts a sighting, the
+        background build starts at the _shared_seen_min-th, and a
+        cached program is picked at the group's own width."""
+        import types
+
+        from pilosa_tpu.parallel.serve import MeshManager
+
+        monkeypatch.setenv("PILOSA_TPU_BATCH_SHARED", "auto")
+        monkeypatch.setenv("PILOSA_TPU_SHARED_SEEN_MIN", "3")
+        mgr = MeshManager(None, mesh=types.SimpleNamespace(
+            shape={"slices": 1}))
+        started = []
+        monkeypatch.setattr(
+            "pilosa_tpu.parallel.serve.threading.Thread",
+            lambda target, **kw: types.SimpleNamespace(
+                start=lambda: started.append(target)))
+        monkeypatch.setattr(mgr, "_build_shared",
+                            lambda *k, **kw: "shared-program")
+        group = self._group(3, "runs")
+        for seen in (1, 2, 3):
+            assert mgr._pick_count(group, "xla").kind == "coarse"
+            assert len(started) == (1 if seen == 3 else 0)
+        started[0]()                        # the background build lands
+        pick = mgr._pick_count(list(reversed(group)), "xla")
+        assert (pick.kind, pick.width) == ("shared", 3)
+        # Columns follow the canonical order, whatever the arrival order.
+        assert [r.leaf_keys for r in pick.order] == \
+            [r.leaf_keys for r in group]
+        assert pick.program() == "shared-program"

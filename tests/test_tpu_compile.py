@@ -163,7 +163,7 @@ def test_general_tree_kernels(chip):
     assert kernels_in(text) == 1
 
 
-# -- what _run_count_group and _coarse_fn can select -------------------------
+# -- what _pick_count and _count_program can select --------------------------
 
 @pytest.mark.parametrize("op,leaves", [("and", 1), ("and", 2), ("or", 2),
                                        ("andnot", 2), ("and", 8),
@@ -252,20 +252,21 @@ def test_xla_count_programs(chip, program):
     mask = chip.sliced(np.int32)
     w = chip.pool()
     if program == "coarse_b16":
-        fn = M.compile_serve_count_coarse(chip.mesh, AND2, 2, 16)
+        fn = M.compile_serve_count(chip.mesh, AND2, 2, 16, runs=True)
         args = ((w, w), *chip.starts_valid(32), mask)
     elif program == "shared_28_of_8":
         fn = M.compile_serve_count_batch_shared(chip.mesh, AND2, PAIRS28, 8)
         args = ((w,) * 8, *chip.starts_valid(8), mask)
     elif program == "general_b16":
-        fn = M.compile_serve_count_batch(chip.mesh, AND2, 2, 16)
+        fn = M.compile_serve_count(chip.mesh, AND2, 2, 16)
         args = ((w, w), *chip.idx_hit(32), mask)
     elif program == "fused_8":
-        fn = M.compile_serve_count_fused(chip.mesh, nary("andnot", 8), 8)
+        fn = M.compile_serve_count(chip.mesh, nary("andnot", 8), 8,
+                                   host_meta=True)
         args = ((w,) * 8, chip.repl(np.int32, 8, S, 16),
                 chip.repl(np.uint32, 8, S, 16), chip.repl(np.int32, S))
     else:
-        fn = M.compile_serve_count(chip.mesh, nary("or", 8), 8)
+        fn = M.compile_serve_count(chip.mesh, nary("or", 8), 8, 1)
         args = ((w,) * 8, *chip.idx_hit(8), mask)
     text, mem = compiled(fn, *args)
     assert kernels_in(text) == 0
@@ -336,7 +337,7 @@ def test_bsi_range_ladder_through_the_count_kernels(chip):
     from pilosa_tpu.bsi import lower as L
     from pilosa_tpu.bsi.field import FieldSchema
     from pilosa_tpu.parallel.mesh import (
-        compile_serve_count_coarse_pallas_uniform, compile_serve_count_fused)
+        compile_serve_count, compile_serve_count_coarse_pallas_uniform)
     from pilosa_tpu.parallel.plan import _tree_signature
 
     leaves: list = []
@@ -350,7 +351,7 @@ def test_bsi_range_ladder_through_the_count_kernels(chip):
         compile_serve_count_coarse_pallas_uniform(chip.mesh, sig, n, 1),
         w, chip.repl(np.int32, n), mask)
     assert kernels_in(text) == 1
-    compiled(compile_serve_count_fused(chip.mesh, sig, n), w,
+    compiled(compile_serve_count(chip.mesh, sig, n, host_meta=True), w,
              chip.repl(np.int32, n, S, 16), chip.repl(np.uint32, n, S, 16),
              chip.repl(np.int32, S))
 
@@ -370,7 +371,7 @@ def test_four_chip_program_reduces_over_the_interconnect(chip, four,
             return (M.compile_serve_count_coarse_pallas_uniform(
                 c.mesh, AND2, 2, 16),
                 (c.pool(), c.pool()), c.repl(np.int32, 32), mask)
-        return (M.compile_serve_count_coarse(c.mesh, AND2, 2, 16),
+        return (M.compile_serve_count(c.mesh, AND2, 2, 16, runs=True),
                 (c.pool(), c.pool()), *c.starts_valid(32), mask)
 
     one_text, one_mem = compiled(*build(chip))
@@ -398,12 +399,14 @@ def test_seg_2b_x4_programs_over_the_sharded_pool(four, program, n):
     w, mask = four.sliced(np.uint32, CAP, 2048, s=s2), \
         four.sliced(np.int32, s=s2)
     if program == "coarse":
-        fn = M.compile_serve_count_coarse(four.mesh, nary("or", n), n, 1)
+        fn = M.compile_serve_count(four.mesh, nary("or", n), n, 1,
+                                   runs=True)
         args = ((w,) * n, tuple(four.sliced(np.int32, s=s2)
                                 for _ in range(n)),
                 tuple(four.sliced(np.uint32, s=s2) for _ in range(n)), mask)
     elif program == "fused":
-        fn = M.compile_serve_count_fused(four.mesh, nary("andnot", n), n)
+        fn = M.compile_serve_count(four.mesh, nary("andnot", n), n,
+                                   host_meta=True)
         args = ((w,) * n, four.repl(np.int32, n, s2, 16),
                 four.repl(np.uint32, n, s2, 16), four.repl(np.int32, s2))
     else:
@@ -483,8 +486,8 @@ def test_xla_coarse_programs_do_not_copy_the_pool(chip, four, program,
     slots = leaves if program != "coarse" else leaves * batch
     args = ((w,) * leaves, *c.starts_valid(slots, s=s), mask)
     if program == "coarse":
-        fn = M.compile_serve_count_coarse(c.mesh, nary("and", leaves),
-                                          leaves, batch)
+        fn = M.compile_serve_count(c.mesh, nary("and", leaves), leaves,
+                                   batch, runs=True)
     else:
         fn = M.compile_serve_count_batch_shared(c.mesh, AND2, PAIRS28, leaves)
     text, mem = compiled(fn, *args)
