@@ -99,6 +99,21 @@ def bitmap_from_tar(tar_bytes: bytes) -> Optional[Bitmap]:
     return None
 
 
+class WriteCounter:
+    """Writes to one view: one count for all of its fragments, moved
+    by every write that moves a `Fragment.generation` (a bit write,
+    an import, a restore) and by nothing else. A `View` shares one
+    with the fragments it opens; a bare `Fragment` has its own.
+    Only `_MutationEpoch.bump` writes `n`, under the epoch's lock:
+    fragments of one view are written under their OWN `_mu`, and an
+    unguarded `n += 1` on two threads can lose an update."""
+
+    __slots__ = ("n", "__weakref__")
+
+    def __init__(self):
+        self.n = 0
+
+
 class _MutationEpoch:
     """Process-wide monotonic mutation counter.
 
@@ -120,12 +135,20 @@ class _MutationEpoch:
     `s` is the STRUCTURAL sub-counter: it moves only when the SET of
     fragments a query could touch — or how its tree lowers — changes
     (fragment/frame/index create or delete, label or time-quantum
-    change). Plain bit writes move `n` alone, and pair each bump with
-    the touched fragment's own `generation` increment. That split
-    lets a query memo that recorded its fragments' generations
-    revalidate after an UNRELATED write: `s` unchanged means the
-    fragment set is intact, so comparing the recorded generations is
-    a complete staleness check (HostQueryCache.query_get)."""
+    change). Plain bit writes move `n` alone, and `bump` pairs each
+    with the written view's `WriteCounter`. That split lets a query
+    memo that recorded the counters of the views it reads revalidate
+    after an UNRELATED write: `s` unchanged means the fragment set is
+    intact, so comparing the recorded counters is a complete
+    staleness check (HostQueryCache.query_get).
+
+    THE ORDERING RULE, stated here once: a write moves what a
+    validator compares (the fragment's `generation`, the view's
+    `WriteCounter`) BEFORE it moves `n`, and a validator reads `n`
+    BEFORE it reads them. A reader that saw the new `n` then sees
+    the moved counter; one that saw the old `n` stamps a value the
+    write has already left behind. Either way a stamp never marks a
+    write validated that the reader did not see."""
 
     __slots__ = ("n", "s", "_mu")
 
@@ -134,8 +157,9 @@ class _MutationEpoch:
         self.s = 0
         self._mu = threading.Lock()
 
-    def bump(self):
+    def bump(self, writes: WriteCounter):
         with self._mu:
+            writes.n += 1
             self.n += 1
 
     def bump_structural(self):
@@ -205,12 +229,17 @@ class Fragment:
                  cache_size: int = DEFAULT_CACHE_SIZE,
                  row_attr_store=None, stats=None,
                  wal: Optional[WalConfig] = None,
-                 integrity: Optional[IntegrityContext] = None):
+                 integrity: Optional[IntegrityContext] = None,
+                 view_writes: Optional[WriteCounter] = None):
         self.path = path
         self.index = index
         self.frame = frame
         self.view = view
         self.slice = slice_
+        # The owning view's write counter (View._open_fragment shares
+        # its own); a fragment built without a view counts alone.
+        self.view_writes = (view_writes if view_writes is not None
+                            else WriteCounter())
         self.cache_type = cache_type
         self.cache_size = cache_size
         self.row_attr_store = row_attr_store
@@ -790,7 +819,7 @@ class Fragment:
     def _log_append(self, op: int, pos: int, churn: bool):
         self.generation += 1
         self.epoch += 1
-        MUTATION_EPOCH.bump()
+        MUTATION_EPOCH.bump(self.view_writes)
         self._log.append((op, pos, churn))
         if len(self._log) > self._log_limit:
             drop = len(self._log) - self._log_limit
@@ -802,7 +831,7 @@ class Fragment:
         any earlier generation must rebuild."""
         self.generation += 1
         self.epoch += 1
-        MUTATION_EPOCH.bump()
+        MUTATION_EPOCH.bump(self.view_writes)
         self._log.clear()
         self._log_base = self.generation
 

@@ -15,7 +15,7 @@ from typing import Dict, Optional
 
 from .. import SLICE_WIDTH
 from .cache import CACHE_TYPE_RANKED, DEFAULT_CACHE_SIZE
-from .fragment import Fragment, MUTATION_EPOCH
+from .fragment import Fragment, MUTATION_EPOCH, WriteCounter
 
 VIEW_STANDARD = "standard"
 VIEW_INVERSE = "inverse"
@@ -45,6 +45,10 @@ class View:
         self.wal = wal
         self.integrity = integrity
         self.fragments: Dict[int, Fragment] = {}
+        # One count of the writes to any fragment of this view: what a
+        # whole-query memo records in place of every fragment's
+        # generation (Executor._query_token).
+        self.writes = WriteCounter()
         self._create_mu = threading.RLock()
 
     @property
@@ -79,6 +83,7 @@ class View:
             stats=self.stats.with_tags(f"slice:{slice_}") if self.stats else None,
             wal=self.wal,
             integrity=self.integrity,
+            view_writes=self.writes,
         )
         frag.open(lazy=lazy)
         # Copy-on-write: readers (max_slice, query fan-out) iterate

@@ -647,9 +647,9 @@ class Executor:
         # Whole-query memo (the Range/nary routed-path answer to the
         # reference's rank cache): a repeated read-only Count on an
         # unmutated holder is one dict probe validated by the
-        # process-wide MUTATION_EPOCH — skipping re-lowering, plan
-        # construction, and the per-slice generation walk, which
-        # together dwarf the actual fold on small routed queries.
+        # process-wide MUTATION_EPOCH — skipping re-lowering and plan
+        # construction, which together dwarf the actual fold on small
+        # routed queries.
         # Single-node only: with cluster fan-out, remote writes don't
         # bump the LOCAL epoch, so a hit could serve another node's
         # stale slices. (SPMD replicates writes to every rank's holder
@@ -734,8 +734,8 @@ class Executor:
         if backend_on or qkey is not None:
             # Lowering is pure host work; with the backend off it still
             # runs when a memo entry will be stored, because the leaves
-            # name exactly the fragments the revalidation token must
-            # cover (a tokenless entry dies on every epoch bump).
+            # name exactly the views the revalidation token must cover
+            # (a tokenless entry dies on every epoch bump).
             from .parallel.plan import _lower_tree, _tree_signature
 
             leaves: list = []
@@ -754,7 +754,7 @@ class Executor:
                     else:
                         lowered = (shape, leaves)
                 if qkey is not None:
-                    qtoken = self._query_token(index, leaves, slices)
+                    qtoken = self._query_token(index, leaves)
 
         # Routing decision, recorded for trace attribution: which
         # engine serves, and which kill-switches steered it there.
@@ -826,7 +826,7 @@ class Executor:
             n = int(result or 0)
             if qkey is not None:
                 # Stored against the PRE-compute epoch (and PRE-compute
-                # fragment generations): a write racing the fold bumped
+                # view write counters): a write racing the fold bumped
                 # them, so the entry can never validate — stale results
                 # invalidate, they don't serve.
                 self._host_cache.query_put(qkey, qepoch, n, qsepoch, qtoken)
@@ -852,29 +852,23 @@ class Executor:
                                cache=cache_tag)
         return n
 
-    # Above this fan-out, gathering (fragment, generation) pairs for
-    # the memo token costs more than the occasional refold it saves;
-    # tokenless entries still epoch-validate (the r4 behavior).
-    _QUERY_TOKEN_MAX = 8192
+    def _query_token(self, index: str, leaves) -> tuple:
+        """((WriteCounter, value), ...), one entry per unique leaf VIEW
+        of the lowered tree — the revalidation token for
+        HostQueryCache.query_get, O(views) whatever the fan-out. Read
+        BEFORE the fold on purpose (see query_put). An absent view is
+        simply skipped: it has no fragment, and one appearing later
+        bumps the structural epoch (View._open_fragment), which
+        already invalidates the token.
 
-    def _query_token(self, index: str, leaves, slices) -> Optional[tuple]:
-        """((fragment, generation), ...) across every (slice, unique
-        leaf view) this Count touches — the revalidation token for
-        HostQueryCache.query_get. Read BEFORE the fold on purpose (see
-        query_put). Absent fragments are simply skipped: a fragment
-        appearing later bumps the structural epoch (View._open_fragment),
-        which already invalidates the token."""
-        uniq = list(dict.fromkeys((f, v) for f, v, _r, _q in leaves))
-        if len(uniq) * len(slices) > self._QUERY_TOKEN_MAX:
-            return None
-        pairs = []
-        holder = self.holder
-        for s in slices:
-            for frame, view in uniq:
-                frag = holder.fragment(index, frame, view, s)
-                if frag is not None:
-                    pairs.append((frag, frag.generation))
-        return tuple(pairs)
+        Never weaker than a token of every touched fragment's
+        generation: whatever moves a generation moves its view's
+        counter (fragment._log_append/_log_reset). Coarser in one
+        case: a Count over a SUBSET of a view's slices refolds after a
+        write to another slice of that view."""
+        views = (self.holder.view(index, f, v) for f, v in
+                 dict.fromkeys((f, v) for f, v, _r, _q in leaves))
+        return tuple((v.writes, v.writes.n) for v in views if v is not None)
 
     # -- BSI aggregates ------------------------------------------------------
 

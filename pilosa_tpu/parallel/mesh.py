@@ -1373,6 +1373,12 @@ def compile_serve_count_coarse_pallas_uniform(mesh: Mesh, tree_shape,
     return count_coarse_pallas_uniform
 
 
+# Queries a shared-read group may have and still run the scan's narrow
+# body (compile_serve_count_batch_shared): measured at 2 and 4, where it
+# wins, and at 16, where it loses.
+_SHARED_NARROW_MAX = 4
+
+
 def compile_serve_count_batch_shared(mesh: Mesh, tree_shape,
                                      leaf_map: Tuple[Tuple[int, ...], ...],
                                      num_unique: int):
@@ -1391,9 +1397,18 @@ def compile_serve_count_batch_shared(mesh: Mesh, tree_shape,
     device analog of the reference's per-fragment row cache serving
     many queries from one materialized row (fragment.go:332-367 +
     BitmapCache) — except the "cache" is one scan step's VMEM block.
-    The scan is bound by its 960 sequential steps, not by bytes: 16
-    pairs of 8 rows take 20.6 ms on a v5e, 21 us a step (62.0 ms until
-    PR 30, when the scan read from a re-laid copy of each pool).
+    The scan is bound by its 960 sequential steps and the device ops
+    in each, not by bytes, so it has two bodies (one v5e, 960 slices,
+    launch to ready; PR 32). `wide`, above _SHARED_NARROW_MAX queries:
+    two fetches of scalars, U gathers behind a barrier, one popcount
+    fusion, two adds; 16 pairs of 8 rows 21.5 ms, 28 pairs 25.8 ms.
+    `narrow`: one fetch and one fusion that gathers, folds and counts,
+    so 2 device ops a step where `wide` has 7 + U; 2 queries of 3 rows
+    6.4 ms against 9.3, 4 of 5 rows 9.9 against 12.9, but 16 of 8
+    23.3 and 28 of 8 34.6, which is why `wide` stays. A device op is
+    also an event of a device trace, 960 steps a launch: the herds'
+    groups are narrow, and with `wide` for them stopping a 5-s trace
+    took 70 s (20 s now).
 
     leaf_map is STATIC: leaf_map[b] gives, per leaf position of the
     tree, the unique-leaf index it reads. The compile cache key must
@@ -1410,7 +1425,7 @@ def compile_serve_count_batch_shared(mesh: Mesh, tree_shape,
 
     batch = len(leaf_map)
 
-    def per_shard(words_t, start_t, valid_t, mask):
+    def wide(words_t, start_t, valid_t, mask):
         s_l = words_t[0].shape[0]
         start_st = jnp.stack(start_t)            # (U, S_l)
         valid_st = jnp.stack(valid_t)            # (U, S_l)
@@ -1420,13 +1435,10 @@ def compile_serve_count_batch_shared(mesh: Mesh, tree_shape,
             # the pool as it was staged (16 containers from start*16; a
             # (S, cap/16, 16*W) view of the pool is a copy of it on the
             # chip, see _row_run_blocks) — read once, used by every
-            # query below. The barrier is the load-bearing part:
-            # without it XLA is free to fuse (i.e. DUPLICATE) each
-            # cheap dynamic-slice gather into every consuming fold,
-            # re-reading HBM per query and silently degenerating this
-            # program to the plain batch's traffic. The barrier forces
-            # the U blocks to materialize once (U * 128 KB,
-            # VMEM-resident) before the B folds consume them.
+            # query below. The barrier forces the U blocks to
+            # materialize once (U * 128 KB, VMEM-resident) before the B
+            # folds consume them; without it the compiler fuses each
+            # gather into the folds that read it.
             with jax.named_scope("gather_leaves"):
                 blocks = list(lax.optimization_barrier(tuple(
                     lax.dynamic_slice(
@@ -1459,6 +1471,46 @@ def compile_serve_count_batch_shared(mesh: Mesh, tree_shape,
                                jnp.arange(s_l, dtype=jnp.int32))
         return jnp.stack([lax.psum(lo, SLICE_AXIS),
                           lax.psum(hi, SLICE_AXIS)])
+
+    def narrow(words_t, start_t, valid_t, mask):
+        s_l = words_t[0].shape[0]
+        # One int32 a (leaf, slice): the run's container offset, and in
+        # bit 0 whether the leaf is there and the slice is live. A
+        # masked slice zeroes every leaf, and and/or/andnot of zeros
+        # is zero, so the mask needs no multiply of its own. Indexed
+        # [u, s] one by one, the compiler fetches a step's column in
+        # ONE fusion.
+        live = mask != 0
+        table = jnp.stack([
+            st * (2 * ROW_SPAN) + ((v != 0) & live).astype(jnp.int32)
+            for st, v in zip(start_t, valid_t)])             # (U, S_l)
+
+        def step(s, acc):
+            row = [table[u, s] for u in range(num_unique)]
+            with jax.named_scope("gather_leaves"):
+                blocks = [
+                    lax.dynamic_slice(
+                        words_t[u], (s, row[u] >> 1, 0),
+                        (1, ROW_SPAN, words_t[u].shape[2]))
+                    * (row[u] & 1).astype(jnp.uint32)
+                    for u in range(num_unique)]
+            out = []
+            for b in range(batch):
+                blk = fold_tree(tree, lambda i: blocks[leaf_map[b][i]])
+                with jax.named_scope("popcount"):
+                    pc = lax.population_count(blk).sum(dtype=jnp.uint32)
+                out += [(pc & jnp.uint32(0xFFFF)).astype(jnp.int32),
+                        (pc >> 16).astype(jnp.int32)]
+            return tuple(a + o for a, o in zip(acc, out))
+
+        # Scalar carries, [lo, hi] a query: their adds run on the
+        # scalar core and are no device op of their own.
+        init = tuple(lax.pcast(jnp.zeros((), jnp.int32), (SLICE_AXIS,),
+                               to="varying") for _ in range(2 * batch))
+        acc = lax.fori_loop(0, s_l, step, init)
+        return lax.psum(jnp.stack(acc), SLICE_AXIS).reshape(batch, 2).T
+
+    per_shard = narrow if batch <= _SHARED_NARROW_MAX else wide
 
     fn = shard_map(
         per_shard,

@@ -318,6 +318,7 @@ class HostQueryCache:
         self.stats = {"block_hit": 0, "block_miss": 0,
                       "memo_hit": 0, "memo_miss": 0,
                       "query_hit": 0, "query_miss": 0, "query_reval": 0,
+                      "query_put": 0, "query_token_pairs": 0,
                       "matrix_hit": 0, "matrix_miss": 0}
 
     # Leaf dense-matrix cache budget (bytes): a matrix is one leaf
@@ -362,54 +363,40 @@ class HostQueryCache:
         """Whole-QUERY count memo, validated by the process-wide
         MUTATION_EPOCH (core.fragment): the warm path for a repeated
         read-only Count is one dict probe + one int compare — no
-        re-lowering, no plan construction, no per-slice generation
-        walk.
+        re-lowering, no plan construction.
 
-        Second tier (r5): an entry stored with a TOKEN — the
-        structural epoch plus every touched fragment's generation at
-        store time — REVALIDATES after an epoch bump from an
-        unrelated write: if the structural epoch is unchanged (no
-        fragment/frame/index create/delete, no label or time-quantum
-        change anywhere), the fragment SET the query touches is
-        intact, so comparing recorded generations is a complete
-        staleness check. A pass re-stamps the entry at the current
-        epoch — sound because a generation can't move without bumping
-        MUTATION_EPOCH (fragment._log_append/_log_reset), so the next
-        bump forces another generation walk. Entries hold WEAK
-        fragment refs; a dead ref never validates. Without a token
-        (non-lowerable tree, oversized fan-out) any bump invalidates,
-        the r4 behavior."""
+        Second tier: an entry stored with a TOKEN — the structural
+        epoch plus the write counter of every VIEW the query reads
+        (Executor._query_token), as they stood at store time —
+        REVALIDATES after an epoch bump from an unrelated write: if
+        the structural epoch is unchanged (no fragment/frame/index
+        create/delete, no label or time-quantum change anywhere), the
+        fragment SET the query touches is intact, so comparing the
+        recorded counters is a complete staleness check. A pass
+        re-stamps the entry at the current epoch — sound because a
+        counter can't move without bumping MUTATION_EPOCH (they move
+        under one lock, the counter first: `_MutationEpoch`'s ordering
+        rule), so the next bump forces another comparison. Entries
+        hold the counters WEAKLY; a dead ref never validates. Without
+        a token (a tree `_lower_tree` declines) any bump invalidates."""
         with self._mu:
             e = self._query.get(key)
             if e is not None and e[0] == epoch:
                 self._query.move_to_end(key)
                 self.stats["query_hit"] += 1
                 return e[1]
-            if e is None or e[2] is None or s_epoch is None:
-                # No token to walk: the miss is decided — count it in
-                # THIS critical section (the common path takes one
-                # lock round-trip, not two).
-                self.stats["query_miss"] += 1
-                return None
-        # The generation walk can span thousands of weakref derefs
-        # (token cap 8192): run it OUTSIDE the lock — this class
-        # promises dict-sized critical sections only — then re-take
-        # it to re-stamp, tolerating a concurrent replace (the walk
-        # validated OUR entry's count, so returning it is correct
-        # regardless of what the entry says now).
-        tok = e[2]
-        if tok[0] == s_epoch and all(
-                (fr := f()) is not None and fr.generation == g
-                for f, g in tok[1]):
-            with self._mu:
-                if self._query.get(key) is e:
-                    self._query[key] = (epoch, e[1], tok)
-                    self._query.move_to_end(key)
+            tok = None if e is None or s_epoch is None else e[2]
+            # A token is one entry a view: comparing it is dict-sized
+            # work and stays inside this critical section.
+            if tok is not None and tok[0] == s_epoch and all(
+                    (w := ref()) is not None and w.n == n
+                    for ref, n in tok[1]):
+                self._query[key] = (epoch, e[1], tok)
+                self._query.move_to_end(key)
                 self.stats["query_reval"] += 1
-            return e[1]
-        with self._mu:
+                return e[1]
             self.stats["query_miss"] += 1
-        return None
+            return None
 
     def query_peek(self, key: tuple, epoch: int) -> bool:
         """EXPLAIN-surface probe: would a repeat of this query serve
@@ -423,16 +410,19 @@ class HostQueryCache:
 
     def query_put(self, key: tuple, epoch: int, count: int,
                   s_epoch: Optional[int] = None,
-                  frag_gens: Optional[tuple] = None) -> None:
-        """`frag_gens`: ((fragment, generation), ...) read BEFORE the
-        fold — a write racing the fold moved some generation past its
+                  view_writes: Optional[tuple] = None) -> None:
+        """`view_writes`: ((WriteCounter, value), ...) read BEFORE the
+        fold — a write racing the fold moved some counter past its
         recorded value, so the token can never validate (same
         pre-compute rationale as `epoch`)."""
         token = None
-        if frag_gens is not None and s_epoch is not None:
+        if view_writes is not None and s_epoch is not None:
             token = (s_epoch,
-                     tuple((weakref.ref(f), g) for f, g in frag_gens))
+                     tuple((weakref.ref(w), n) for w, n in view_writes))
         with self._mu:
+            if token is not None:
+                self.stats["query_put"] += 1
+                self.stats["query_token_pairs"] += len(token[1])
             self._query[key] = (epoch, count, token)
             self._query.move_to_end(key)
             while len(self._query) > self._QUERY_MAX:

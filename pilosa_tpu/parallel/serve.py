@@ -1345,16 +1345,15 @@ class MeshManager:
             # Epoch pair read UNDER _mu, before any staleness
             # inspection: a write that lands mid-walk bumps the pair
             # past `ep`, so stamping `ep` after the walk can never mark
-            # that write validated. Ordering on the write side:
-            # generation moves first, the epoch second
-            # (fragment.py:334-335) — any bump included in `ep` has its
-            # generation visible to the walk/snapshot below. The read
-            # must sit INSIDE the lock: validators serialize on _mu, so
-            # an in-lock read is always >= any pair a finished
-            # validator stamped — read outside, a reader that stalled
-            # before the lock could stamp its stale pair OVER a newer
-            # one and silently disable the O(1) fast path until the
-            # next write.
+            # that write validated. By `_MutationEpoch`'s ordering rule
+            # (generation first, the epoch second) any bump included
+            # in `ep` has its generation visible to the walk/snapshot
+            # below. The read must sit INSIDE the lock: validators
+            # serialize on _mu, so an in-lock read is always >= any
+            # pair a finished validator stamped — read outside, a
+            # reader that stalled before the lock could stamp its
+            # stale pair OVER a newer one and silently disable the
+            # O(1) fast path until the next write.
             ep = MUTATION_EPOCH.read()
             sv = self._views.get(key)
             if sv is not None:

@@ -529,11 +529,11 @@ class TestQueryLevelMemo:
 
 
 class TestQueryMemoRevalidation:
-    """r5 second tier: entries carry (structural epoch, fragment
-    generations); an epoch bump from an UNRELATED write revalidates in
-    a generation walk instead of refolding, while touched-fragment
-    writes and any structural change (new fragment/frame/index, label
-    or quantum change) still invalidate."""
+    """r5 second tier: entries carry (structural epoch, the write
+    counter of every view read); an epoch bump from an UNRELATED write
+    revalidates by comparing the counters instead of refolding, while
+    touched-view writes and any structural change (new
+    fragment/frame/index, label or quantum change) still invalidate."""
 
     def _exec(self, holder):
         seed(holder, bits=[(r, c) for r in range(3) for c in (1, 2, 70000)])
@@ -604,6 +604,159 @@ class TestQueryMemoRevalidation:
         assert q(e, "i", pql, slices=[0, 1])[0] == 3
         holder.frame("i", "general").set_bit(0, SLICE_WIDTH + 8)
         assert q(e, "i", pql, slices=[0, 1])[0] == 4
+
+    # -- the token is one write counter a view (PR 32) ----------------------
+
+    def test_other_slice_write_refolds_subset_count(self, holder):
+        # The documented coarsening: the token is per VIEW, so a Count
+        # over slices=[0] refolds after a write to slice 1 of the view
+        # it reads (a per-fragment token used to revalidate here).
+        e = self._exec(holder)
+        holder.frame("i", "general").set_bit(0, SLICE_WIDTH + 8)
+        pql = "Count(Bitmap(rowID=0))"
+        assert q(e, "i", pql, slices=[0])[0] == 3
+        r0 = e.host_cache_stats["query_reval"]
+        m0 = e.host_cache_stats["query_miss"]
+        holder.frame("i", "general").set_bit(0, SLICE_WIDTH + 9)
+        assert q(e, "i", pql, slices=[0])[0] == 3
+        assert e.host_cache_stats["query_reval"] == r0
+        assert e.host_cache_stats["query_miss"] == m0 + 1
+
+    @pytest.mark.parametrize("how", ["import", "restore"])
+    def test_log_reset_invalidates(self, holder, how):
+        # Imports and restores replace storage wholesale (_log_reset):
+        # they move the view's counter like any bit write.
+        import io
+
+        e = self._exec(holder)
+        frag = holder.fragment("i", "general", "standard", 0)
+        backup = io.BytesIO()
+        frag.write_to_tar(backup)
+        pql = "Count(Bitmap(rowID=0))"
+        assert q(e, "i", pql)[0] == 3
+        r0 = e.host_cache_stats["query_reval"]
+        if how == "import":
+            holder.frame("i", "general").import_bits([0, 0], [7, 8])
+            want = 5
+        else:
+            holder.frame("i", "general").set_bit(0, 555)
+            assert q(e, "i", pql)[0] == 4
+            backup.seek(0)
+            frag.read_from_tar(backup)
+            want = 3
+        assert q(e, "i", pql)[0] == want
+        assert e.host_cache_stats["query_reval"] == r0
+
+    def test_range_count_one_entry_per_quantum_view(self, holder):
+        idx = holder.create_index_if_not_exists("i")
+        f = idx.create_frame_if_not_exists("events", time_quantum="YM")
+        f.set_bit(1, 100, t=datetime(2017, 4, 2))
+        f.set_bit(1, 200, t=datetime(2017, 5, 9))
+        f.set_bit(1, 300, t=datetime(2018, 1, 1))  # views a later write hits
+        e = Executor(holder, use_device=True, device_min_work=10**9)
+        pql = ('Count(Range(rowID=1, frame="events",'
+               ' start="2017-04-01T00:00", end="2017-06-01T00:00"))')
+        s0 = dict(e.host_cache_stats)
+        assert q(e, "i", pql)[0] == 2
+        s1 = dict(e.host_cache_stats)
+        assert s1["query_put"] - s0["query_put"] == 1
+        # standard_201704 and standard_201705: one entry each, whatever
+        # the number of slices
+        assert s1["query_token_pairs"] - s0["query_token_pairs"] == 2
+        # a write to an unrelated quantum (and to `standard`, which a
+        # Range never reads) revalidates ...
+        f.set_bit(1, 301, t=datetime(2018, 1, 2))
+        assert q(e, "i", pql)[0] == 2
+        s2 = dict(e.host_cache_stats)
+        assert s2["query_reval"] == s1["query_reval"] + 1
+        assert s2["query_miss"] == s1["query_miss"]
+        # ... and one to a quantum it reads refolds
+        f.set_bit(1, 201, t=datetime(2017, 5, 10))
+        assert q(e, "i", pql)[0] == 3
+        assert e.host_cache_stats["query_reval"] == s2["query_reval"]
+
+    def test_write_between_token_read_and_put_never_validates(
+            self, holder, monkeypatch):
+        # The token is read BEFORE the fold: a write that lands after
+        # it (here at the last moment, just before query_put) is not in
+        # the stored count, and the entry must never serve.
+        e = self._exec(holder)
+        pql = "Count(Bitmap(rowID=0))"
+        cache = e._host_cache
+        real_put = cache.query_put
+
+        def racing_put(*a, **kw):
+            holder.frame("i", "general").set_bit(0, 555)
+            return real_put(*a, **kw)
+
+        monkeypatch.setattr(cache, "query_put", racing_put)
+        assert q(e, "i", pql)[0] == 3  # folded before the write landed
+        monkeypatch.setattr(cache, "query_put", real_put)
+        h0 = e.host_cache_stats["query_hit"]
+        r0 = e.host_cache_stats["query_reval"]
+        assert q(e, "i", pql)[0] == 4
+        assert e.host_cache_stats["query_hit"] == h0
+        assert e.host_cache_stats["query_reval"] == r0
+
+    def test_token_costs_views_not_slices(self, holder, monkeypatch):
+        from pilosa_tpu.parallel.plan import _lower_tree
+
+        n_slices = 64
+        bits = [(r, s * SLICE_WIDTH + 1) for s in range(n_slices)
+                for r in (0, 1)]
+        seed(holder, bits=bits)
+        seed(holder, frame="other", bits=[(0, 1)])
+        e = Executor(holder, use_device=True, device_min_work=10**9)
+        pql = ("Count(Union(Intersect(Bitmap(rowID=0), Bitmap(rowID=1)),"
+               " Bitmap(rowID=0, frame=other)))")
+        leaves = []
+        assert _lower_tree(holder, "i",
+                           parse_string(pql).calls[0].children[0], leaves)
+        calls = []
+        real = holder.fragment
+        monkeypatch.setattr(
+            holder, "fragment",
+            lambda *a: calls.append(a) or real(*a))
+        tok = e._query_token("i", leaves)
+        assert calls == []
+        views = [holder.view("i", "general", "standard"),
+                 holder.view("i", "other", "standard")]
+        assert list(tok) == [(v.writes, v.writes.n) for v in views]
+        monkeypatch.undo()
+        s0 = dict(e.host_cache_stats)
+        assert q(e, "i", pql)[0] == n_slices
+        s1 = dict(e.host_cache_stats)
+        assert s1["query_put"] - s0["query_put"] == 1
+        assert s1["query_token_pairs"] - s0["query_token_pairs"] == len(views)
+
+    def test_concurrent_writers_lose_no_bump(self, holder):
+        # Fragments of one view are written under their OWN locks; a
+        # lost increment of the view's counter is the one thing that
+        # could validate a stale entry.
+        import sys
+        import threading
+
+        f = seed(holder, bits=[(0, 1), (0, SLICE_WIDTH + 1)])
+        writes = holder.view("i", "general", "standard").writes
+        n0, per_thread = writes.n, 2000
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [
+                threading.Thread(
+                    target=lambda base=base: [
+                        f.set_bit(1 + i % 7, base + i % 1000)
+                        for i in range(per_thread)],
+                    daemon=True)
+                for base in (0, SLICE_WIDTH)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=20)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert writes.n == n0 + 2 * per_thread
 
 
 class TestCallCacheKey:

@@ -1365,10 +1365,14 @@ class TestCoarseGather:
         """(fn, pool operands, the unique row each (start, valid) slot
         reads, the queries) for the three shapes the serving layer
         launches: a lone Count, a padded group of 16 and a shared-read
-        group."""
+        group, which scans with one body up to _SHARED_NARROW_MAX
+        queries and with another above."""
         from pilosa_tpu.parallel import mesh as M
 
-        if program == "shared":
+        if program.startswith("shared"):
+            pairs = pairs * (2 if program == "shared_wide" else 1)
+            assert (len(pairs) > M._SHARED_NARROW_MAX) \
+                == (program == "shared_wide")
             return (M.compile_serve_count_batch_shared(mesh, tree, pairs, 3),
                     3, (0, 1, 2), pairs)
         batch = 1 if program == "batch1" else 16
@@ -1376,7 +1380,8 @@ class TestCoarseGather:
         return (M.compile_serve_count(mesh, tree, 2, batch, runs=True),
                 2, tuple(r for qr in queries for r in qr), queries)
 
-    @pytest.mark.parametrize("program", ["batch1", "batch16", "shared"])
+    @pytest.mark.parametrize("program", ["batch1", "batch16", "shared",
+                                         "shared_wide"])
     @pytest.mark.parametrize("cap", [32, 128])
     @pytest.mark.parametrize("devices", [1, 4])
     def test_coarse_rows_match_numpy(self, devices, cap, program):
@@ -1418,7 +1423,8 @@ class TestCoarseGather:
                        for sl in range(s) if mask[sl])
             assert M.combine_count(limbs[:, j]) == want, (j, a, b)
 
-    @pytest.mark.parametrize("program", ["batch1", "batch16", "shared"])
+    @pytest.mark.parametrize("program", ["batch1", "batch16", "shared",
+                                         "shared_wide"])
     def test_coarse_programs_never_reshape_the_pool_minor(self, program):
         """On the chip the pool's two minor dimensions are tiled, so a
         reshape that changes the last one is a copy of the whole pool,

@@ -18,6 +18,7 @@ could land on a worker that cannot load the library, and skip).
 """
 
 import itertools
+import re
 
 import numpy as np
 import pytest
@@ -440,8 +441,6 @@ _NO_BYTES_MOVE = {"parameter", "bitcast", "tuple", "get-tuple-element",
 def instructions(text):
     """(name, opcode, bytes of the largest array in the result) of every
     instruction of an HLO module's text, fused computations included."""
-    import re
-
     for line in text.splitlines():
         head, eq, rest = line.partition(" = ")
         if not eq or not head.strip().startswith(("%", "ROOT ")):
@@ -499,6 +498,48 @@ def test_xla_coarse_programs_do_not_copy_the_pool(chip, four, program,
     limit = (16 * 2**20 if program != "coarse"
              else 1.1 * rows if batch == 1 else shard)
     assert mem.temp_size_in_bytes < limit, (mem.temp_size_in_bytes, limit)
+
+
+def _scan_step_ops(text):
+    """The instructions of the program's while body that the chip runs as
+    device ops of their own, and a device trace records as events, each
+    step: fusions and dynamic slices outside scalar memory (the scalar
+    core's adds, compares and selects are neither)."""
+    body = re.search(r"body=%?([\w.\-]+)", text).group(1)
+    start = text.index("\n%" + body + " (")
+    lines = text[start:text.index("\n}", start)].splitlines()[1:]
+    ops = []
+    for line in lines:
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) "
+                     r"([\w\-]+)\(", line)
+        if m and m.group(3) in ("fusion", "dynamic-slice", "concatenate",
+                                "copy") and "S(6)" not in m.group(2):
+            ops.append(m.group(1))
+    return ops
+
+
+@pytest.mark.parametrize("leaf_map,uniques,devices,most", [
+    (((0, 1), (1, 2)), 3, 1, 2), (((0, 1), (1, 2)), 3, 4, 2),
+    (((0, 1), (1, 2), (2, 3), (3, 4)), 5, 1, 2),
+    (PAIRS28[:16], 8, 1, 7 + 8)])
+def test_shared_scan_step_is_few_device_ops(chip, four, leaf_map, uniques,
+                                            devices, most):
+    """The shared-read scan pays for every device op of its step 960 times
+    a launch, in time and in the events of a device trace (a traced herd
+    run: 470,000 of 492,000 events, and 70 s to stop the trace; PR 32).
+    Up to _SHARED_NARROW_MAX queries a step is two: the fetch of its
+    scalars and the fusion that gathers, folds and counts. The wide body
+    is held to what it has."""
+    from pilosa_tpu.parallel import mesh as M
+
+    c = chip if devices == 1 else four
+    s = S * 2 if devices == 4 else S
+    w, mask = c.sliced(np.uint32, CAP, 2048, s=s), c.sliced(np.int32, s=s)
+    text, _ = compiled(
+        M.compile_serve_count_batch_shared(c.mesh, AND2, leaf_map, uniques),
+        (w,) * uniques, *c.starts_valid(uniques, s=s), mask)
+    ops = _scan_step_ops(text)
+    assert 0 < len(ops) <= most, ops
 
 
 # -- what a refusal looks like ------------------------------------------------
