@@ -1061,6 +1061,17 @@ class Handler:
                 fb.add(stats.get(f"fallback_{reason}", 0),
                        {"reason": reason})
             fams.append(fb)
+            refused = prom.MetricFamily(
+                "pilosa_container_patch_refused_total", "counter",
+                "Containers that writes created and the staged view "
+                "was restaged for, not patched, by reason (no_slot = "
+                "the slice's capacity is used up, new_row = the row "
+                "is not in the view's row table, format = a sparse or "
+                "mixed-format view).")
+            for reason in ("no_slot", "new_row", "format"):
+                refused.add(stats.get(f"container_patch_refused_{reason}",
+                                      0), {"reason": reason})
+            fams.append(refused)
             fams.append(prom.MetricFamily(
                 "pilosa_plan_quarantined_total", "counter",
                 "Plan signatures quarantined off the device path "

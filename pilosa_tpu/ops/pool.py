@@ -139,8 +139,87 @@ def scatter_words(words, slot, word, set_mask, clear_mask):
     return words.at[slot, word].set(upd, mode="drop")
 
 
+class PatchRefused(KeyError):
+    """A set targets a container the pool image lacks and cannot be
+    given: `reason` is "new_row" (the row is not in the image's dense
+    row table, so it has no key) or "no_slot" (the slice's capacity is
+    used up). The caller rebuilds the image. A KeyError, as a set of an
+    absent container was before containers could be patched in."""
+
+    def __init__(self, reason: str):
+        super().__init__(f"set targets a container absent from the pool "
+                         f"image ({reason})")
+        self.reason = reason
+
+
+def _container_keys(row_ids: np.ndarray, pos: np.ndarray):
+    """Slice-local positions -> (pool key int32, row-is-in-the-table)."""
+    rows = pos >> np.uint64(20)
+    dense = np.searchsorted(row_ids, rows)
+    if len(row_ids):
+        known_row = (dense < len(row_ids)) & (
+            row_ids[np.minimum(dense, len(row_ids) - 1)] == rows)
+    else:
+        known_row = np.zeros(len(pos), dtype=bool)
+    key = (dense * ROW_SPAN
+           + ((pos >> np.uint64(16)) & np.uint64(15)).astype(np.int64)
+           ).astype(np.int32)
+    return key, known_row
+
+
+def assign_free_slots(keys_row: np.ndarray, slots_row: np.ndarray,
+                      row_ids: np.ndarray, pos: np.ndarray,
+                      val: np.ndarray):
+    """Give every container that a set of one slice's folded mutations
+    targets and the pool image lacks a free slot of the slice.
+
+    THE ORDER OF KEYS AFTER A PATCH: appended, with a host-side order.
+    A slice's device keys are sorted as staged, in slots [0, n), and
+    every slot from n on is free: INVALID_KEY, zero words (no slot is
+    ever given back: an emptied container rebuilds the image). A
+    created container takes slot n, then n + 1, ..., wherever its key
+    sorts, so no staged container moves and no (idx, hit) resolved
+    against them goes stale. The host keeps the slice's keys SORTED
+    (`keys_row`, INVALID_KEY-padded) and beside them `slots_row`:
+    slots_row[i] is the device slot of keys_row[i] (the identity until
+    the first patch). Every host lookup searches keys_row and maps the
+    position through slots_row (plan_slice_mutations here,
+    mesh.resolve_row_indices, mesh.coarse_row_starts); no device
+    program searches keys: each reads its slot's key // 16 and
+    key % 16 wherever the slot lies.
+
+    Returns None when every set targets a container the image holds,
+    else (keys_row', slots_row', new_keys, new_slots): copies of the two
+    rows with the created keys inserted in order, and the (K,) int32
+    keys and the slots they were given. Raises PatchRefused.
+    """
+    pos = np.asarray(pos, dtype=np.uint64)
+    sets = pos[np.asarray(val, dtype=bool)]
+    key, known_row = _container_keys(row_ids, sets)
+    cap = keys_row.shape[0]
+    at = np.searchsorted(keys_row, key)
+    held = known_row & (at < cap) & (keys_row[np.minimum(at, cap - 1)] == key)
+    if held.all():
+        return None
+    if not known_row.all():
+        raise PatchRefused("new_row")
+    new_keys = np.unique(key[~held])
+    n = int(np.searchsorted(keys_row, INVALID_KEY))  # live containers
+    if n + len(new_keys) > cap:
+        raise PatchRefused("no_slot")
+    taken = n + len(new_keys)
+    new_slots = np.arange(n, taken, dtype=np.int32)
+    at = np.searchsorted(keys_row[:n], new_keys)
+    return (np.concatenate([np.insert(keys_row[:n], at, new_keys),
+                            keys_row[taken:]]),
+            np.concatenate([np.insert(slots_row[:n], at, new_slots),
+                            slots_row[taken:]]),
+            new_keys, new_slots)
+
+
 def plan_slice_mutations(keys_row: np.ndarray, row_ids: np.ndarray,
-                         pos: np.ndarray, val: np.ndarray):
+                         pos: np.ndarray, val: np.ndarray,
+                         slots_row: Optional[np.ndarray] = None):
     """Fold one slice's mutations into a (slot, word, set_mask,
     clear_mask) scatter plan against an existing pool image.
 
@@ -155,30 +234,26 @@ def plan_slice_mutations(keys_row: np.ndarray, row_ids: np.ndarray,
     instead of a full pool re-upload.
 
     keys_row: the pool's sorted (INVALID_KEY-padded) key array;
-    row_ids: the pool's dense row table. Returns unpadded 1-D arrays.
+    row_ids: the pool's dense row table; slots_row: the device slot of
+    each entry of keys_row where containers were patched in
+    (assign_free_slots has the layout; None = the position itself).
+    Returns unpadded 1-D arrays.
     Raises KeyError when a set targets a row/container absent from the
-    pool (stale image — caller rebuilds); clears of absent containers
-    are dropped (nothing to clear, matching roaring remove of a missing
-    container key).
+    pool (stale image — caller rebuilds, or assigns it a free slot
+    first); clears of absent containers are dropped (nothing to clear,
+    matching roaring remove of a missing container key).
     """
     pos = np.asarray(pos, dtype=np.uint64)
     val = np.asarray(val, dtype=bool)
-    rows = pos >> np.uint64(20)
-    dense = np.searchsorted(row_ids, rows)
-    if len(row_ids):
-        known_row = (dense < len(row_ids)) & (
-            row_ids[np.minimum(dense, len(row_ids) - 1)] == rows)
-    else:
-        known_row = np.zeros(len(pos), dtype=bool)
-    key = (dense * ROW_SPAN
-           + ((pos >> np.uint64(16)) & np.uint64(15)).astype(np.int64)
-           ).astype(np.int32)
+    key, known_row = _container_keys(row_ids, pos)
     sl = np.searchsorted(keys_row, key).astype(np.int64)
     known = known_row & (sl < keys_row.shape[0]) & (
         keys_row[np.minimum(sl, keys_row.shape[0] - 1)] == key)
     if np.any(val & ~known):
         raise KeyError("set targets a container absent from the pool image")
     sl, pos, val = sl[known], pos[known], val[known]
+    if slots_row is not None:
+        sl = slots_row[sl].astype(np.int64)
     wd = ((pos & np.uint64(0xFFFF)) >> np.uint64(5)).astype(np.int32)
     bit = np.uint32(1) << (pos & np.uint64(31)).astype(np.uint32)
 

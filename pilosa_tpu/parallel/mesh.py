@@ -1045,11 +1045,15 @@ def combine_count(limbs) -> int:
     return (int(limbs[1]) << 16) + int(limbs[0])
 
 
-def resolve_row_indices(keys_host: np.ndarray, dense_id: int):
+def resolve_row_indices(keys_host: np.ndarray, dense_id: int,
+                        slots_host: Optional[np.ndarray] = None):
     """Host-side row → container-location resolution for the serving
     count path.
 
-    keys_host: (S, cap) sorted int32 pool keys (INVALID_KEY padded).
+    keys_host: (S, cap) sorted int32 pool keys (INVALID_KEY padded);
+    slots_host: (S, cap) device slot of each of them, where the pool
+    takes created containers into free slots (the layout:
+    ops.pool.assign_free_slots; None = a key's position is its slot).
     Returns (idx (S, 16) int32 WITHIN-SLICE container indices in
     [0, cap) and hit (S, 16) uint32). Indices are within-slice — not
     flat — because inside shard_map each shard only holds its local
@@ -1076,6 +1080,8 @@ def resolve_row_indices(keys_host: np.ndarray, dense_id: int):
     within = np.clip(i.reshape(s, ROW_SPAN)
                      - (np.arange(s, dtype=np.int64) * cap)[:, None],
                      0, cap - 1)
+    if slots_host is not None:
+        within = np.take_along_axis(slots_host, within, axis=1)
     return within.astype(np.int32), hit.reshape(s, ROW_SPAN)
 
 
@@ -1096,7 +1102,8 @@ def _gather_leaf_blocks(words_t, idx_t, hit_t, i):
         return blk * hit_t[i].reshape(-1)[:, None]
 
 
-def coarse_row_starts(keys_host: np.ndarray, dense_id: int):
+def coarse_row_starts(keys_host: np.ndarray, dense_id: int,
+                      slots_host: Optional[np.ndarray] = None):
     """Host-side COARSE eligibility check for one leaf row: when every
     slice holds the row's 16 containers as one contiguous, 16-aligned
     run (or holds none of them), the serving kernels can gather the row
@@ -1116,7 +1123,11 @@ def coarse_row_starts(keys_host: np.ndarray, dense_id: int):
     multiple, so fully-dense rows land aligned); sparse or partial rows
     fall back to the general gather path (resolve_row_indices).
 
-    Returns (starts (S,) int32 row-run indices [pos/16], valid (S,)
+    slots_host is resolve_row_indices': a row with a container patched
+    into a free slot is eligible only if its 16 slots are still one
+    aligned run, which a slot taken later never continues.
+
+    Returns (starts (S,) int32 row-run indices [slot/16], valid (S,)
     uint32 presence flags) or None when any slice is partial/unaligned.
     """
     s, cap = keys_host.shape
@@ -1136,16 +1147,22 @@ def coarse_row_starts(keys_host: np.ndarray, dense_id: int):
         return None  # staged nowhere: the general path answers zero
         #              via hit=0 without a special case here
     ps = pos[present]
+    if (ps + ROW_SPAN > cap).any():
+        return None
+    at = np.flatnonzero(present)[:, None]
+    span = np.arange(ROW_SPAN, dtype=np.int64)
+    run = ps[:, None] + span[None, :]
+    if not (keys_host[at, run] == (lo + span)[None, :]).all():
+        return None
+    if slots_host is not None:
+        slots = slots_host[at, run].astype(np.int64)
+        ps = slots[:, 0]
+        if not (slots == ps[:, None] + span[None, :]).all():
+            return None
     if ((ps % ROW_SPAN) != 0).any():
         return None
-    rows = ps // ROW_SPAN
-    run = keys_host.reshape(s, cap // ROW_SPAN, ROW_SPAN)[
-        np.flatnonzero(present), rows]
-    want = lo + np.arange(ROW_SPAN, dtype=np.int64)
-    if not (run == want[None, :]).all():
-        return None
     starts = np.zeros(s, dtype=np.int32)
-    starts[present] = rows.astype(np.int32)
+    starts[present] = (ps // ROW_SPAN).astype(np.int32)
     return starts, present.astype(np.uint32)
 
 
@@ -1918,6 +1935,47 @@ def compile_serve_apply_writes(mesh: Mesh):
         return ShardedIndex(keys=keys, words=words)
 
     return apply_writes
+
+
+def pack_container_patches(per_slice, num_slices: int, capacity: int):
+    """Stack per-slice (new_keys, new_slots) of
+    ops.pool.assign_free_slots into padded (S, K) (slot, key) arrays
+    for compile_serve_patch_containers; padding as
+    pack_mutation_batches: the out-of-bounds slot `capacity`."""
+    from ..ops.pool import mutation_batch_width
+
+    k = mutation_batch_width(max(len(v[0]) for v in per_slice.values()))
+    slot = np.full((num_slices, k), capacity, dtype=np.int32)
+    key = np.full((num_slices, k), INVALID_KEY, dtype=np.int32)
+    for si, (new_keys, new_slots) in per_slice.items():
+        slot[si, :len(new_slots)] = new_slots
+        key[si, :len(new_keys)] = new_keys
+    return slot, key
+
+
+def compile_serve_patch_containers(mesh: Mesh):
+    """Jit the write of created containers' keys into free slots of
+    sharded pools: fn(keys (S, C), slot (S, K), key (S, K)) -> keys.
+    The slots' words are zero already (ops.pool.assign_free_slots) and
+    take the containers' bits through compile_serve_apply_writes, so
+    only the (S, C) int32 keys pass through this program; each shard
+    writes the slots of its own slices and drops the padding."""
+
+    def one(keys_row, slot, key):
+        return keys_row.at[slot].set(key, mode="drop")
+
+    fn = shard_map(
+        lambda keys, slot, key: jax.vmap(one)(keys, slot, key),
+        mesh=mesh,
+        in_specs=(P(SLICE_AXIS),) * 3,
+        out_specs=P(SLICE_AXIS),
+    )
+
+    @jax.jit
+    def patch_containers(keys, slot, key):
+        return fn(keys, slot, key)
+
+    return patch_containers
 
 
 def default_mesh(n_devices: Optional[int] = None) -> Mesh:

@@ -13,10 +13,15 @@ Staging and maintenance:
     then maintained INCREMENTALLY: each Fragment keeps a mutation log
     (core/fragment.py log_since), and refresh() folds the bits written
     since the staged generation into one device scatter
-    (compile_serve_apply_writes). Only container churn — a container
-    created or emptied, or a bulk import — forces a restage, matching
-    the reference's cheap mmap mutation (fragment.go:371-413) without
-    ever re-uploading the pool.
+    (compile_serve_apply_writes). A container the writes CREATED is
+    patched into a free slot of its slice first (_refresh_walk,
+    compile_serve_patch_containers; the pool's key order afterwards:
+    ops.pool.assign_free_slots), and its bits go through the same
+    scatter. Only what a slot cannot take forces a restage — a
+    container emptied, a slice with no free slot, a row new to the
+    view, a sparse or mixed-format view, a fragment that appeared or
+    went, a bulk import — matching the reference's cheap mmap mutation
+    (fragment.go:371-413) without ever re-uploading the pool.
   - Queries carry a per-slice ownership mask, so one staged index
     serves any slice subset (the cluster's slicesByNode split,
     executor.go:1087-1101) and non-owned slices contribute nothing to
@@ -45,6 +50,8 @@ from ..ops.pool import (
     CONTAINER_WORDS,
     INVALID_KEY,
     ROW_SPAN,
+    PatchRefused,
+    assign_free_slots,
     fold_log_entries,
     plan_slice_mutations,
 )
@@ -68,10 +75,12 @@ from .mesh import (
     compile_serve_count_coarse_pallas,
     compile_serve_count_coarse_pallas_batch,
     compile_serve_count_coarse_pallas_uniform,
+    compile_serve_patch_containers,
     compile_serve_row_counts,
     compile_serve_row_counts_src,
     compile_serve_row_counts_tanimoto,
     default_mesh,
+    pack_container_patches,
     pack_mutation_batches,
     resolve_row_indices,
 )
@@ -137,8 +146,9 @@ class DispatchGenMoved(Exception):
 class StagedView:
     """One (index, frame, view)'s staged device image + bookkeeping."""
 
-    __slots__ = ("sharded", "row_ids", "keys_host", "slice_gens",
-                 "num_slices", "idx_cache", "host_idx_cache", "last_used",
+    __slots__ = ("sharded", "row_ids", "keys_host", "slots_host",
+                 "free_slots", "slice_gens", "num_slices", "idx_cache",
+                 "host_idx_cache", "last_used",
                  "last_stage_s", "inc_spend_s", "inc_ewma_s", "inc_count",
                  "validated_epoch", "pins", "sparse", "sparse_keys_host",
                  "sparse_cards_host", "slice_formats", "sparse_idx_cache",
@@ -149,7 +159,15 @@ class StagedView:
                  slice_formats=None):
         self.sharded = sharded            # ShardedIndex (device, padded S)
         self.row_ids = row_ids            # (R,) uint64 dense row table
-        self.keys_host = keys_host        # (S_padded, cap) int32 host copy
+        self.keys_host = keys_host        # (S_padded, cap) int32, SORTED
+        # The device slot of each entry of keys_host, and how many slots
+        # each slice has left for containers created after staging
+        # (ops.pool.assign_free_slots has the layout): as staged the
+        # identity, and capacity less the slice's containers.
+        s_pad, cap = keys_host.shape
+        self.slots_host = np.tile(np.arange(cap, dtype=np.int32),
+                                  (s_pad, 1))
+        self.free_slots = cap - (keys_host != INVALID_KEY).sum(axis=1)
         self.slice_gens = slice_gens      # per-slice (fragment, gen);
         #                                   None = staged as absent
         self.num_slices = num_slices      # unpadded staged slice count
@@ -172,7 +190,9 @@ class StagedView:
         # output), LRU-ordered (move-to-end on hit — a hot row staged
         # early must not be the first evicted at the 1024 bound). Valid
         # as long as the key layout is — incremental word scatters don't
-        # touch it; a restage builds a fresh StagedView, so the cache
+        # touch it; a container patched into a free slot moves no other
+        # container and drops its own row's entry (_refresh_walk); a
+        # restage builds a fresh StagedView, so the cache
         # dies with the stale keys. Uploading these per query is
         # several device_puts; cached, a repeat-row query pays
         # nothing.
@@ -503,6 +523,7 @@ class MeshManager:
         self._burst_mu = threading.Lock()
         self._burst_hint = 0
         self._apply_fn = None
+        self._patch_fn = None  # compile_serve_patch_containers
         # EWMA (seconds) of measured incremental-apply cost — the other
         # side of refresh()'s cost gate (vs StagedView.last_stage_s) —
         # and the batch/pool shapes already compiled (novel shapes pay
@@ -606,6 +627,16 @@ class MeshManager:
             "h2d_bytes": 0, "h2d_dispatch_us": 0,
             "refresh_pick_incremental": 0, "refresh_pick_restage": 0,
             "refresh_probe_restage": 0, "inc_ewma_us": 0,
+            # Containers that writes created and _refresh_walk patched
+            # into free slots of the staged pool; the creations it had
+            # to restage for instead, by reason (/metrics:
+            # pilosa_container_patch_refused_total{reason}); and the
+            # fewest free slots of any slice of any dense staged view,
+            # set at staging and after each patch: the distance to the
+            # next no_slot.
+            "container_patches": 0, "container_patch_refused_no_slot": 0,
+            "container_patch_refused_new_row": 0,
+            "container_patch_refused_format": 0, "free_slots_min": 0,
             "memo_hit": 0, "memo_store": 0, "memo_size": 0,
             "idx_cache_hit": 0, "idx_cache_miss": 0,
             "mask_cache_hit": 0, "mask_cache_miss": 0,
@@ -1225,6 +1256,7 @@ class MeshManager:
         self._evict_over_budget()
         self._sparse_views = sum(1 for v in self._views.values()
                                  if v.sparse is not None)
+        self._note_free_slots()
         self.stats.inc("stage")
         dispatch_s = time.monotonic() - t0
         self.stats.inc("stage_us", int(dispatch_s * 1e6))
@@ -1391,15 +1423,30 @@ class MeshManager:
         process has mutated since `sv` was validated, so walk the
         slices' generations and bring the staged image up to the epoch
         pair `ep` — nothing to do, an incremental scatter, or a
-        restage."""
+        restage. A container the writes created takes a free slot of
+        its slice (ops.pool.assign_free_slots: the new key on the host
+        and, through compile_serve_patch_containers, on the device)
+        and its bits go through the same scatter as the writes to
+        containers that were there, before this call returns: nothing
+        is deferred or gathered over several reads. The log's entries
+        are all the patch needs: the container did not exist at the
+        staged generation, so its words are the zero words of the free
+        slot with the log's surviving sets for its key. What a free
+        slot cannot take restages as before: a container EMPTIED (its
+        slot would have to be given back), a slice with no free slot,
+        a row the view's row table lacks, a sparse or mixed view, a
+        fragment that appeared or went, a pruned log."""
         index, frame, view = key
 
-        def restage():
+        def restage(refused: Optional[str] = None):
+            if refused is not None:
+                self.stats.inc("container_patch_refused_" + refused)
             f = self._stage(key, num_slices)
             f.validated_epoch = ep
             return f
 
         pending: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
+        created = False
         new_gens = list(sv.slice_gens)
         for s in range(num_slices):
             frag = self.holder.fragment(index, frame, view, s)
@@ -1419,8 +1466,10 @@ class MeshManager:
                 if gen == staged_gen:
                     continue
                 entries = frag.log_since(staged_gen)
-            if entries is None or any(e[2] for e in entries):
-                return restage()
+            if entries is None or any(op and churn
+                                      for op, _, churn in entries):
+                return restage()  # log pruned, or a container emptied
+            created = created or any(e[2] for e in entries)
             pending[s] = fold_log_entries(entries)
             new_gens[s] = (frag, gen)
 
@@ -1436,7 +1485,7 @@ class MeshManager:
             # pick (with hysteresis) is what lets a densifying
             # slice eventually convert back to packed words.
             self.stats.inc("refresh_pick_restage")
-            return restage()
+            return restage("format" if created else None)
         # Cost gate: incremental scatter vs full
         # restage, decided from MEASURED costs on THIS backend —
         # the view's own last stage time vs an EWMA of recent
@@ -1496,15 +1545,22 @@ class MeshManager:
                     sv.inc_ewma_s = inc_est * 0.9
                 return restage()
         t_inc = time.monotonic()
-        per_slice = {}
+        per_slice, patches = {}, {}
         try:
             for s, (pos, val) in pending.items():
+                keys_row, slots_row = sv.keys_host[s], sv.slots_host[s]
+                patch = assign_free_slots(keys_row, slots_row, sv.row_ids,
+                                          pos, val)
+                if patch is not None:
+                    keys_row, slots_row = patch[:2]
+                    patches[s] = patch
                 per_slice[s] = plan_slice_mutations(
-                    sv.keys_host[s], sv.row_ids, pos, val)
-        except KeyError:
-            return restage()
+                    keys_row, sv.row_ids, pos, val, slots_row)
+        except PatchRefused as e:
+            return restage(e.reason)
         batches = pack_mutation_batches(
             per_slice, sv.padded_slices, sv.keys_host.shape[1])
+        patched = self._patch_containers(sv, patches) if patches else None
         if self._apply_fn is None:
             self._apply_fn = compile_serve_apply_writes(self.mesh)
         # The jitted apply recompiles on any NEW batch/pool shape
@@ -1514,7 +1570,7 @@ class MeshManager:
         # steady state never pays. Shape-novelty mirrors exactly
         # what jit keys compilation on.
         shapes = (tuple(sv.sharded.words.shape),
-                  tuple(tuple(np.shape(b)) for b in batches))
+                  tuple(tuple(np.shape(b)) for b in batches), patched)
         fresh_compile = shapes not in self._apply_shapes
         self._apply_shapes.add(shapes)
         self._purge_memo(sv.sharded.words)
@@ -1557,6 +1613,43 @@ class MeshManager:
 
             self._measure_async(sv.sharded.words, t_inc, on_inc)
         return sv
+
+    def _patch_containers(self, sv: StagedView, patches: dict) -> tuple:
+        """Write the keys of created containers into the free slots
+        _refresh_walk assigned them ({slice: assign_free_slots'
+        result}), on the device and on the host, and drop what was
+        resolved against the old keys: the (idx, hit) of the rows that
+        gained a container. Call under _mu; the containers' bits follow
+        in the caller's scatter. Returns the shape the program was
+        launched at (what jit keys its compilation on)."""
+        if self._patch_fn is None:
+            self._patch_fn = compile_serve_patch_containers(self.mesh)
+        slot, new_key = pack_container_patches(
+            {s: p[2:] for s, p in patches.items()}, sv.padded_slices,
+            sv.keys_host.shape[1])
+        with jax_scope("pilosa:patch_containers"):
+            keys = self._patch_fn(sv.sharded.keys, slot, new_key)
+        sv.sharded = sv.sharded._replace(keys=keys)
+        n = 0
+        for s, (keys_row, slots_row, new_keys, _) in patches.items():
+            sv.keys_host[s], sv.slots_host[s] = keys_row, slots_row
+            sv.free_slots[s] -= len(new_keys)
+            n += len(new_keys)
+            for dense_id in set((new_keys // ROW_SPAN).tolist()):
+                sv.idx_cache.pop(dense_id, None)
+                sv.host_idx_cache.pop(dense_id, None)
+        self.stats.inc("container_patches", n)
+        self._note_free_slots()
+        return slot.shape
+
+    def _note_free_slots(self) -> None:
+        """Gauge free_slots_min: the fewest free slots of any slice of
+        any dense staged view (0 while none is staged). Call under
+        _mu."""
+        self.stats.set("free_slots_min", min(
+            (int(v.free_slots[:v.num_slices].min())
+             for v in self._views.values()
+             if v.sparse is None and v.num_slices), default=0))
 
     def invalidate(self, index: Optional[str] = None):
         """Drop staged views (all, or one index's)."""
@@ -2873,7 +2966,7 @@ class MeshManager:
             self.stats.inc("idx_cache_hit")
             return cached
         self.stats.inc("idx_cache_miss")
-        out = resolve_row_indices(sv.keys_host, dense_id)
+        out = resolve_row_indices(sv.keys_host, dense_id, sv.slots_host)
         if len(sv.host_idx_cache) >= self._IDX_CACHE_MAX:
             sv.host_idx_cache.popitem(last=False)
         sv.host_idx_cache[dense_id] = out
@@ -3127,9 +3220,10 @@ class MeshManager:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        flat_idx, hit = resolve_row_indices(sv.keys_host, dense_id)
+        flat_idx, hit = resolve_row_indices(sv.keys_host, dense_id,
+                                            sv.slots_host)
         sharding = NamedSharding(self.mesh, P(SLICE_AXIS))
-        coarse = coarse_row_starts(sv.keys_host, dense_id)
+        coarse = coarse_row_starts(sv.keys_host, dense_id, sv.slots_host)
         if coarse is not None:
             starts_h, valid_h = coarse
             # Uniform layout: the row sits at ONE run index on every

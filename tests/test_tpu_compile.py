@@ -299,11 +299,13 @@ def test_sparse_pair_programs(chip, kind, backend):
 
 @pytest.mark.parametrize("program", ["topn_4096_rows", "topn_src",
                                      "tanimoto", "bsi_sum_planes",
-                                     "apply_writes"])
+                                     "apply_writes", "patch_containers"])
 def test_row_count_and_write_programs(chip, program):
     """TopN over 4,096 rows (plain, with a src tree, tanimoto), the
-    BSI Sum's per-plane counts (a 64-row space), and the incremental
-    write scatter, all over the 960-slice pool."""
+    BSI Sum's per-plane counts (a 64-row space), the incremental
+    write scatter and the write of created containers' keys into free
+    slots (the keys alone: no pool passes through it), all over the
+    960-slice pool."""
     from pilosa_tpu.parallel import mesh as M
 
     keys, w, mask = chip.sliced(np.int32, CAP), chip.pool(), \
@@ -320,11 +322,17 @@ def test_row_count_and_write_programs(chip, program):
         fn = M.compile_serve_row_counts_tanimoto(chip.mesh, ["leaf", 0],
                                                  1, 4096)
         args = (keys, w, (w,), *chip.idx_hit(1), mask)
-    else:
+    elif program == "apply_writes":
         fn = M.compile_serve_apply_writes(chip.mesh)
         args = (index, chip.sliced(np.int32, 8), chip.sliced(np.int32, 8),
                 chip.sliced(np.uint32, 8), chip.sliced(np.uint32, 8))
-    compiled(fn, *args)
+    else:
+        fn = M.compile_serve_patch_containers(chip.mesh)
+        args = (keys, chip.sliced(np.int32, 8), chip.sliced(np.int32, 8))
+    _, mem = compiled(fn, *args)
+    if program == "patch_containers":
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            + mem.output_size_in_bytes < 4 * S * CAP * 4
 
 
 def test_bsi_range_ladder_through_the_count_kernels(chip):
@@ -386,7 +394,8 @@ def test_four_chip_program_reduces_over_the_interconnect(chip, four,
 
 
 @pytest.mark.parametrize("program,n", [("coarse", 2), ("coarse", 8),
-                                       ("fused", 8), ("apply_writes", 1)])
+                                       ("fused", 8), ("apply_writes", 1),
+                                       ("patch_containers", 0)])
 def test_seg_2b_x4_programs_over_the_sharded_pool(four, program, n):
     """What benchmarks/configs/seg-2b-x4 serves, at its size: 1,920
     slices sharded over the 2x2, 480 a device. The herd's count_coarse
@@ -410,12 +419,17 @@ def test_seg_2b_x4_programs_over_the_sharded_pool(four, program, n):
                                    host_meta=True)
         args = ((w,) * n, four.repl(np.int32, n, s2, 16),
                 four.repl(np.uint32, n, s2, 16), four.repl(np.int32, s2))
-    else:
+    elif program == "apply_writes":
         fn = M.compile_serve_apply_writes(four.mesh)
         index = M.ShardedIndex(keys=four.sliced(np.int32, CAP, s=s2),
                                words=w)
         args = (index,) + tuple(four.sliced(t, 8, s=s2) for t in (
             np.int32, np.int32, np.uint32, np.uint32))
+    else:  # the created containers' keys: no pool operand at all
+        fn = M.compile_serve_patch_containers(four.mesh)
+        args = (four.sliced(np.int32, CAP, s=s2),
+                four.sliced(np.int32, 8, s=s2),
+                four.sliced(np.int32, 8, s=s2))
     text, mem = compiled(fn, *args)
     quarter = s2 // 4 * CAP * 2048 * 4
     assert quarter == 503_316_480
@@ -424,8 +438,7 @@ def test_seg_2b_x4_programs_over_the_sharded_pool(four, program, n):
         < 1.01 * n * quarter + 2**20
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         < 15.75 * 2**30
-    if program != "apply_writes":
-        assert "all-reduce" in text
+    assert ("all-reduce" in text) == (program in ("coarse", "fused"))
 
 
 # -- the xla coarse programs read the pool where it lies ----------------------
