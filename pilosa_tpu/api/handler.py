@@ -1072,6 +1072,15 @@ class Handler:
                 refused.add(stats.get(f"container_patch_refused_{reason}",
                                       0), {"reason": reason})
             fams.append(refused)
+            applied = prom.MetricFamily(
+                "pilosa_apply_writes_total", "counter",
+                "Refreshes that scattered writes into a staged pool, "
+                "by mode (in_place = no reader held the pool, so its "
+                "buffer was donated to the scatter; copied = a reader "
+                "was pinned, so the scatter started from a copy).")
+            for mode in ("in_place", "copied"):
+                applied.add(stats.get(f"apply_{mode}", 0), {"mode": mode})
+            fams.append(applied)
             fams.append(prom.MetricFamily(
                 "pilosa_plan_quarantined_total", "counter",
                 "Plan signatures quarantined off the device path "

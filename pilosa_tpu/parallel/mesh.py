@@ -1884,7 +1884,8 @@ def compile_serve_row_counts(mesh: Mesh, num_rows: int):
 
 def pack_mutation_batches(per_slice, num_slices: int, capacity: int):
     """Stack per-slice plan_slice_mutations outputs into padded (S, B)
-    batch arrays for compile_serve_apply_writes.
+    batch arrays for compile_serve_apply_writes, with the width of the
+    widest slice's plan (the columns the program has to run) last.
 
     per_slice: {slice_id: (slot, word, set_mask, clear_mask)}. The
     no-op/width scheme is ops.pool's (pad_mutation_plan): padding rides
@@ -1901,38 +1902,59 @@ def pack_mutation_batches(per_slice, num_slices: int, capacity: int):
     rows = [per_slice.get(si) for si in range(num_slices)]
     padded = [pad_mutation_plan(r, capacity, b) if r is not None else empty
               for r in rows]
-    return tuple(np.stack([p[i] for p in padded]) for i in range(4))
+    return (*(np.stack([p[i] for p in padded]) for i in range(4)),
+            np.int32(widest))
 
 
-def compile_serve_apply_writes(mesh: Mesh):
-    """Jit the scatter of folded set/clear batches into sharded pools.
+def compile_serve_apply_writes(mesh: Mesh, donate: bool = False):
+    """Jit the scatter of folded set/clear batches into a sharded
+    pool's words, where the pool lies.
 
-    fn(index, slot, word, set_mask, clear_mask) -> updated ShardedIndex.
-    Targets are unique per slice (plan_slice_mutations) and padding
-    rides out-of-bounds slots dropped by the scatter, so the update is
-    exact for mixed sets and clears — the device-side half of SetBit /
-    ClearBit (reference fragment.go:371-459), applied as one batched
-    scatter per refresh instead of a full pool re-upload.
+    fn(words (S, C, W), slot, word, set_mask, clear_mask (S, B), n)
+    -> words: (cur & ~clear) | set at each slice's targets, column by
+    column for the first n of the B columns (n: the widest real plan,
+    pack_mutation_batches' last value; the columns after it are all
+    padding and are not run, and B alone keys the compile). Targets
+    are unique per slice (plan_slice_mutations) and padding rides
+    out-of-bounds slots dropped by the scatter, so the update is exact
+    for mixed sets and clears: the device-side half of SetBit /
+    ClearBit (reference fragment.go:371-459), one launch per refresh
+    instead of a pool re-upload. The keys do not pass through it.
+
+    Why a loop of width-1 scatters and not one scatter of width B:
+    for a scatter of 8 or more updates a slice the TPU compiler copies
+    the whole pool into another layout ({3,1,2,0}), scatters there and
+    copies it back (two pool-sized ops a launch, 12.2 ms for a 1.89 GB
+    pool, and a pool-sized temporary), donated or not; a width-1
+    scatter runs in the pool's own tiled layout
+    (tests/test_tpu_compile.py holds both forms to that). With
+    donate=True the words' buffer is the output's and the launch
+    touches no byte it does not change, and the caller's array is
+    DELETED: only for a pool no reader can still launch on
+    (serve.MeshManager._apply_writes decides). With donate=False the
+    same program starts from one copy of the pool.
     """
 
     from ..ops.pool import scatter_words
 
-    def per_shard(keys, words, slot, word, set_mask, clear_mask):
-        return keys, jax.vmap(scatter_words)(
-            words, slot, word, set_mask, clear_mask)
+    def per_shard(words, slot, word, set_mask, clear_mask, n):
+        def column(j, w):
+            return jax.vmap(scatter_words)(
+                w, *(lax.dynamic_slice_in_dim(a, j, 1, axis=1)
+                     for a in (slot, word, set_mask, clear_mask)))
+
+        return lax.fori_loop(0, n, column, words)
 
     fn = shard_map(
         per_shard,
         mesh=mesh,
-        in_specs=(P(SLICE_AXIS),) * 6,
-        out_specs=(P(SLICE_AXIS), P(SLICE_AXIS)),
+        in_specs=(P(SLICE_AXIS),) * 5 + (P(),),
+        out_specs=P(SLICE_AXIS),
     )
 
-    @jax.jit
-    def apply_writes(index: ShardedIndex, slot, word, set_mask, clear_mask):
-        keys, words = fn(index.keys, index.words, slot, word,
-                         set_mask, clear_mask)
-        return ShardedIndex(keys=keys, words=words)
+    @partial(jax.jit, donate_argnums=(0,) if donate else ())
+    def apply_writes(words, slot, word, set_mask, clear_mask, n):
+        return fn(words, slot, word, set_mask, clear_mask, n)
 
     return apply_writes
 

@@ -13,7 +13,9 @@ Staging and maintenance:
     then maintained INCREMENTALLY: each Fragment keeps a mutation log
     (core/fragment.py log_since), and refresh() folds the bits written
     since the staged generation into one device scatter
-    (compile_serve_apply_writes). A container the writes CREATED is
+    (compile_serve_apply_writes), which runs in the staged pool's own
+    buffer when no reader holds the pool and from one copy of it when
+    one does (_apply_writes). A container the writes CREATED is
     patched into a free slot of its slice first (_refresh_walk,
     compile_serve_patch_containers; the pool's key order afterwards:
     ops.pool.assign_free_slots), and its bits go through the same
@@ -522,7 +524,9 @@ class MeshManager:
         # the previous drain was lone. Decremented as requests drain.
         self._burst_mu = threading.Lock()
         self._burst_hint = 0
-        self._apply_fn = None
+        # compile_serve_apply_writes' two forms, by `donate`
+        # (_apply_writes picks).
+        self._apply_fns: dict = {}
         self._patch_fn = None  # compile_serve_patch_containers
         # EWMA (seconds) of measured incremental-apply cost — the other
         # side of refresh()'s cost gate (vs StagedView.last_stage_s) —
@@ -637,6 +641,11 @@ class MeshManager:
             "container_patches": 0, "container_patch_refused_no_slot": 0,
             "container_patch_refused_new_row": 0,
             "container_patch_refused_format": 0, "free_slots_min": 0,
+            # Refreshes that scattered writes, by what _apply_writes
+            # picked (/metrics: pilosa_apply_writes_total{mode}), and
+            # the in-place launches that failed and cost the view.
+            "apply_in_place": 0, "apply_copied": 0,
+            "apply_in_place_failed": 0,
             "memo_hit": 0, "memo_store": 0, "memo_size": 0,
             "idx_cache_hit": 0, "idx_cache_miss": 0,
             "mask_cache_hit": 0, "mask_cache_miss": 0,
@@ -880,10 +889,13 @@ class MeshManager:
             arrs = list(sh) + (list(sp) if sp is not None else [])
             try:
                 for arr in arrs:
-                    for shard in arr.addressable_shards:
-                        n = (int(np.prod(shard.data.shape))
-                             * shard.data.dtype.itemsize)
-                        dev = str(shard.device)
+                    # From the sharding, never the shards' buffers: a
+                    # pool the next write donated since the snapshot
+                    # keeps its shape and placement and has no buffer.
+                    n = (int(np.prod(arr.sharding.shard_shape(arr.shape)))
+                         * arr.dtype.itemsize)
+                    for d in arr.sharding.addressable_devices:
+                        dev = str(d)
                         per_device[dev] = per_device.get(dev, 0) + n
                         placed = True
             except (AttributeError, TypeError):
@@ -1329,6 +1341,8 @@ class MeshManager:
                     words.block_until_ready()
                     elapsed = time.monotonic() - t0
                 except Exception:  # noqa: BLE001 — surfaces at query
+                    # (Or the array is gone: the next write donated it
+                    # to its scatter before this worker reached it.)
                     # A failed fetch still records a sample (ADVICE
                     # r4): dropping it would leave last_stage_s=None
                     # forever, disabling the view's cost gate AND the
@@ -1561,23 +1575,8 @@ class MeshManager:
         batches = pack_mutation_batches(
             per_slice, sv.padded_slices, sv.keys_host.shape[1])
         patched = self._patch_containers(sv, patches) if patches else None
-        if self._apply_fn is None:
-            self._apply_fn = compile_serve_apply_writes(self.mesh)
-        # The jitted apply recompiles on any NEW batch/pool shape
-        # (mutation_batch_width doubles, a different capacity) —
-        # a sample carrying a one-off XLA compile must not feed
-        # the EWMA or the gate would flip to restage on costs the
-        # steady state never pays. Shape-novelty mirrors exactly
-        # what jit keys compilation on.
-        shapes = (tuple(sv.sharded.words.shape),
-                  tuple(tuple(np.shape(b)) for b in batches), patched)
-        fresh_compile = shapes not in self._apply_shapes
-        self._apply_shapes.add(shapes)
-        self._purge_memo(sv.sharded.words)
         sp = span("incremental", index=index, frame=frame, view=view)
-        with jax_scope("pilosa:apply_writes"):
-            sv.sharded = self._apply_fn(sv.sharded, *batches)
-        self._views_gen += 1
+        fresh_compile = self._apply_writes(key, sv, batches, patched)
         sp.finish()
         sv.slice_gens = new_gens
         sv.validated_epoch = ep
@@ -1613,6 +1612,74 @@ class MeshManager:
 
             self._measure_async(sv.sharded.words, t_inc, on_inc)
         return sv
+
+    def _apply_writes(self, key, sv: StagedView, batches,
+                      patched) -> bool:
+        """Scatter pack_mutation_batches' `batches` into sv's staged
+        words and swap the result in (call under _mu; the keys, patched
+        or not, stay as they are). THE place that picks the form of
+        compile_serve_apply_writes, from what it observes: with no pin
+        on the view the words are donated and the scatter runs in the
+        pool's own buffer; with a pin the same program starts from a
+        copy and the pinned reader's pool stays whole.
+
+        Why the pin count is the right witness. Donation deletes the
+        array for every holder that has not launched on it yet, and a
+        reader holds a staged `words` unlaunched only between its plan
+        and its launch. Every such reader (_stage_leaves,
+        _stage_leaves_host, _sparse_count, _row_counts_args,
+        _src_counts_args) takes its pin under _mu in the same critical
+        section that snapshots the words, and gives it back only after
+        its result is fetched; this refresh runs under _mu, before the
+        reader that triggered it snapshots or pins anything. So pins ==
+        0, read here, says no reader holds the old words, and pins > 0
+        that one may. What else names the array takes no pin and cannot
+        launch on it: the limb memo's refs are purged just below, the
+        cost measurement only waits on it (a deleted array is a skipped
+        sample), the byte accounting reads shapes and shardings.
+
+        A failed donated launch may have consumed the pool, so the view
+        is dropped: the host's fragments are the truth, this read folds
+        there and the next one restages, writes included. A failed
+        undonated launch leaves the old pool in place, as before.
+        Returns whether this launch compiled (the caller keeps such a
+        sample out of the cost gate's EWMA)."""
+        in_place = sv.pins == 0
+        fn = self._apply_fns.get(in_place)
+        if fn is None:
+            fn = self._apply_fns[in_place] = compile_serve_apply_writes(
+                self.mesh, donate=in_place)
+        words = sv.sharded.words
+        # The jitted apply recompiles on any NEW batch/pool shape
+        # (mutation_batch_width doubles, a different capacity) and
+        # once for each form — a sample carrying a one-off XLA
+        # compile must not feed the EWMA or the gate would flip to
+        # restage on costs the steady state never pays.
+        # Shape-novelty mirrors exactly what jit keys compilation on.
+        shapes = (in_place, tuple(words.shape),
+                  tuple(tuple(np.shape(b)) for b in batches), patched)
+        fresh_compile = shapes not in self._apply_shapes
+        self._apply_shapes.add(shapes)
+        self._purge_memo(words)
+        try:
+            with jax_scope("pilosa:apply_writes"):
+                words = fn(words, *batches)
+        except Exception:
+            if in_place:
+                del self._views[key]
+                self._views_gen += 1
+                self.stats["staged_bytes"] = sum(
+                    self._view_bytes(v) for v in self._views.values())
+                self.stats.inc("apply_in_place_failed")
+                costs.LEDGER.view_evicted(key)
+                _log.warning("in-place apply of %s failed; the staged "
+                             "view is dropped and restages at the "
+                             "next read", key, exc_info=True)
+            raise
+        sv.sharded = sv.sharded._replace(words=words)
+        self._views_gen += 1
+        self.stats.inc("apply_in_place" if in_place else "apply_copied")
+        return fresh_compile
 
     def _patch_containers(self, sv: StagedView, patches: dict) -> tuple:
         """Write the keys of created containers into the free slots
@@ -1800,10 +1867,16 @@ class MeshManager:
         mutated under _mu: a concurrent refresh() swaps sv.sharded in
         place, and a query that read one leaf's words before the swap
         and another after would mix two generations of the same view.
-        Only compiled calls run unlocked. `pins` (a list) collects an
-        eviction pin per staged view used, held until the caller's
-        _release_pins — the unlocked execution window must not have its
-        images evicted-and-restaged under memory pressure mid-fold."""
+        Only compiled calls run unlocked. `pins` (a list) collects a
+        pin per staged view used, held until the caller's
+        _release_pins: the unlocked execution window must not have its
+        images evicted-and-restaged under memory pressure mid-fold,
+        and a pinned reader's pool is never donated to a write's
+        scatter (_apply_writes copies instead), so the generation it
+        snapshotted stays whole until it has launched and fetched. A
+        caller without `pins` must let no refresh run between this
+        call and its launch (the SPMD descriptor plane: one descriptor
+        at a time, writes among them)."""
         with _held(self._mu):
             self._use_epoch += 1
             out = self._stage_leaves(index, leaves, num_slices, pins=pins)
@@ -3537,7 +3610,6 @@ class MeshManager:
             if pins is not None:
                 sv.pins += 1
                 pins.append(sv)
-            sharded = sv.sharded
             mask = self._mask_for(sv, slices)
             if mask is None:
                 self.stats.inc("fallback")
@@ -3548,6 +3620,10 @@ class MeshManager:
                                      pins=pins)
             if out is None:
                 return None
+            # Snapshot AFTER the src leaves staged: one of them may be
+            # this view, refreshed again (and, unpinned, scattered in
+            # place) by a write that landed since the refresh above.
+            sharded = sv.sharded
             words_t, idx_t, hit_t, _coarse_t, _first = out
             dev_mask = self._device_mask(mask)
             padded = 1 << (len(sv.row_ids) - 1).bit_length()

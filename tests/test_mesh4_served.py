@@ -236,9 +236,13 @@ def test_setbit_into_each_shard_is_counted_next_and_survives_reopen(tmp_path):
         assert s.query(text)["results"] == [int(rows[1].sum())]
     assert s.query(pql("Difference", [1, 0]))["results"] \
         == [expected(rows, "Difference", [1, 0])]
-    # Scattered into the shard that holds the slice, not staged again.
+    # Scattered into the shard that holds the slice, not staged again;
+    # one client, so no reader held the pool: in place, every time.
     assert s.mgr.stats["stage"] == stages
     assert s.mgr.stats["incremental"] >= 1
+    assert s.metric('pilosa_apply_writes_total{mode="in_place"}') \
+        == s.mgr.stats["incremental"]
+    assert s.metric('pilosa_apply_writes_total{mode="copied"}') == 0
     s.holder.close()
     again = Served(opened(tmp_path / "d"), 4)
     assert again.query(text)["results"] == [int(rows[1].sum())]

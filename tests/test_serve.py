@@ -2316,3 +2316,277 @@ class TestPickCount:
         assert [r.leaf_keys for r in pick.order] == \
             [r.leaf_keys for r in group]
         assert pick.program() == "shared-program"
+
+
+class TestApplyWritesWhereThePoolLies:
+    """The write scatter runs in the staged pool's own buffer when no
+    reader holds the pool (StagedView.pins == 0, read under _mu) and
+    from one copy of it when one does (serve.MeshManager._apply_writes,
+    mesh.compile_serve_apply_writes): one program, two jitted forms."""
+
+    KEY = ("i", "general", "standard")
+    TEXT = "Count(Bitmap(rowID=10))"
+
+    @staticmethod
+    def _served(holder, devices=1):
+        """An executor over `devices` CPU devices, its gate deterministic
+        (a measured gate may restage a tiny pool at will)."""
+        from pilosa_tpu.parallel.mesh import default_mesh
+        from pilosa_tpu.parallel.serve import MeshManager
+
+        e = Executor(holder, use_device=True)
+        e._mesh_mgr = MeshManager(holder, mesh=default_mesh(devices))
+        e._mesh_mgr.deterministic_gate = True
+        return e, e._mesh_mgr
+
+    def _staged(self, holder, devices=1):
+        """Rows 10 and 11 over two slices, staged by a first Count."""
+        f = seed(holder, bits=[(10, c) for c in range(8)]
+                 + [(10, SLICE_WIDTH + 3), (11, 1), (11, SLICE_WIDTH + 9)])
+        e, mgr = self._served(holder, devices)
+        assert q(e, "i", self.TEXT) == [9]
+        return f, e, mgr
+
+    @pytest.mark.parametrize("devices", [1, 4])
+    def test_no_pin_applies_in_place(self, holder, devices):
+        f, e, mgr = self._staged(holder, devices)
+        old = mgr._views[self.KEY].sharded.words
+        f.set_bit(10, 100)
+        f.set_bit(10, SLICE_WIDTH + 100)    # the other shard of four
+        f.clear_bit(10, 0)
+        assert q(e, "i", self.TEXT) == [10]
+        assert (mgr.stats["apply_in_place"], mgr.stats["apply_copied"],
+                mgr.stats["stage"]) == (1, 0, 1)
+        sv = mgr._views[self.KEY]
+        assert old.is_deleted() and not sv.sharded.words.is_deleted()
+        assert sv.sharded.words.sharding == old.sharding
+        assert q(e, "i", "TopN(frame=general, n=2)")[0] \
+            == q(Executor(holder, use_device=False), "i",
+                 "TopN(frame=general, n=2)")[0]
+        # The byte accounting reads no buffer, so a donated pool in an
+        # older snapshot cannot fail a scrape.
+        snap = [(mgr._views[self.KEY].sharded._replace(words=old),
+                 sv.keys_host, None, None, None)]
+        assert mgr._device_memory_from(snap)["padded_bytes"] \
+            == mgr.device_memory()["padded_bytes"]
+
+    def test_pinned_reader_keeps_its_pool_and_the_next_read_sees_the_write(
+            self, holder):
+        """A reader that planned before the write (its pin taken, its
+        launch not made yet) still answers the pre-write counts from the
+        pool it snapshotted; the refresh beside it copied."""
+        from pilosa_tpu.parallel.mesh import compile_serve_row_counts
+        from pilosa_tpu.parallel.serve import combine_limbs
+
+        f, e, mgr = self._staged(holder)
+        pins: list = []
+        row_ids, sharded, dev_mask, padded, _ = mgr._row_counts_args(
+            "i", "general", "standard", [0, 1], 2, pins=pins)
+        assert [sv.pins for sv in pins] == [1]
+        f.set_bit(10, 100)
+        assert q(e, "i", self.TEXT) == [10]
+        assert (mgr.stats["apply_in_place"], mgr.stats["apply_copied"]) \
+            == (0, 1)
+        assert not sharded.words.is_deleted()
+        assert mgr._views[self.KEY].sharded.words is not sharded.words
+        limbs = np.asarray(compile_serve_row_counts(mgr.mesh, padded)(
+            sharded, dev_mask))
+        assert dict(zip(row_ids.tolist(),
+                        combine_limbs(limbs, len(row_ids)).tolist())) \
+            == {10: 9, 11: 2}
+        mgr._release_pins(pins)
+        f.set_bit(10, 101)
+        assert q(e, "i", self.TEXT) == [11]
+        assert (mgr.stats["apply_in_place"], mgr.stats["apply_copied"]) \
+            == (1, 1)
+
+    @pytest.mark.parametrize("donate", [True, False],
+                             ids=["in_place", "copied"])
+    @pytest.mark.parametrize("width,run", [(1, None), (8, None), (9, None),
+                                           (3, 8)],
+                             ids=["1", "8", "9_of_16", "3_and_its_padding"])
+    def test_scatter_matches_numpy(self, width, run, donate):
+        """Sets and clears mixed, at the plan widths that fill batch
+        widths 8 and 16; slices with narrower plans ride out-of-bounds
+        slots in the columns that run, and with run=8 the columns that
+        are all padding run too: dropped, every one."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from pilosa_tpu.ops.pool import mutation_batch_width
+        from pilosa_tpu.parallel.mesh import (SLICE_AXIS,
+                                              compile_serve_apply_writes,
+                                              default_mesh,
+                                              pack_mutation_batches)
+
+        mesh, slices, cap = default_mesh(4), 8, 16
+        rng = np.random.default_rng(3500 + width)
+        words = rng.integers(0, 2**32, (slices, cap, 2048), dtype=np.uint32)
+        want, per_slice = words.copy(), {}
+        for s in (1, 2, 5, 7):
+            k = width if s == 5 else int(rng.integers(1, width + 1))
+            at = rng.choice(cap * 2048, size=k, replace=False)
+            slot, word = (at // 2048).astype(np.int32), \
+                (at % 2048).astype(np.int32)
+            sets, clears = (rng.integers(0, 2**32, k, dtype=np.uint32)
+                            for _ in range(2))
+            per_slice[s] = (slot, word, sets, clears)
+            want[s, slot, word] = (want[s, slot, word] & ~clears) | sets
+        *batch, widest = pack_mutation_batches(per_slice, slices, cap)
+        assert widest == width and batch[0].shape \
+            == (slices, mutation_batch_width(width))
+        staged = jax.device_put(words, NamedSharding(mesh, P(SLICE_AXIS)))
+        out = compile_serve_apply_writes(mesh, donate=donate)(
+            staged, *batch, widest if run is None else np.int32(run))
+        assert staged.is_deleted() == donate
+        np.testing.assert_array_equal(np.asarray(out), want)
+
+    def test_write_after_a_patch_keeps_the_patched_keys(self, holder):
+        from pilosa_tpu.ops.pool import INVALID_KEY
+
+        f, e, mgr = self._staged(holder)
+        sv = mgr._views[self.KEY]
+        f.set_bit(11, 65536 + 4)  # row 11, slice 0, block 1: a new container
+        assert q(e, "i", "Count(Bitmap(rowID=11))") == [3]
+        f.set_bit(11, 65536 + 5)  # into the patched container, in place
+        assert q(e, "i", "Count(Bitmap(rowID=11))") == [4]
+        assert sv is mgr._views[self.KEY]
+        assert (mgr.stats["container_patches"], mgr.stats["stage"],
+                mgr.stats["apply_in_place"]) == (1, 1, 2)
+        dev_keys = np.asarray(sv.sharded.keys)
+        live = sv.keys_host != INVALID_KEY
+        assert live[0].sum() == 3 and live[1].sum() == 2
+        for s in range(sv.padded_slices):
+            assert (dev_keys[s, sv.slots_host[s][live[s]]]
+                    == sv.keys_host[s][live[s]]).all()
+
+    def test_back_to_back_writes_do_not_break_the_measure_loop(self,
+                                                                holder):
+        """Each write donates the words the cost measurement of the
+        write before may still be waiting on: a deleted array is a
+        skipped sample (ok=False), never a dead worker."""
+        import time
+
+        f, e, mgr = self._staged(holder)
+        for i in range(6):
+            f.set_bit(10, 200 + i)
+            assert q(e, "i", self.TEXT) == [10 + i]
+        assert mgr.stats["apply_in_place"] == 6
+        old = mgr._views[self.KEY].sharded.words
+        f.set_bit(10, 300)
+        assert q(e, "i", self.TEXT) == [16] and old.is_deleted()
+        got = []
+        mgr._measure_async(old, time.monotonic(),
+                           lambda dt, ok=True: got.append(ok))
+        for _ in range(500):
+            if got and not mgr._measure_q.unfinished_tasks:
+                break
+            time.sleep(0.01)
+        assert got == [False] and mgr._measure_thread.is_alive()
+
+    def test_readers_of_every_kind_racing_writers_never_meet_a_deleted_pool(
+            self, holder):
+        """Counts (lone and batched), TopN plain and with a src tree,
+        beside two writers: whichever form each refresh picked, no
+        reader's launch found its pool gone (any such failure falls
+        back to the host and is counted), and the quiesced view equals
+        the host."""
+        import sys
+        import threading
+        import time
+
+        f, e, mgr = self._staged(holder)
+        host = Executor(holder, use_device=False)
+        texts = ["Count(Intersect(Bitmap(rowID=10), Bitmap(rowID=11)))",
+                 self.TEXT, "TopN(frame=general, n=2)",
+                 "TopN(Bitmap(rowID=11), frame=general, n=2)"]
+        for t in texts:
+            q(e, "i", t)                       # every program compiled
+        stop, errors = threading.Event(), []
+
+        def writer(k):
+            rng = np.random.default_rng(k)
+            while not stop.is_set():
+                c = int(rng.integers(2)) * SLICE_WIDTH + int(rng.integers(512))
+                (f.set_bit if rng.random() < 0.7 else f.clear_bit)(
+                    10 + int(rng.integers(2)), c)
+
+        def reader(k):
+            rng = np.random.default_rng(k)
+            try:
+                while not stop.is_set():
+                    q(e, "i", texts[int(rng.integers(len(texts)))])
+            except Exception as err:  # noqa: BLE001 — reported below
+                errors.append(repr(err))
+
+        threads = [threading.Thread(target=writer, args=(k,))
+                   for k in (1, 2)] + [
+            threading.Thread(target=reader, args=(k,)) for k in range(6)]
+        was = sys.getswitchinterval()
+        sys.setswitchinterval(1e-4)   # more plan-to-launch windows cut open
+        try:
+            for t in threads:
+                t.start()
+            time.sleep(1.5)
+            stop.set()
+            for t in threads:
+                t.join(60)
+        finally:
+            stop.set()
+            sys.setswitchinterval(was)
+        assert not any(t.is_alive() for t in threads) and errors == []
+        for t in texts:
+            assert q(e, "i", t) == q(host, "i", t)
+        st = mgr.stats.copy()
+        assert (st.get("fallback_error", 0), st.get("lone_fused_failed", 0),
+                st["apply_in_place_failed"], st["stage"]) == (0, 0, 0, 1)
+        assert st["apply_in_place"] + st["apply_copied"] \
+            == st["incremental"] > 0
+
+    def test_failed_in_place_apply_drops_the_view_and_the_write_shows(
+            self, holder):
+        f, e, mgr = self._staged(holder)
+        f.set_bit(10, 100)
+        assert q(e, "i", self.TEXT) == [10]     # compiles the donated form
+        real, old = mgr._apply_fns[True], mgr._views[self.KEY].sharded.words
+
+        def lost(words, *batch):
+            words.delete()                       # the launch consumed it
+            raise RuntimeError("device lost mid-scatter")
+
+        mgr._apply_fns[True] = lost
+        f.set_bit(10, 101)
+        assert q(e, "i", self.TEXT) == [11]
+        assert mgr.stats["apply_in_place_failed"] == 1 and old.is_deleted()
+        mgr._apply_fns[True] = real
+        assert q(e, "i", self.TEXT) == [11]
+        sv = mgr._views[self.KEY]                # staged anew, write included
+        assert not sv.sharded.words.is_deleted()
+        assert (mgr.stats["stage"], mgr.stats["apply_in_place"]) == (2, 1)
+        f.set_bit(10, 102)
+        assert q(e, "i", self.TEXT) == [12]
+        assert mgr.stats["apply_in_place"] == 2
+
+    def test_failed_copied_apply_leaves_the_old_pool_in_place(self, holder):
+        f, e, mgr = self._staged(holder)
+        pins: list = []
+        mgr._row_counts_args("i", "general", "standard", [0, 1], 2,
+                             pins=pins)
+        sv, old = mgr._views[self.KEY], mgr._views[self.KEY].sharded.words
+
+        def refused(words, *batch):
+            raise RuntimeError("launch refused")
+
+        mgr._apply_fns[False] = refused
+        f.set_bit(10, 100)
+        assert q(e, "i", self.TEXT) == [10]      # folded on the host
+        assert mgr._views[self.KEY] is sv and sv.sharded.words is old
+        assert not old.is_deleted()
+        assert (mgr.stats["apply_copied"],
+                mgr.stats["apply_in_place_failed"]) == (0, 0)
+        del mgr._apply_fns[False]                # the write is still owed
+        f.set_bit(10, 101)     # (a new write: the query memo holds [10])
+        assert q(e, "i", self.TEXT) == [11]
+        assert mgr.stats["apply_copied"] == 1 and not old.is_deleted()
+        assert mgr._views[self.KEY] is sv and sv.sharded.words is not old
+        mgr._release_pins(pins)

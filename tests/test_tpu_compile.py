@@ -92,6 +92,12 @@ class Chip:
         return (tuple(self.sliced(np.int32, s=s) for _ in range(n)),
                 tuple(self.sliced(np.uint32, s=s) for _ in range(n)))
 
+    def write_batch(self, width, s=S):
+        """pack_mutation_batches' (S, B) arrays and its scalar."""
+        return (*(self.sliced(t, width, s=s) for t in (
+            np.int32, np.int32, np.uint32, np.uint32)),
+            self.repl(np.int32))
+
     def idx_hit(self, n):
         return (tuple(self.sliced(np.int32, 16) for _ in range(n)),
                 tuple(self.sliced(np.uint32, 16) for _ in range(n)))
@@ -324,8 +330,7 @@ def test_row_count_and_write_programs(chip, program):
         args = (keys, w, (w,), *chip.idx_hit(1), mask)
     elif program == "apply_writes":
         fn = M.compile_serve_apply_writes(chip.mesh)
-        args = (index, chip.sliced(np.int32, 8), chip.sliced(np.int32, 8),
-                chip.sliced(np.uint32, 8), chip.sliced(np.uint32, 8))
+        args = (w, *chip.write_batch(8))
     else:
         fn = M.compile_serve_patch_containers(chip.mesh)
         args = (keys, chip.sliced(np.int32, 8), chip.sliced(np.int32, 8))
@@ -421,10 +426,7 @@ def test_seg_2b_x4_programs_over_the_sharded_pool(four, program, n):
                 four.repl(np.uint32, n, s2, 16), four.repl(np.int32, s2))
     elif program == "apply_writes":
         fn = M.compile_serve_apply_writes(four.mesh)
-        index = M.ShardedIndex(keys=four.sliced(np.int32, CAP, s=s2),
-                               words=w)
-        args = (index,) + tuple(four.sliced(t, 8, s=s2) for t in (
-            np.int32, np.int32, np.uint32, np.uint32))
+        args = (w, *four.write_batch(8, s=s2))
     else:  # the created containers' keys: no pool operand at all
         fn = M.compile_serve_patch_containers(four.mesh)
         args = (four.sliced(np.int32, CAP, s=s2),
@@ -511,6 +513,45 @@ def test_xla_coarse_programs_do_not_copy_the_pool(chip, four, program,
     limit = (16 * 2**20 if program != "coarse"
              else 1.1 * rows if batch == 1 else shard)
     assert mem.temp_size_in_bytes < limit, (mem.temp_size_in_bytes, limit)
+
+
+@pytest.mark.parametrize("width", [8, 16])
+@pytest.mark.parametrize("donate", [True, False],
+                         ids=["in_place", "copied"])
+@pytest.mark.parametrize("slices,cap,devices", [
+    (960, 240, 1), (960, 128, 1), (1920, 128, 4)],
+    ids=["topn-1b", "seg-1b", "seg-2b-x4"])
+def test_write_program_scatters_where_the_pool_lies(chip, four, slices, cap,
+                                                    devices, donate, width):
+    """Until PR 35 the write program was one scatter of the batch's width
+    (8 or more updates a slice), which the chip's compiler runs in
+    another layout: `copy.11 u32[28800,16,8,128]{3,1,2,0}` in, `reshape.3
+    u32[960,240,2048]` out, two passes over the whole pool and a
+    pool-sized temporary for one bit, donated or not (12.2 ms of every
+    SetBit over topn-1b's 1.89 GB; two thirds of that cell's device
+    time in the ledger's PR 34 line). Held here, at the three staged
+    pools of benchmarks/configs and both batch widths a refresh sends:
+    the donated form aliases the pool, needs no temporary to speak of
+    and has no instruction that moves a pool's worth of bytes but the
+    scatter itself, in place inside the loop; the undonated form is the
+    same program after exactly one copy."""
+    from pilosa_tpu.parallel import mesh as M
+
+    c = chip if devices == 1 else four
+    text, mem = compiled(
+        M.compile_serve_apply_writes(c.mesh, donate=donate),
+        c.sliced(np.uint32, cap, 2048, s=slices),
+        *c.write_batch(width, s=slices))
+    shard = slices // devices * cap * 2048 * 4
+    pool_sized = [(name, op) for name, op, size in instructions(text)
+                  if size >= shard and op not in _NO_BYTES_MOVE]
+    assert mem.temp_size_in_bytes < 2**20, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes == (shard if donate else 0)
+    # The scatter itself, in place (its fusion inside the loop), and in
+    # the undonated form the one copy it starts from: nothing else.
+    assert sorted(op for _, op in pool_sized) == \
+        ([] if donate else ["copy"]) + ["fusion", "scatter"], pool_sized
+    assert not re.search(r"= u32\[\d+,16,8,128\]", text)  # the relayout
 
 
 def _scan_step_ops(text):
