@@ -16,7 +16,12 @@ can fail.
 program itself with its unsafe WAL path on; see harness.PROGRAM_CONTROLS.)
 
 It serves from the reference's `live()` tables, whichever reference the
-configuration names, holds no chip and imports nothing of the program.
+configuration names, holds no chip and imports nothing of the program. A
+reference of one frame is asked by key (`live.answer(key)`, `live.set_bit(row,
+column)`), the key read from the PQL here. A reference of several frames takes
+the PQL whole: its `live()` gives `answer_pql(pql)` (a count, or a ranking as
+(row, count) pairs), and `set_bit(row, column, frame)` with the frame the
+SetBit names; so does its approximate twin, where it has one.
 Started by the harness as a child:  control.py <mode> <reference.pickle> <port>
 """
 
@@ -38,6 +43,7 @@ MODES = ("sound", "stale_writes", "approximate_topn", "alter_answer")
 _ROW = re.compile(r"rowID=(\d+)")
 _COL = re.compile(r"columnID=(\d+)")
 _N = re.compile(r"\bn=(\d+)")
+_FRAME = re.compile(r'frame="([^"]*)"')
 
 
 class Answers:
@@ -63,30 +69,37 @@ class Answers:
         return ("IA",) if op == "Intersect" else ("UA",)
 
     def answer(self, pql: str):
+        whole = hasattr(self.live, "answer_pql")  # several frames
         if pql.startswith("SetBit("):
             row = int(_ROW.search(pql).group(1))
             col = int(_COL.search(pql).group(1))
+            named = _FRAME.findall(pql)[:1] if whole else ()
             if self.mode != "stale_writes":
                 with self.mu:
-                    self.live.set_bit(row, col)
+                    self.live.set_bit(row, col, *named)
             return True
         with self.mu:
             self.reads += 1
             bump = int(self.mode == "alter_answer" and self.reads % 7 == 0)
-        if pql.startswith("Count("):
-            with self.mu:
-                return self.live.answer(self._count_key(pql)) + bump
-        if pql.startswith("TopN("):
-            rows = _ROW.findall(pql)
-            key = ("T", int(rows[0]) if rows else None,
-                   int(_N.search(pql).group(1)))
-            with self.mu:
-                pairs = (self.approx if self.mode == "approximate_topn"
-                         else self.live).answer(key)
-            if bump and pairs:
-                pairs = [(pairs[0][0], pairs[0][1] + 1)] + list(pairs[1:])
-            return [{"id": r, "count": c} for r, c in pairs]
-        raise ValueError(f"the control does not speak {pql[:40]!r}")
+        topn = pql.startswith("TopN(")
+        source = self.approx if topn and self.mode == "approximate_topn" \
+            else self.live
+        with self.mu:
+            if whole:
+                got = source.answer_pql(pql)
+            elif pql.startswith("Count("):
+                got = source.answer(self._count_key(pql))
+            elif topn:
+                rows = _ROW.findall(pql)
+                got = source.answer(("T", int(rows[0]) if rows else None,
+                                     int(_N.search(pql).group(1))))
+            else:
+                raise ValueError(f"the control does not speak {pql[:40]!r}")
+        if isinstance(got, int):
+            return got + bump
+        if bump and got:
+            got = [(got[0][0], got[0][1] + 1)] + list(got[1:])
+        return [{"id": r, "count": c} for r, c in got]
 
 
 def main() -> int:

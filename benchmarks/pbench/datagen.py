@@ -9,10 +9,12 @@ from the seed and the reference the configuration names (`names.reference`)
 tabulates its share from them. It is timed by itself (`ref.tabulated_s`) and
 the harness leaves those seconds out of `setup_s`.
 
-A frame kind (a module `pbench/kinds/<kind>.py`) gives the harness
-`generate(config, seed, data_dir, plan) -> reference` and
-`stage_query(frame_name)`, the query that stages the kind's view; `Kind` here
-builds both from how one slice is made.
+A kind (a module `pbench/kinds/<kind>.py`; protocol: that package's
+docstring) gives the harness `generate(config, seed, data_dir, plan) ->
+reference` and `stage_query(frame_name)`, the query that stages the kind's
+view; `Kind` here builds both from how one slice of one frame is made. A kind
+of several frames writes its own `generate` from the pieces here
+(`create_schema`, `frag_path`, `_write_fragment`).
 """
 
 from __future__ import annotations
@@ -132,7 +134,7 @@ def _job(args) -> Tuple[int, Optional[dict]]:
     without, the reference's share of the slice, from the same words made
     again from the seed."""
     slice_words, config, seed, s, data_dir, locals_, src_rows = args
-    frame = config["frame"]
+    frame = names.frames(config)[0]
     rows, words = slice_words(seed, s, data_dir, config["index"], frame)
     if data_dir is not None:
         return s, None
@@ -140,14 +142,20 @@ def _job(args) -> Tuple[int, Optional[dict]]:
                                                  src_rows)
 
 
-def create_schema(data_dir: str, index: str, frame: str) -> None:
+def create_schema(data_dir: str, index: str, *frames) -> None:
+    """The index and its frames, each a name or a configuration's frame: its
+    `name`, and under `options` what the schema needs of it, by the names
+    `Index.create_frame` takes (cache_type, time_quantum, inverse_enabled)."""
     from pilosa_tpu.core import Holder
 
     h = Holder(data_dir)
     h.open()
-    h.create_index_if_not_exists(index) \
-        .create_frame_if_not_exists(frame) \
-        .create_view_if_not_exists(VIEW)
+    idx = h.create_index_if_not_exists(index)
+    for frame in frames:
+        frame = {"name": frame} if isinstance(frame, str) else frame
+        idx.create_frame_if_not_exists(
+            frame["name"], **frame.get("options", {})) \
+            .create_view_if_not_exists(VIEW)
     h.close()
 
 
@@ -171,9 +179,9 @@ class Kind:
         seconds its tabulation took as `tabulated_s`. With `approx` (the
         control's) a pair: the exact reference and one counted over every
         other slice and doubled."""
-        index, frame, slices = config["index"], config["frame"], \
+        index, frame, slices = config["index"], names.frames(config)[0], \
             int(config["slices"])
-        create_schema(data_dir, index, frame["name"])
+        create_schema(data_dir, index, frame)
         updates = len(plan.updates())
         candidates = self.candidates(seed, slices << 20, 3 * updates + 64) \
             if updates else ()

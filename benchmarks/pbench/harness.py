@@ -18,7 +18,7 @@ import sys
 import threading
 import time
 import tomllib
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 from . import (datagen, durable, layers, names, reference, schedule,
                xplane)
@@ -75,11 +75,31 @@ def load_cell(workload: str) -> dict:
 # -- the plan: abstract ops of both streams, bound by --seed -----------------
 
 
+class PlanFrame(NamedTuple):
+    name: str
+    n_rows: int
+    perm: Sequence[int]  # Zipf rank -> row id, from --seed
+
+
 class Plan:
+    """The ops of a run. `frames` holds the configuration's frames by name,
+    the first of them the default: the one an op that names no frame draws
+    its ranks over. `bind` is the kind's own, or None for `schedule.bind`;
+    `config` and `seed` are there for it."""
+
     def __init__(self, config: dict, traffic: dict, seed: int):
-        self.frame = config["frame"]
-        self.n_rows = int(self.frame["rows"])
-        self.perm = schedule.row_permutation(seed, self.n_rows)
+        self.config, self.seed = config, seed
+        self.frames = {
+            f["name"]: PlanFrame(f["name"], int(f["rows"]),
+                                 schedule.row_permutation(seed, int(f["rows"]),
+                                                          nth))
+            for nth, f in enumerate(names.frames(config))}
+        self.default = next(iter(self.frames.values()))
+        self.bind = getattr(names.kind(config), "bind", None)
+        if self.bind is None and any("frames" in o for o in traffic["ops"]):
+            raise ValueError("the traffic names frames: the configuration's "
+                             "kind has to bind them (pbench/kinds)")
+        rows = {f.name: f.n_rows for f in self.frames.values()}
         warm = traffic["warmup"]
         clients = int(traffic["clients"])
         per_client = math.ceil(warm["ops_per_round"] / clients)
@@ -87,35 +107,52 @@ class Plan:
         lengths = {"window": int(traffic["max_ops"]),
                    "warmup": per_client * clients * int(warm["max_rounds"])}
         self.abstract = {
-            s: schedule.Template(traffic, self.n_rows, s).ops(0, n)
+            s: schedule.Template(traffic, self.default.n_rows, s,
+                                 rows).ops(0, n)
             for s, n in lengths.items()}
         self.abstract["burst"] = schedule.Template(
-            traffic, self.n_rows).bursts(clients, int(warm.get("bursts", 0)))
+            traffic, self.default.n_rows, frames=rows).bursts(
+                clients, int(warm.get("bursts", 0)))
         self.burst_rounds = len(self.abstract["burst"]) // clients
         self.columns: Dict[tuple, int] = {}
         self._bound: Dict[tuple, schedule.BoundOp] = {}
 
+    def rows(self, op: schedule.AbstractOp) -> List[Tuple[str, int]]:
+        """(frame name, row id) of each of the op's ranks."""
+        out = []
+        for k, rank in enumerate(op.ranks):
+            f = self.frames[op.frames[k]] if k < len(op.frames) \
+                else self.default
+            out.append((f.name, int(f.perm[rank])))
+        return out
+
     def updates(self) -> List[tuple]:
         """(stream, index, row) of every update, warm-up first."""
-        return [(s, i, int(self.perm[op.ranks[0]]))
+        return [(s, i, self.rows(op)[0][1])
                 for s in ("warmup", "window")
                 for i, op in enumerate(self.abstract[s])
                 if op.kind == "update"]
 
     def src_rows(self) -> List[int]:
-        return sorted({int(self.perm[op.ranks[0]])
+        return sorted({self.rows(op)[0][1]
                        for ops in self.abstract.values() for op in ops
                        if op.kind == "topn" and op.ranks})
 
     def assign_columns(self, candidates, can_write) -> None:
         """Each update takes the first unused candidate column that the
         reference lets its row write: the bit is clear, so every SetBit
-        changes a bit, and no container has to be made for it."""
-        free = [int(c) for c in candidates]
+        changes a bit, and no container has to be made for it. An update
+        that names its frame takes from that frame's candidates
+        (`candidates[frame]`) and asks `can_write(row, column, frame)`."""
+        free: Dict[tuple, List[int]] = {}  # () or (frame,) -> candidates
         for stream, i, row in self.updates():
-            for j, c in enumerate(free):
-                if can_write(row, c):
-                    self.columns[(stream, i)] = free.pop(j)
+            named = self.abstract[stream][i].frames[:1]
+            if named not in free:
+                free[named] = [int(c) for c in (
+                    candidates[named[0]] if named else candidates)]
+            for j, c in enumerate(free[named]):
+                if can_write(row, c, *named):
+                    self.columns[(stream, i)] = free[named].pop(j)
                     break
             else:
                 raise RuntimeError("ran out of writable candidate columns")
@@ -126,9 +163,11 @@ class Plan:
             return None
         got = self._bound.get((stream, i))
         if got is None:
-            got = self._bound[(stream, i)] = schedule.bind(
-                ops[i], self.perm, self.frame["name"], self.n_rows,
-                self.columns.get((stream, i)))
+            column = self.columns.get((stream, i))
+            got = self._bound[(stream, i)] = (
+                self.bind(ops[i], self, column) if self.bind else
+                schedule.bind(ops[i], self.default.perm, self.default.name,
+                              self.default.n_rows, column))
         return got
 
 
@@ -137,7 +176,10 @@ class Plan:
 
 def compare(ref, phases: Dict[str, List[Done]], plan: Plan) -> dict:
     """Every answer of every phase against the reference. Returns the numbers
-    compared and the window ops whose answer was wrong."""
+    compared and the window ops whose answer was wrong. The reference is
+    handed reads as (key, t_send, t_done, answer) and writes as (row, column,
+    t_send, t_ack), with the frame written as a fifth where the op's kind
+    named it (`BoundOp.frame`); `acked` likewise (row, column[, frame])."""
     reads, writes, where, acked = [], [], [], []
     wrong_seqs, examples = set(), []
     wrong = unanswered = not_judged = 0
@@ -149,6 +191,8 @@ def compare(ref, phases: Dict[str, List[Done]], plan: Plan) -> dict:
     for phase, log in phases.items():
         for d in log:
             op = plan.op_at(phase, d.seq) if d.seq >= 0 else None
+            # A write names its frame last, where its kind named it.
+            named = (op.frame,) if op and op.frame is not None else ()
             for j, (pql, t0, t1, status, result) in enumerate(d.requests):
                 if status != 200 or (isinstance(result, dict)
                                      and "error" in result):
@@ -158,8 +202,7 @@ def compare(ref, phases: Dict[str, List[Done]], plan: Plan) -> dict:
                     if pql.startswith("SetBit("):
                         # Unknown whether it landed: it may be seen by any
                         # later read, and is owed to none.
-                        writes.append((op.write[0], op.write[1], t0,
-                                       float("inf")))
+                        writes.append((*op.write, t0, float("inf"), *named))
                     continue
                 if pql.startswith("SetBit("):
                     if result is not True:
@@ -167,8 +210,8 @@ def compare(ref, phases: Dict[str, List[Done]], plan: Plan) -> dict:
                         flag(phase, d.seq,
                              f"{pql[:60]} acknowledged {result!r}")
                     else:
-                        acked.append((op.write[0], op.write[1]))
-                    writes.append((op.write[0], op.write[1], t0, t1))
+                        acked.append((*op.write, *named))
+                    writes.append((*op.write, t0, t1, *named))
                 else:
                     reads.append((op.key if op else d.key, t0, t1, result))
                     where.append((phase, d.seq, pql))
@@ -267,6 +310,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         config["columns"] = slices << 20
     chips = int(cell["cell"]["chips"])
     kind = names.kind(config)
+    default_frame = names.frames(config)[0]["name"]
     run_dir = os.path.join(OUT_DIR, workload + (f".{control}" if control
                                                 else ""))
     shutil.rmtree(run_dir, ignore_errors=True)
@@ -335,14 +379,16 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         clients = Clients(srv.host, srv.port, config["index"], n_clients,
                           plan.op_at,
                           int(traffic.get("profile_one_in", 0)))
-        stage_pql, stage_key, stage_kind = kind.stage_query(
-            config["frame"]["name"])
-        status, body, t0, t1 = clients.post(0, stage_pql, False)
-        result = body["results"][0] if status == 200 \
-            and isinstance(body, dict) and "results" in body else body
-        stage = Done(0, -1, stage_kind, t0, t1,
-                     status == 200, ((stage_pql, t0, t1, status, result),),
-                     None, stage_key)
+        stage: List[Done] = []
+        for stage_pql, stage_key, stage_kind in (
+                kind.stage_queries(config) if hasattr(kind, "stage_queries")
+                else [kind.stage_query(default_frame)]):
+            status, body, t0, t1 = clients.post(0, stage_pql, False)
+            result = body["results"][0] if status == 200 \
+                and isinstance(body, dict) and "results" in body else body
+            stage.append(Done(0, -1, stage_kind, t0, t1, status == 200,
+                              ((stage_pql, t0, t1, status, result),),
+                              None, stage_key))
         mark("stage")
         vars_staged = srv.vars() if not stand_in else {}
         if not stand_in and pinned is not None:
@@ -396,7 +442,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         if tracer is not None:
             tracer.join()
             wj = os.path.join(trace_dir, "window.json")
-            deadline = time.monotonic() + 120
+            # Stopping a trace took 70.5 s on four chips (PR 32): wait for
+            # it while the server lives.
+            deadline = time.monotonic() + 600
             while not os.path.exists(wj) and time.monotonic() < deadline \
                     and srv.alive():
                 time.sleep(0.2)
@@ -422,7 +470,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     mark("stop")
 
     # -- the comparison, once the window has closed and the state is freed ------
-    phases = {"stage": [stage],
+    phases = {"stage": stage,
               "burst": [d for d in warm_log if d.stream == "burst"],
               "warmup": [d for d in warm_log if d.stream == "warmup"],
               "window": window_log}
@@ -438,14 +486,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     if not stand_in and any(o["kind"] == "update" for o in traffic["ops"]):
         # "An acknowledged SetBit is durable": every one of them has to be in
         # the dead server's fragment files, by a plain reader of the format.
-        lost = durable.lost_writes(
-            lambda s: datagen.frag_path(data_dir, config["index"],
-                                        config["frame"]["name"], s),
-            cmp_["acked"])
+        by_frame: Dict[str, list] = {}
+        for w in cmp_["acked"]:  # (row, column[, frame]): the frame written
+            by_frame.setdefault(w[2] if len(w) > 2 else default_frame,
+                                []).append(w[:2])
+        lost = [(name, r, c) for name, acked in by_frame.items()
+                for r, c in durable.lost_writes(
+                    lambda s, name=name: datagen.frag_path(
+                        data_dir, config["index"], name, s), acked)]
         compared["lost_writes"] = {"value": len(lost), "limit": 0,
                                    "of": len(cmp_["acked"])}
-        cmp_["examples"] += [f"SetBit(row {r}, column {c}) acknowledged and "
-                             f"not on disk" for r, c in lost[:4]]
+        cmp_["examples"] += [f"SetBit(row {r}, column {c}, frame {name}) "
+                             f"acknowledged and not on disk"
+                             for name, r, c in lost[:4]]
     shutil.rmtree(data_dir, ignore_errors=True)
     mark("compare")
     win = reduce_window(window_log, cmp_["wrong_seqs"])

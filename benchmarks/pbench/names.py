@@ -1,6 +1,7 @@
 """Generators and references found by name, as traffic mixes and per-layer
-metrics are. `config["frame"]["kind"]` names the module `pbench/kinds/<kind>.py`
-and `config["correctness"]["reference"]` the module `pbench/refs/<name>.py`;
+metrics are. `config["kind"]` (or, where the configuration has one frame, that
+frame's `kind`) names the module `pbench/kinds/<kind>.py` and
+`config["correctness"]["reference"]` the module `pbench/refs/<name>.py`;
 a later PR adds one as a new file (protocols: the two packages' docstrings)."""
 
 from __future__ import annotations
@@ -8,6 +9,7 @@ from __future__ import annotations
 import importlib
 import os
 import pkgutil
+from typing import List
 
 
 class UnknownName(Exception):
@@ -27,10 +29,17 @@ def _find(what: str, name: str, package: str):
                       f"the file {package.replace('.', os.sep)}/{name}.py)")
 
 
+def frames(config: dict) -> List[dict]:
+    """The configuration's frames, the first of them the default: `frames`,
+    a list, or `frame`, which means a list of that one."""
+    return list(config["frames"]) if "frames" in config else [config["frame"]]
+
+
 def kind(config: dict):
-    """The generator of the configuration's frame: `generate(config, seed,
-    data_dir, plan)` and `stage_query(frame_name)`."""
-    return _find("frame kind", str(config["frame"]["kind"]), "pbench.kinds")
+    """The configuration's generator (protocol: `pbench/kinds/__init__.py`).
+    A configuration of several frames names it once, as `kind`."""
+    name = config["kind"] if "kind" in config else frames(config)[0]["kind"]
+    return _find("frame kind", str(name), "pbench.kinds")
 
 
 def reference(config: dict):

@@ -7,6 +7,15 @@ cell, whatever `--seed` says. `--seed` only decides how a rank maps to a row id
 (a permutation), which data the rows hold and which columns are written; that
 is `bind()`.
 
+An op of a traffic file may name frames of the configuration, `"frames":
+[...]`: its i-th rank is then drawn over the rows of the i-th frame named (that
+frame's own row count, and its own Zipf theta where the file's
+`zipf_theta_by_frame` gives one), and names past the op's ranks draw nothing.
+What the names mean in PQL is for the configuration's kind to say, in its
+`bind` (`pbench/kinds/__init__.py`); so is an op kind that this file does not
+know, which draws `arity` ranks. A file that names no frame draws every rank
+over the configuration's first frame and is bound by `bind()` here.
+
 Imports numpy only. Nothing of the program.
 """
 
@@ -21,11 +30,12 @@ STREAMS = {"window": 0, "warmup": 1, "burst": 2}
 
 
 class AbstractOp(NamedTuple):
-    kind: str              # "count" | "update" | "topn"
+    kind: str              # "count" | "update" | "topn" | one the kind binds
     op: str                # Intersect | Union | Difference | "" (topn, update)
     arity: str             # "2", "all", "1" (update), "src" / "none" (topn)
     ranks: Tuple[int, ...]  # Zipf ranks, 0 = the hottest row
     n: int                 # TopN's n, else 0
+    frames: Tuple[str, ...] = ()  # the frames the traffic file names for it
 
 
 def zipf_cdf(n: int, theta: float) -> np.ndarray:
@@ -48,49 +58,59 @@ def _expand(ops: Sequence[dict], size: int) -> Tuple[List[dict], List[dict]]:
 
 
 class Template:
-    """The abstract sequence of one traffic file over a frame of `n_rows`.
+    """The abstract sequence of one traffic file over a frame of `n_rows`,
+    and over the `frames` (name -> rows) that its ops name.
 
     `op(i)` is the i-th op of the stream; client c of C takes ops c, c + C,
     c + 2C, ... Blocks are made on demand and kept."""
 
-    def __init__(self, traffic: dict, n_rows: int, stream: str = "window"):
+    def __init__(self, traffic: dict, n_rows: int, stream: str = "window",
+                 frames: Optional[Dict[str, int]] = None):
         self.traffic = traffic
-        self.n_rows = int(n_rows)
         self.size = int(traffic["block"]["size"])
         self.seed = int(traffic["template_seed"])
         self.stream = STREAMS[stream]
-        self.cdf = zipf_cdf(self.n_rows, float(traffic["zipf_theta"]))
+        thetas = traffic.get("zipf_theta_by_frame", {})
+        self._zipf = {
+            name: (zipf_cdf(int(n), float(thetas.get(
+                name, traffic["zipf_theta"]))), int(n))
+            for name, n in {None: n_rows, **(frames or {})}.items()}
         self._striped, self._free = _expand(traffic["ops"], self.size)
         self._blocks: Dict[int, List[AbstractOp]] = {}
         self._mu = threading.Lock()
 
-    def _ranks(self, rng, k: int) -> Tuple[int, ...]:
-        out: List[int] = []
+    def _ranks(self, rng, k: int, frames: Sequence[str] = ()
+               ) -> Tuple[int, ...]:
+        """k ranks, the i-th over the i-th frame named (past the names: the
+        default frame), distinct within a frame."""
+        out: List[tuple] = []
         while len(out) < k:
-            r = int(np.searchsorted(self.cdf, rng.random(), side="right"))
-            r = min(r, self.n_rows - 1)
-            if r not in out:
-                out.append(r)
-        return tuple(out)
+            frame = frames[len(out)] if len(out) < len(frames) else None
+            cdf, n_rows = self._zipf[frame]
+            r = int(np.searchsorted(cdf, rng.random(), side="right"))
+            r = min(r, n_rows - 1)
+            if (frame, r) not in out:
+                out.append((frame, r))
+        return tuple(r for _, r in out)
 
     def _abstract(self, rng, spec: dict) -> AbstractOp:
         kind = spec["kind"]
         if kind == "update":
-            return AbstractOp("update", "", "1", self._ranks(rng, 1), 0)
-        if kind == "count":
-            arity = str(spec["arity"])
-            if arity == "all":
-                # All rows; a Difference names its minuend by rank.
-                k = 1 if spec["op"] == "Difference" else 0
-            else:
-                k = int(arity)
-            return AbstractOp("count", spec["op"], arity,
-                              self._ranks(rng, k), 0)
-        if kind == "topn":
+            op, arity, k, n = "", "1", 1, 0
+        elif kind == "count":
+            op, arity, n = spec["op"], str(spec["arity"]), 0
+            # All rows; a Difference names its minuend by rank.
+            k = int(op == "Difference") if arity == "all" else int(arity)
+        elif kind == "topn":
             k = 1 if spec.get("src") else 0
-            return AbstractOp("topn", "", "src" if k else "none",
-                              self._ranks(rng, k), int(spec["n"]))
-        raise ValueError(f"unknown op kind {kind!r}")
+            op, arity, n = "", "src" if k else "none", int(spec["n"])
+        else:  # a kind of op only the configuration's kind can bind
+            k = int(spec.get("arity", 0))
+            op, arity, n = str(spec.get("op", "")), str(k), \
+                int(spec.get("n", 0))
+        frames = tuple(spec.get("frames", ()))
+        return AbstractOp(kind, op, arity, self._ranks(rng, k, frames), n,
+                          frames)
 
     def _block(self, b: int) -> List[AbstractOp]:
         with self._mu:
@@ -137,8 +157,10 @@ class Template:
 # -- binding: --seed decides row ids and written columns ----------------------
 
 
-def row_permutation(seed: int, n_rows: int) -> np.ndarray:
-    return np.random.default_rng([seed, 101]).permutation(n_rows)
+def row_permutation(seed: int, n_rows: int, nth: int = 0) -> np.ndarray:
+    """The rank -> row id map of the configuration's nth frame."""
+    return np.random.default_rng(
+        [seed, 101, nth] if nth else [seed, 101]).permutation(n_rows)
 
 
 def bitmap(row: int, frame: str) -> str:
@@ -150,6 +172,7 @@ class BoundOp(NamedTuple):
     pql: Tuple[str, ...]     # one request each; an update has two
     key: tuple               # the reference's key of the (last) read
     write: Optional[Tuple[int, int]]  # (row, column) of an update
+    frame: Optional[str] = None  # the frame written, where the kind names it
 
 
 def bind(op: AbstractOp, perm: np.ndarray, frame: str, n_rows: int,

@@ -21,6 +21,8 @@ for p in (REPO, BENCH):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+import pbench.kinds  # noqa: E402
+import pbench.refs  # noqa: E402
 from pbench import (datagen, durable, harness, layers, reference,  # noqa: E402
                     schedule, window, xplane)
 
@@ -31,6 +33,9 @@ CELLS = [w["name"] for w in BENCHMARK["workloads"]]
 # stand-in for "a later PR's files" in the rehearsal below. Not a deployment.
 FIXTURE = os.path.join(HERE, "fixture")
 FIXTURE_CELL = "topn-fixture.topn-fixture8"
+# And a deployment of two frames with a kind and a reference of its own (Star
+# Trace in miniature; test_startrace_fixture.py).
+STAR_CELL = "startrace-fixture.startrace-fixture4"
 TRAFFIC_DIRS = {**{f[:-5]: os.path.join(BENCH, "traffic")
                    for f in os.listdir(os.path.join(BENCH, "traffic"))},
                 "topn-fixture8": os.path.join(FIXTURE, "traffic")}
@@ -58,16 +63,23 @@ def rows_for(name):
 @pytest.fixture
 def later_pr(tmp_path, monkeypatch):
     """A tree as a later PR would leave it: the benchmark's files untouched,
-    and beside them a new configuration, a new traffic mix, a new
-    counter-backed metric and their BENCHMARK.json entries. The harness is
-    pointed at it."""
+    and beside them new configurations, new traffic mixes, a new kind and a
+    new reference, a new counter-backed metric and their BENCHMARK.json
+    entries. The harness is pointed at it."""
     root = tmp_path / "repo"
     shutil.copytree(BENCH, root / "benchmarks",
                     ignore=shutil.ignore_patterns("out", "__pycache__"))
-    for sub in ("configs", "traffic"):
-        for f in os.listdir(os.path.join(FIXTURE, sub)):
-            shutil.copy(os.path.join(FIXTURE, sub, f),
-                        root / "benchmarks" / sub / f)
+    added = []
+    for sub in ("configs", "traffic", "pbench/kinds", "pbench/refs"):
+        src = os.path.join(FIXTURE, os.path.basename(sub))
+        for f in os.listdir(src):
+            assert not (root / "benchmarks" / sub / f).exists(), f
+            shutil.copy(os.path.join(src, f), root / "benchmarks" / sub / f)
+            if sub.startswith("pbench/"):
+                added.append(f"pbench.{os.path.basename(sub)}.{f[:-3]}")
+    for pkg in (pbench.kinds, pbench.refs):
+        monkeypatch.setattr(pkg, "__path__", list(pkg.__path__) + [str(
+            root / "benchmarks/pbench" / pkg.__name__.rsplit(".", 1)[1])])
     (root / "benchmarks/layer_metrics/mesh.deduped.json").write_text(
         json.dumps({"name": "mesh.deduped", "layer": "mesh serving",
                     "unit": "ops", "better": "higher",
@@ -80,19 +92,27 @@ def later_pr(tmp_path, monkeypatch):
     b["workloads"].append({"name": FIXTURE_CELL, "config": "topn-fixture",
                            "traffic": "topn-fixture8", "chips": 1,
                            "why": "z"})
+    b["configs"].append({"name": "startrace-fixture", "source": "x",
+                         "reduced": [], "why": "y",
+                         "file": "benchmarks/configs/startrace-fixture.json"})
+    b["workloads"].append({"name": STAR_CELL, "config": "startrace-fixture",
+                           "traffic": "startrace-fixture4", "chips": 1,
+                           "why": "z"})
     for m in b["end_to_end"]:
         if m["name"] in ("ops_per_s", "read_p50_ms"):
             assert "workloads" not in m
     b["per_layer"].append({"name": "mesh.deduped", "unit": "ops",
                            "better": "higher", "source": "program_counter",
                            "layer": "mesh serving", "moves": "ops_per_s",
-                           "workloads": [FIXTURE_CELL]})
+                           "workloads": [FIXTURE_CELL, STAR_CELL]})
     (root / "BENCHMARK.json").write_text(json.dumps(b))
     monkeypatch.setattr(harness, "REPO", str(root))
     monkeypatch.setattr(harness, "BENCH_DIR", str(root / "benchmarks"))
     monkeypatch.setattr(harness, "OUT_DIR", str(tmp_path / "out"))
     monkeypatch.setattr(layers, "HERE", str(root / "benchmarks"))
-    return root
+    yield root
+    for module in added:  # the next tree's copies are other files
+        sys.modules.pop(module, None)
 
 
 # -- the schedule ----------------------------------------------------------------

@@ -370,45 +370,87 @@ def test_an_unknown_name_is_an_error_that_lists_the_known(where, key):
             is reference.TopNReference.assemble.__func__)
 
 
-# -- the dense path, pinned ----------------------------------------------------------
+# -- what a cell generates and binds, pinned --------------------------------------------
 
-PINS = load(FIXTURE, "pins", "seg-1b.2slices.json")
+PINS = {name: load(FIXTURE, "pins", name + ".2slices.json")
+        for name in ("seg-1b", "topn-1b", "topn-ingest-1b")}
 
 
 def _sha(obj):
     return hashlib.sha256(json.dumps(obj).encode()).hexdigest()
 
 
-@pytest.mark.parametrize("seed", sorted(PINS["seeds"]))
-def test_dense_path_is_what_it_was_on_the_parent(seed, tmp_path):
-    """seg-1b at 2 slices: the fragment files, the reference table, the kept
-    column bits, assign_columns' picks and the first 64 bound ops, against
-    values taken from the parent commit's code (fixture/pins/): the driver
-    lays this benchmark over the parent, and a reading that moves with the
-    harness alone would be the benchmark's doing."""
-    want = PINS["seeds"][seed]
-    cell = harness.load_cell(PINS["cell"])
+def _tables(ref):
+    """The reference's tables in the pins' form: a count reference's `base`
+    and kept column bits; a TopN reference's |row|, |row & src|, rows kept
+    at each candidate column, rows present in each slice and row bytes."""
+    if hasattr(ref, "base"):
+        return {"base": [int(x) for x in ref.base],
+                "kept_sha256": _sha(sorted((int(c), [int(b) for b in bits])
+                                           for c, bits in ref.kept.items()))}
+
+    def pairs(d):
+        return sorted([int(k), int(v)] for k, v in d.items())
+
+    def sets(d):
+        return _sha(sorted([int(k), sorted(int(r) for r in v)]
+                           for k, v in d.items()))
+    return {"totals": pairs(ref.totals),
+            "by_src": sorted([int(x), pairs(d)]
+                             for x, d in ref.by_src.items()),
+            "kept_sha256": sets(ref.kept),
+            "present_sha256": sets(ref.present),
+            "row_bytes_sha256": _sha(pairs(ref.row_bytes))}
+
+
+@pytest.mark.parametrize("name,seed", [(n, s) for n in sorted(PINS)
+                                       for s in sorted(PINS[n]["seeds"])])
+def test_dense_path_is_what_it_was_on_the_parent(name, seed, tmp_path):
+    """seg-1b, topn-1b and topn-ingest-1b at 2 slices: the fragment files, the
+    reference's tables, the candidates, assign_columns' picks, the first 64
+    bound ops, every update as bound and the staging query, against values
+    taken from the parent commit's code (fixture/pins/): the driver lays this
+    benchmark over the parent, and a reading that moves with the harness
+    alone would be the benchmark's doing. (The two TopN cells are pinned
+    with `max_ops` cut and on seeds whose updated rows hold a container in
+    one of the two slices: at 960 slices every row does.)"""
+    pins = PINS[name]
+    want = pins["seeds"][seed]
+    cell = harness.load_cell(pins["cell"])
     config = dict(cell["config"], slices=2, columns=2 << 20)
-    plan = harness.Plan(config, cell["traffic"], int(seed))
-    ref = names.kind(config).generate(config, int(seed), str(tmp_path), plan)
+    traffic = dict(cell["traffic"],
+                   max_ops=pins.get("max_ops", cell["traffic"]["max_ops"]))
+    plan = harness.Plan(config, traffic, int(seed))
+    kind = names.kind(config)
+    ref = kind.generate(config, int(seed), str(tmp_path), plan)
     digests = []
     for s in (0, 1):
-        with open(datagen.frag_path(str(tmp_path), "i", "dense", s),
-                  "rb") as f:
+        with open(datagen.frag_path(str(tmp_path), config["index"],
+                                    config["frame"]["name"], s), "rb") as f:
             digests.append(hashlib.sha256(f.read()).hexdigest())
     assert digests == want["fragments_sha256"]
-    assert [int(x) for x in ref.base] == want["base"]
-    assert len(ref.candidates()) == want["n_candidates"]
-    assert ref.candidates()[:16] == want["candidates_head"]
-    assert _sha(sorted((int(c), [int(b) for b in bits])
-                       for c, bits in ref.kept.items())) == want["kept_sha256"]
+    for table, got in _tables(ref).items():
+        assert got == want[table], table
+    cands = [int(c) for c in ref.candidates()]
+    assert len(cands) == want["n_candidates"]
+    assert cands[:16] == want["candidates_head"]
+    assert _sha(cands) == want.get("candidates_sha256", _sha(cands))
     plan.assign_columns(ref.candidates(), ref.can_write)
     picks = sorted((s, i, c) for (s, i), c in plan.columns.items())
     assert [list(p) for p in picks[:40]] == want["picks_head"]
     assert _sha(picks) == want["picks_sha256"]
-    ops = [plan.op_at("window", i) for i in range(64)]
-    assert [[o.kind, list(o.pql), list(o.key),
-             list(o.write) if o.write else None] for o in ops] == want["ops"]
+
+    def bound(o):
+        assert o.frame is None  # one frame: a write is (row, column) alone
+        return [o.kind, list(o.pql), list(o.key),
+                list(o.write) if o.write else None]
+    assert [bound(plan.op_at("window", i)) for i in range(64)] == want["ops"]
+    if "updates_sha256" in want:
+        assert _sha([[stream, i, *bound(plan.op_at(stream, i))[1:]]
+                     for stream, i, _ in plan.updates()]) == \
+            want["updates_sha256"]
+        pql, key, op_kind = kind.stage_query(config["frame"]["name"])
+        assert [pql, list(key), op_kind] == want["stage_query"]
 
 
 # -- a whole run of a mix with updates ------------------------------------------------
