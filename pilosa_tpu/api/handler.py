@@ -1093,6 +1093,18 @@ class Handler:
             for mode in ("in_place", "copied"):
                 applied.add(stats.get(f"apply_{mode}", 0), {"mode": mode})
             fams.append(applied)
+            full = prom.MetricFamily(
+                "pilosa_refresh_full_walks_total", "counter",
+                "Refreshes of a staged view that visited every slice, "
+                "not only the slices the view's write record names, by "
+                "reason (unrecorded = no record was read when the view "
+                "was last brought up to date, structural = a fragment, "
+                "frame or index was created or deleted since, overrun "
+                "= more writes than the record holds).")
+            for reason in ("unrecorded", "structural", "overrun"):
+                full.add(stats.get(f"refresh_full_walks_{reason}", 0),
+                         {"reason": reason})
+            fams.append(full)
             fams.append(prom.MetricFamily(
                 "pilosa_plan_quarantined_total", "counter",
                 "Plan signatures quarantined off the device path "

@@ -99,6 +99,12 @@ def bitmap_from_tar(tar_bytes: bytes) -> Optional[Bitmap]:
     return None
 
 
+# Writes a WriteCounter remembers the slice of (its ring's size): a
+# reader that falls further behind than this walks every slice. 64
+# clients land at most 64 writes between two refreshes of a view.
+WRITE_RECORD = 512
+
+
 class WriteCounter:
     """Writes to one view: one count for all of its fragments, moved
     by every write that moves a `Fragment.generation` (a bit write,
@@ -106,12 +112,20 @@ class WriteCounter:
     with the fragments it opens; a bare `Fragment` has its own.
     Only `_MutationEpoch.bump` writes `n`, under the epoch's lock:
     fragments of one view are written under their OWN `_mu`, and an
-    unguarded `n += 1` on two threads can lose an update."""
+    unguarded `n += 1` on two threads can lose an update.
 
-    __slots__ = ("n", "__weakref__")
+    `ring` records WHERE: the slice of write `n` at `ring[n %
+    WRITE_RECORD]`, for the last WRITE_RECORD writes (None until the
+    first). A reader that remembers the `n` it last saw learns the
+    slices written since from `_MutationEpoch.written_since`, as long
+    as the ring still reaches back that far; reading consumes
+    nothing, so any number of readers share one record."""
+
+    __slots__ = ("n", "ring", "__weakref__")
 
     def __init__(self):
         self.n = 0
+        self.ring: Optional[List[int]] = None
 
 
 class _MutationEpoch:
@@ -157,10 +171,26 @@ class _MutationEpoch:
         self.s = 0
         self._mu = threading.Lock()
 
-    def bump(self, writes: WriteCounter):
+    def bump(self, writes: WriteCounter, slice_: int):
         with self._mu:
             writes.n += 1
+            if writes.ring is None:
+                writes.ring = [0] * WRITE_RECORD
+            writes.ring[writes.n % WRITE_RECORD] = slice_
             self.n += 1
+
+    def written_since(self, writes: WriteCounter, n0: int) -> tuple:
+        """(writes.n, the slices of the writes after `n0`, one entry a
+        write) read together under the lock, or (writes.n, None) when
+        the ring no longer reaches back to `n0`."""
+        with self._mu:
+            n, ring = writes.n, writes.ring
+            if n == n0:
+                return n, []
+            if ring is None or not 0 < n - n0 <= WRITE_RECORD:
+                return n, None
+            a, b = (n0 + 1) % WRITE_RECORD, (n + 1) % WRITE_RECORD
+            return n, ring[a:b] if a < b else ring[a:] + ring[:b]
 
     def bump_structural(self):
         with self._mu:
@@ -819,7 +849,7 @@ class Fragment:
     def _log_append(self, op: int, pos: int, churn: bool):
         self.generation += 1
         self.epoch += 1
-        MUTATION_EPOCH.bump(self.view_writes)
+        MUTATION_EPOCH.bump(self.view_writes, self.slice)
         self._log.append((op, pos, churn))
         if len(self._log) > self._log_limit:
             drop = len(self._log) - self._log_limit
@@ -831,7 +861,7 @@ class Fragment:
         any earlier generation must rebuild."""
         self.generation += 1
         self.epoch += 1
-        MUTATION_EPOCH.bump(self.view_writes)
+        MUTATION_EPOCH.bump(self.view_writes, self.slice)
         self._log.clear()
         self._log_base = self.generation
 

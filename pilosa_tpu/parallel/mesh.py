@@ -2104,20 +2104,23 @@ def pack_mutation_batches(per_slice, num_slices: int, capacity: int):
     per_slice: {slice_id: (slot, word, set_mask, clear_mask)}. The
     no-op/width scheme is ops.pool's (pad_mutation_plan): padding rides
     out-of-bounds slots, B is the shared power-of-two width of the
-    widest slice's plan.
+    widest slice's plan. The arrays are made as padding whole and only
+    the written slices' rows are filled in: the host's cost follows
+    the slices written, not the slices staged.
     """
-    from ..ops.pool import mutation_batch_width, pad_mutation_plan
+    from ..ops.pool import mutation_batch_width
 
     widest = max((len(v[0]) for v in per_slice.values()), default=0)
     b = mutation_batch_width(widest)
-    empty = pad_mutation_plan(
-        (np.zeros(0, np.int32), np.zeros(0, np.int32),
-         np.zeros(0, np.uint32), np.zeros(0, np.uint32)), capacity, b)
-    rows = [per_slice.get(si) for si in range(num_slices)]
-    padded = [pad_mutation_plan(r, capacity, b) if r is not None else empty
-              for r in rows]
-    return (*(np.stack([p[i] for p in padded]) for i in range(4)),
-            np.int32(widest))
+    slot = np.full((num_slices, b), capacity, dtype=np.int32)
+    word = np.zeros((num_slices, b), dtype=np.int32)
+    set_mask = np.zeros((num_slices, b), dtype=np.uint32)
+    clear_mask = np.zeros((num_slices, b), dtype=np.uint32)
+    for si, (sl, wd, sm, cm) in per_slice.items():
+        n = len(sl)
+        slot[si, :n], word[si, :n] = sl, wd
+        set_mask[si, :n], clear_mask[si, :n] = sm, cm
+    return slot, word, set_mask, clear_mask, np.int32(widest)
 
 
 def compile_serve_apply_writes(mesh: Mesh, donate: bool = False):

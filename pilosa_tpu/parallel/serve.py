@@ -164,7 +164,7 @@ class StagedView:
                  "sparse_cards_host", "slice_formats", "sparse_idx_cache",
                  "sparse_slots_host", "sparse_free_slots", "sparse_kmax",
                  "sparse_k64", "sparse_overflow", "sparse_overflow_dev",
-                 "sparse_dev_idx_cache", "dispatch_gen")
+                 "sparse_dev_idx_cache", "dispatch_gen", "writes_seen")
 
     def __init__(self, sharded, row_ids, keys_host, slice_gens, num_slices,
                  sparse=None, sparse_keys_host=None, sparse_cards_host=None,
@@ -289,6 +289,12 @@ class StagedView:
         # bump pairs with an epoch bump — fragment.py:334-346), so the
         # per-slice walk is skipped entirely. None = never validated.
         self.validated_epoch: Optional[tuple] = None
+        # (the view's WriteCounter, its n) read with that pair, before
+        # the walk or snapshot it stamps: every write of the view up to
+        # that n is in the image, so the next refresh visits only the
+        # slices the counter's record names after it
+        # (MeshManager._slices_written). None = no record to read.
+        self.writes_seen: Optional[tuple] = None
 
     @property
     def padded_slices(self) -> int:
@@ -739,6 +745,15 @@ class MeshManager:
             "h2d_bytes": 0, "h2d_dispatch_us": 0,
             "refresh_pick_incremental": 0, "refresh_pick_restage": 0,
             "refresh_probe_restage": 0, "inc_ewma_us": 0,
+            # Slices the refreshes past the epoch fast path set out to
+            # visit (refresh_walks counts those refreshes), and the
+            # refreshes that took every slice because the view's write
+            # record could not say which were written, by reason
+            # (_slices_written).
+            "refresh_walk_fragments": 0, "refresh_full_walks": 0,
+            "refresh_full_walks_unrecorded": 0,
+            "refresh_full_walks_structural": 0,
+            "refresh_full_walks_overrun": 0,
             # Containers that writes created and _refresh_walk patched
             # into free slots of the staged pool; the creations it had
             # to restage for instead, by reason (/metrics:
@@ -1544,8 +1559,12 @@ class MeshManager:
                     # as the dominant host cost of a concurrent herd).
                     return sv
             if sv is None or sv.num_slices != num_slices:
+                # The view's write count, read after `ep` and before the
+                # snapshot: every write up to it is in the staged image.
+                v = self.holder.view(*key)
+                seen = None if v is None else (v.writes, v.writes.n)
                 fresh = self._stage(key, num_slices)
-                fresh.validated_epoch = ep
+                fresh.validated_epoch, fresh.writes_seen = ep, seen
                 return fresh
             # What the first Count after a write pays, and no profiled
             # request of the benchmark crosses: counted.
@@ -1556,6 +1575,35 @@ class MeshManager:
                 self.stats.inc("refresh_walks")
                 self.stats.inc("refresh_walk_us",
                                int((time.monotonic() - t0) * 1e6))
+
+    def _slices_written(self, sv: StagedView, ep, writes,
+                        num_slices: int) -> tuple:
+        """(the writes_seen to store once `sv` is brought up to `ep`,
+        the slices _refresh_walk visits). With the structural epoch
+        where `sv` was validated no fragment appeared or went, so the
+        slices `writes` recorded since sv.writes_seen are all that can
+        have moved: those below num_slices, each once, none after a
+        write to another view. Every slice when the record cannot say,
+        counted by reason (refresh_full_walks_<reason>; /metrics
+        pilosa_refresh_full_walks_total{reason}): `unrecorded`, no
+        record read at the last validation or another counter now (the
+        view was made again); `structural`, the structural epoch moved;
+        `overrun`, the ring no longer reaches back. Call under _mu."""
+        seen = sv.writes_seen
+        if writes is None or seen is None or seen[0] is not writes:
+            reason = "unrecorded"
+        elif sv.validated_epoch[1] != ep[1]:
+            reason = "structural"
+        else:
+            n, written = MUTATION_EPOCH.written_since(writes, seen[1])
+            if written is not None:
+                return (writes, n), sorted(
+                    {s for s in written if s < num_slices})
+            reason = "overrun"
+        self.stats.inc("refresh_full_walks")
+        self.stats.inc("refresh_full_walks_" + reason)
+        return (None if writes is None else (writes, writes.n),
+                range(num_slices))
 
     def _refresh_walk(self, key, sv: StagedView, ep,
                       num_slices: int) -> StagedView:
@@ -1576,14 +1624,24 @@ class MeshManager:
         slot would have to be given back), a slice with no free slot,
         a row the view's row table lacks, a write to a dense slice of a
         mixed view, a fragment that appeared or went, a pruned log. A
-        sparse view's writes go to _refresh_sparse."""
+        sparse view's writes go to _refresh_sparse.
+
+        Which slices it visits: those the view's write record
+        (core.fragment.WriteCounter) names since `sv.writes_seen`, so a
+        write costs the slices it wrote, not the slices staged; none at
+        all after a write to another view. Every slice, as before, when
+        the record cannot answer (_slices_written: the structural epoch
+        moved, the ring was overrun, or nothing was recorded)."""
         index, frame, view = key
+        v = self.holder.view(index, frame, view)
+        seen, slices = self._slices_written(
+            sv, ep, v.writes if v is not None else None, num_slices)
 
         def restage(refused: Optional[str] = None):
             if refused is not None:
                 self.stats.inc("container_patch_refused_" + refused)
             f = self._stage(key, num_slices)
-            f.validated_epoch = ep
+            f.validated_epoch, f.writes_seen = ep, seen
             return f
 
         pending: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
@@ -1594,8 +1652,9 @@ class MeshManager:
         sparse_fmt = (sv.slice_formats if sv.sparse is not None
                       else None)
         created = False
-        new_gens = list(sv.slice_gens)
-        for s in range(num_slices):
+        moved: Dict[int, tuple] = {}  # slice -> (fragment, gen) now
+        self.stats.inc("refresh_walk_fragments", len(slices))
+        for s in slices:
             frag = self.holder.fragment(index, frame, view, s)
             staged = sv.slice_gens[s]
             if frag is None:
@@ -1622,10 +1681,10 @@ class MeshManager:
                 return restage()  # log pruned, or a container emptied
             created = created or any(e[2] for e in entries)
             pending[s] = fold_log_entries(entries)
-            new_gens[s] = (frag, gen)
+            moved[s] = (frag, gen)
 
         if not pending:
-            sv.validated_epoch = ep
+            sv.validated_epoch, sv.writes_seen = ep, seen
             return sv
         if sv.sparse is not None:
             # A sorted-array pool takes its writes as whole containers
@@ -1637,8 +1696,8 @@ class MeshManager:
             if self.deterministic_gate or len(touched) != len(pending):
                 self.stats.inc("refresh_pick_restage")
                 return restage("format" if created else None)
-            return self._refresh_sparse(key, sv, ep, new_gens, touched,
-                                        restage)
+            return self._refresh_sparse(key, sv, (ep, seen), moved,
+                                        touched, restage)
         # Cost gate: incremental scatter vs full
         # restage, decided from MEASURED costs on THIS backend —
         # the view's own last stage time vs an EWMA of recent
@@ -1717,8 +1776,9 @@ class MeshManager:
         sp = span("incremental", index=index, frame=frame, view=view)
         fresh_compile = self._apply_writes(key, sv, batches, patched)
         sp.finish()
-        sv.slice_gens = new_gens
-        sv.validated_epoch = ep
+        for s, g in moved.items():
+            sv.slice_gens[s] = g
+        sv.validated_epoch, sv.writes_seen = ep, seen
         sv.inc_count += 1
         self.stats.inc("incremental")
         self.stats.inc("refresh_pick_incremental")
@@ -1857,7 +1917,7 @@ class MeshManager:
         self._note_free_slots()
         return slot.shape
 
-    def _refresh_sparse(self, key, sv: StagedView, ep, new_gens,
+    def _refresh_sparse(self, key, sv: StagedView, stamp, moved,
                         touched: Dict[int, list], restage) -> StagedView:
         """_refresh_walk's branch for a view whose every written slice
         is sorted-array (call under _mu): each container the writes
@@ -1931,8 +1991,9 @@ class MeshManager:
         self.stats.inc("sparse_patches", rewritten - made)
         self.stats.inc("apply_in_place" if in_place else "apply_copied")
         self.stats.inc("incremental")
-        sv.slice_gens = new_gens
-        sv.validated_epoch = ep
+        for s, g in moved.items():
+            sv.slice_gens[s] = g
+        sv.validated_epoch, sv.writes_seen = stamp
         sv.inc_count += 1
         self._note_free_slots()
         return sv

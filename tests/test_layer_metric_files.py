@@ -34,17 +34,18 @@ OLD_PHASES_US = {k: PHASES_US[k] for k in ("parse", "plan", "device_exec",
                                            "readback_d2h")}
 # /debug/vars as the window began and ended.
 VARS = ({"uptime_seconds": 100.0, "process_cpu_seconds": 60.5,
-         "mesh": {"count": 10, "refresh_walks": 3, "refresh_walk_us": 9000}},
+         "mesh": {"count": 10, "refresh_walks": 3, "refresh_walk_us": 9000,
+                  "refresh_walk_fragments": 1930}},
         {"uptime_seconds": 140.0, "process_cpu_seconds": 98.5,
          "mesh": {"count": 110, "refresh_walks": 23,
-                  "refresh_walk_us": 89000}})
+                  "refresh_walk_us": 89000, "refresh_walk_fragments": 1955}})
 OLD_VARS = ({"uptime_seconds": 100.0, "mesh": {"count": 10}},
             {"uptime_seconds": 140.0, "mesh": {"count": 110}})
 
 WANT = {"route.slices_ms": 0.1772, "exec.handoff_ms": 0.0757,
         "exec.account_ms": 0.062, "mesh.lock_wait_ms": 0.006,
         "mesh.prepare_ms": 0.2982, "refresh.walk_ms": 4.0,
-        "host.cpu_cores": 0.95}
+        "host.cpu_cores": 0.95, "refresh.fragments_per_walk": 1.25}
 
 
 def ctx(phases_us, vars_pair):
@@ -79,6 +80,12 @@ def test_metric_file_gives_none_where_the_program_has_nothing(name):
 def test_no_walk_in_the_window_is_none_not_zero():
     same = dict(VARS[1])
     spec = layers.load_metric("refresh.walk_ms")
+    assert layers.evaluate(spec["value"], ctx(PHASES_US, (same, same))) is None
+
+
+def test_no_walk_in_the_window_visits_none_not_zero_fragments():
+    same = dict(VARS[1])
+    spec = layers.load_metric("refresh.fragments_per_walk")
     assert layers.evaluate(spec["value"], ctx(PHASES_US, (same, same))) is None
 
 

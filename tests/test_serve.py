@@ -225,13 +225,19 @@ class TestRefreshFastPath:
         try:
             mgr.refresh("i", "general", "standard", ns)
             assert calls == []
+            walks = mgr.stats.get("refresh_walks", 0)
+            visited = mgr.stats["refresh_walk_fragments"]
             other.set_bit(0, 2)  # unrelated index still moves the
-            #                      process-wide pair: conservative walk
+            #                      process-wide pair: the refresh passes
+            #                      the fast path, and the view's write
+            #                      record says no slice of it moved
             mgr.refresh("i", "general", "standard", ns)
-            assert calls, "process-wide counter: unrelated write re-walks"
-            calls.clear()
+            assert calls == [], "an unrelated write visits no fragment"
+            assert mgr.stats["refresh_walks"] == walks + 1
             mgr.refresh("i", "general", "standard", ns)
-            assert calls == [], "...but exactly once"
+            assert calls == [], "...and stamps the epoch: exactly once"
+            assert mgr.stats["refresh_walks"] == walks + 1
+            assert mgr.stats["refresh_walk_fragments"] == visited
         finally:
             holder.fragment = orig
 
@@ -2538,8 +2544,12 @@ class TestApplyWritesWhereThePoolLies:
         for t in texts:
             assert q(e, "i", t) == q(host, "i", t)
         st = mgr.stats.copy()
+        # The deterministic gate restages once every _DET_RESTAGE_EVERY
+        # incremental applies (how many run in the 1.5 s is the
+        # machine's): every stage after the first is one of those.
         assert (st.get("fallback_error", 0), st.get("lone_fused_failed", 0),
-                st["apply_in_place_failed"], st["stage"]) == (0, 0, 0, 1)
+                st["apply_in_place_failed"], st["stage"]) == (
+                    0, 0, 0, 1 + st["refresh_pick_restage"])
         assert st["apply_in_place"] + st["apply_copied"] \
             == st["incremental"] > 0
 
