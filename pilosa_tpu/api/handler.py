@@ -48,6 +48,7 @@ writeQueryResponse).
 from __future__ import annotations
 
 import binascii
+import contextvars
 import io
 import itertools
 import json
@@ -418,6 +419,50 @@ def _error_status(err: Exception) -> int:
     return 500
 
 
+class FrontTimes:
+    """One query request's time in the HTTP front end, on the profile's
+    clock: `http_read` from the socket adapter's entry to where the
+    request's profile would begin (`begin_ns`), `http_write` from the
+    profile's end (`end_ns`) to the body's last write. The adapter
+    (api/server.py) opens the first and closes the second; the query
+    path stamps the two instants between. Each phase opens while no
+    profile is active: the annotation alone when traced, else the
+    shared no-op. The profile's own extent is not touched."""
+
+    __slots__ = ("t0", "read", "write", "begin_ns", "end_ns")
+
+    def __init__(self):
+        self.t0 = obs.profile.monotonic_ns()
+        self.read = obs.profile.phase("http_read").start()
+        self.write = obs.profile.NOOP_PHASE
+        self.begin_ns: Optional[int] = None
+        self.end_ns: Optional[int] = None
+
+    def begin(self) -> None:
+        self.begin_ns = obs.profile.monotonic_ns()
+        self.read.stop()
+
+    def end(self, at_ns: Optional[int] = None) -> None:
+        self.end_ns = at_ns or obs.profile.monotonic_ns()
+        self.write = obs.profile.phase("http_write").start()
+
+    def close(self) -> Optional[Tuple[float, float]]:
+        """After the last write: (read µs, write µs), or None where the
+        query path stamped no instants (an explain, a malformed body)."""
+        self.read.stop()  # a second stop is a no-op
+        self.write.stop()
+        if self.begin_ns is None or self.end_ns is None:
+            return None
+        return ((self.begin_ns - self.t0) / 1e3,
+                (obs.profile.monotonic_ns() - self.end_ns) / 1e3)
+
+
+# The FrontTimes of the query request this thread serves, set by the
+# socket adapter; None for a handler called directly.
+FRONT: "contextvars.ContextVar[Optional[FrontTimes]]" = \
+    contextvars.ContextVar("pilosa_tpu_front", default=None)
+
+
 class Route(NamedTuple):
     method: str
     pattern: re.Pattern
@@ -492,6 +537,10 @@ class Handler:
         # section. The counter is monotonic across all queries.
         self.profile_sample_rate = 0
         self._profile_seq = itertools.count(1)
+        # The front end's own time of POST /index/{i}/query, as the
+        # socket adapter measures it (FrontTimes): /debug/vars http.*.
+        self.http_stats = obs.StatMap(
+            {"queries": 0, "read_us": 0.0, "write_us": 0.0})
         # Cost observatory ([obs] cost-debt-threshold, server wiring):
         # a tenant whose attributed device_us share exceeds this gets
         # the observe-only X-Pilosa-Cost-Debt header on its query
@@ -616,6 +665,12 @@ class Handler:
         r("GET", r"/internal/epochs", self._get_internal_epochs)
         r("POST", r"/internal/epochs/advance",
           self._post_internal_epochs_advance)
+
+    def note_front(self, read_us: float, write_us: float) -> None:
+        """One query request's FrontTimes, from the socket adapter."""
+        self.http_stats.inc("queries")
+        self.http_stats.inc("read_us", read_us)
+        self.http_stats.inc("write_us", write_us)
 
     def _add_route(self, method: str, pattern: str, fn: Callable):
         self._routes.append(Route(method, re.compile("^" + pattern + "$"), fn))
@@ -1132,6 +1187,29 @@ class Handler:
                 elif k.endswith("_us"):
                     secs.add(v / 1e6, {"entry": k[:-3]})
             fams += [counts, secs]
+        held = getattr(mgr, "lock_held_us", None)
+        if held is not None:
+            fam = prom.MetricFamily(
+                "pilosa_mesh_lock_held_us_total", "counter",
+                "Wall time MeshManager's staging lock was held, by "
+                "holder (a thread's outermost acquisition; waits for "
+                "the GIL while holding count).")
+            for holder, us in sorted(held().items()):
+                if holder != "total":
+                    fam.add(us, {"holder": holder})
+            fams.append(fam)
+        ex_stats = getattr(ex, "exec_stats", None)
+        if ex_stats is not None:
+            st = ex_stats.copy()
+            fams.append(prom.MetricFamily(
+                "pilosa_topn_queries_total", "counter",
+                "TopN calls the executor answered.").add(
+                    st.get("topn_queries", 0)))
+            fams.append(prom.MetricFamily(
+                "pilosa_topn_passes_total", "counter",
+                "Map-reduce passes of those TopNs: phase 1, and phase "
+                "2's exact recount where phase 1 was not taken as "
+                "exact.").add(st.get("topn_passes", 0)))
         if mgr is not None:
             try:
                 dm = mgr.device_memory()
@@ -1578,6 +1656,7 @@ class Handler:
                     }
                     mesh_snap["quarantined_plans"] = \
                         mgr.quarantined_plans()
+                    mesh_snap["mu_held_us"] = mgr.lock_held_us()
                 except Exception:  # noqa: BLE001 — debug never 500s
                     pass
             snap = dict(snap, mesh=mesh_snap)
@@ -1605,6 +1684,10 @@ class Handler:
         hc = getattr(self.executor, "host_cache_stats", None)
         if hc:
             snap = dict(snap, host_cache=dict(hc))
+        ex_stats = getattr(self.executor, "exec_stats", None)
+        if ex_stats is not None:
+            snap = dict(snap, executor=ex_stats.copy())
+        snap = dict(snap, http=self.http_stats.copy())
         # Cluster transport health: retry/transport-error/breaker
         # counters plus each peer's current breaker state, via the
         # executor's injected ClusterClient (absent under test fakes).
@@ -2296,6 +2379,9 @@ class Handler:
             cost_ctx, cost_token = obs.costs.activate(
                 clabel, float(self.profile_sample_rate) if sampled
                 else 1.0)
+        front = FRONT.get()
+        if front is not None:
+            front.begin()
         prof = ptoken = None
         if want_profile or remote_profile or sampled:
             prof = obs.profile.QueryProfile()
@@ -2354,6 +2440,9 @@ class Handler:
             if prof is not None:
                 obs.profile.deactivate(ptoken)
                 prof.finish()
+            if front is not None:
+                front.end(prof.end_ns if prof is not None else None)
+            if prof is not None:
                 obs.profile.STATS.record(prof)
             if cost_ctx is not None:
                 obs.costs.deactivate(cost_token)

@@ -8,10 +8,13 @@ http.server.ThreadingHTTPServer so every request runs on its own thread
 
 from __future__ import annotations
 
+import logging
 import threading
 import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional, Tuple
+
+from .handler import FRONT, FrontTimes
 
 
 class APIServer:
@@ -26,25 +29,46 @@ class APIServer:
         class _Request(BaseHTTPRequestHandler):
             protocol_version = "HTTP/1.1"
 
-            def log_message(self, fmt, *args):  # route through our logger
+            def log_message(self, fmt, *args):
+                # The access line, at debug: [log] level = "debug".
+                lg = api.logger
+                if lg is not None and lg.isEnabledFor(logging.DEBUG):
+                    lg.debug("http: " + fmt % args)
+
+            def log_error(self, fmt, *args):  # a malformed request
                 if api.logger is not None:
                     api.logger.info("http: " + fmt % args)
 
             def _dispatch(self):
-                parsed = urllib.parse.urlsplit(self.path)
-                params = {k: v[-1] for k, v in
-                          urllib.parse.parse_qs(parsed.query).items()}
-                length = int(self.headers.get("Content-Length") or 0)
-                body = self.rfile.read(length) if length else b""
-                resp = api.handler.handle(
-                    self.command, parsed.path.rstrip("/") or "/", params,
-                    dict(self.headers.items()), body)
-                self.send_response(resp.status)
-                for k, v in resp.headers.items():
-                    self.send_header(k, v)
-                self.send_header("Content-Length", str(len(resp.body)))
-                self.end_headers()
-                self.wfile.write(resp.body)
+                # A query's own time here (FrontTimes): opened first,
+                # closed after the last write.
+                front = token = None
+                if self.command == "POST" and \
+                        self.path.partition("?")[0].endswith("/query"):
+                    front = FrontTimes()
+                    token = FRONT.set(front)
+                try:
+                    parsed = urllib.parse.urlsplit(self.path)
+                    params = {k: v[-1] for k, v in
+                              urllib.parse.parse_qs(parsed.query).items()}
+                    length = int(self.headers.get("Content-Length") or 0)
+                    body = self.rfile.read(length) if length else b""
+                    resp = api.handler.handle(
+                        self.command, parsed.path.rstrip("/") or "/",
+                        params, dict(self.headers.items()), body)
+                    self.send_response(resp.status)
+                    for k, v in resp.headers.items():
+                        self.send_header(k, v)
+                    self.send_header("Content-Length", str(len(resp.body)))
+                    self.end_headers()
+                    self.wfile.write(resp.body)
+                finally:
+                    if front is not None:
+                        FRONT.reset(token)
+                        took = front.close()
+                        note = getattr(api.handler, "note_front", None)
+                        if took is not None and note is not None:
+                            note(*took)
 
             do_GET = do_POST = do_DELETE = do_PATCH = _dispatch
 

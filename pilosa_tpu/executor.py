@@ -339,6 +339,10 @@ class Executor:
         # (one per partition and ring of a strict read, one per slice
         # of a bounded spread) -> pilosa_route_owner_decisions_total.
         self.placement_stats = obs.StatMap()
+        # TopN queries and the map-reduce passes they made (two while
+        # phase 1 is not taken as exact): /debug/vars executor.*,
+        # /metrics pilosa_topn_{queries,passes}_total.
+        self.exec_stats = obs.StatMap({"topn_queries": 0, "topn_passes": 0})
 
     def set_spmd(self, spmd):
         """Wire the SPMD descriptor plane (rank 0 of a multi-host
@@ -2055,28 +2059,33 @@ class Executor:
 
     def _execute_top_n(self, index: str, c: Call, slices: Sequence[int],
                        opt: ExecOptions) -> List[tuple]:
-        """Two-phase TopN (executor.go:273-310)."""
-        row_ids, _ = c.uint_slice_arg("ids")
-        n, _ = c.uint_arg("n")
+        """Two-phase TopN (executor.go:273-310). `account` is the
+        executor's own share (the sorts, phase 2's clone, the trim):
+        residual, so every phase of the passes below pauses it."""
+        self.exec_stats.inc("topn_queries")
+        with obs.profile.residual("account"):
+            row_ids, _ = c.uint_slice_arg("ids")
+            n, _ = c.uint_arg("n")
 
-        exact = [False]
-        pairs = self._execute_top_n_slices(index, c, slices, opt, exact)
-        if not pairs or row_ids or opt.remote:
-            return pairs
-        if exact[0]:
-            # Phase 1 was served by the mesh path with exact global
-            # counts — the reference needs phase 2 only because its
-            # phase 1 is rank-cache-approximate; a recount would run
-            # the identical collective again.
-            return pairs
+            exact = [False]
+            pairs = self._execute_top_n_slices(index, c, slices, opt, exact)
+            if not pairs or row_ids or opt.remote:
+                return pairs
+            if exact[0]:
+                # Phase 1 was served by the mesh path with exact global
+                # counts — the reference needs phase 2 only because its
+                # phase 1 is rank-cache-approximate; a recount would run
+                # the identical collective again.
+                return pairs
 
-        # Phase 2: exact re-count of candidate ids, only at the coordinator.
-        other = c.clone()
-        other.args["ids"] = sorted(p[0] for p in pairs)
-        trimmed = self._execute_top_n_slices(index, other, slices, opt)
-        if n and n < len(trimmed):
-            trimmed = trimmed[:n]
-        return trimmed
+            # Phase 2: exact re-count of candidate ids, only at the
+            # coordinator.
+            other = c.clone()
+            other.args["ids"] = sorted(p[0] for p in pairs)
+            trimmed = self._execute_top_n_slices(index, other, slices, opt)
+            if n and n < len(trimmed):
+                trimmed = trimmed[:n]
+            return trimmed
 
     def _execute_top_n_slices(self, index: str, c: Call, slices: Sequence[int],
                               opt: ExecOptions,
@@ -2087,7 +2096,9 @@ class Executor:
         def reduce_fn(prev, v):
             return add_to_pairs(prev or [], v)
 
-        batch_fn = self._mesh_top_n_batch(index, c)
+        self.exec_stats.inc("topn_passes")
+        with obs.profile.phase("plan"):
+            batch_fn = self._mesh_top_n_batch(index, c)
         single_node = self.cluster is None or not self.cluster.nodes
         if batch_fn is not None and exact is not None and single_node:
             inner = batch_fn
@@ -2192,9 +2203,10 @@ class Executor:
 
         def batch_fn(batch_slices):
             try:
+                with obs.profile.phase("mesh_prepare"):
+                    num = self._batch_num_slices(index, batch_slices)
                 pairs = mgr.top_n(
-                    index, frame, VIEW_STANDARD, batch_slices,
-                    self._batch_num_slices(index, batch_slices),
+                    index, frame, VIEW_STANDARD, batch_slices, num,
                     0 if row_ids else n, row_ids,
                     min_threshold or MIN_THRESHOLD, src=src,
                     attr_predicate=attr_predicate,

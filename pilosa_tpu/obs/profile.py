@@ -53,7 +53,7 @@ from .metrics import Histogram
 # this order (then any ad-hoc extras) so profiles diff cleanly.
 PHASES = ("sched_wait", "parse", "plan", "route_slices", "pool_handoff",
           "mesh_prepare", "mesh_lock_wait", "view_refresh", "stage_h2d",
-          "compile", "device_exec", "readback_d2h", "host_fold",
+          "compile", "device_exec", "readback_d2h", "rank", "host_fold",
           "wal_commit", "fanout_remote", "account", "respond")
 
 BYTE_COUNTERS = ("bytes_staged", "bytes_touched_hbm", "bytes_read_back")

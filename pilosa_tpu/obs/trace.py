@@ -283,13 +283,18 @@ def wrap_ctx(fn):
     inherit the active span (and the active query profile / cost
     account). Each call copies its own Context (a Context can't be
     entered concurrently), and when no trace, profile, or cost account
-    is active the function is returned untouched."""
+    is active the function is returned untouched. A residual phase
+    open here stays this thread's to pause (profile.residual): the
+    worker's copy starts with none."""
     if CURRENT.get() is None:
         from .costs import CURRENT_ACCOUNT
         from .profile import CURRENT_PROFILE
         if CURRENT_PROFILE.get() is None and CURRENT_ACCOUNT.get() is None:
             return fn
     ctx = contextvars.copy_context()
+    from .profile import _RESIDUAL
+    if ctx.get(_RESIDUAL) is not None:
+        ctx.run(_RESIDUAL.set, None)
 
     def run(*args, **kwargs):
         return ctx.run(fn, *args, **kwargs)

@@ -446,20 +446,76 @@ def _reraise_shared(what: str, err: BaseException):
     raise RuntimeError(f"{what} failed: {err}") from err
 
 
+# Who takes MeshManager._mu, one name a site: the lines of its _HoldBook.
+MU_HOLDERS = ("refresh", "count_args", "lone_args", "sparse_args",
+              "topn_args", "memo", "release", "stage", "invalidate",
+              "evict", "infeasible", "measure", "batch", "memory",
+              "formats")
+
+
+class _HoldBook:
+    """Wall time a re-entrant lock is held, by holder, in ns on the
+    profile's clock. Only the thread that holds the lock touches it, so
+    it needs no lock of its own; the depth makes a thread's outermost
+    acquisition the one that records. The holders are fixed at
+    construction, so a reader may copy `ns` while holds go on."""
+
+    __slots__ = ("ns", "_depth", "_holder", "_t0")
+
+    def __init__(self, holders):
+        self.ns = dict.fromkeys(holders, 0)
+        self._depth = 0
+        self._holder = None
+        self._t0 = 0
+
+    def enter(self, holder: str) -> None:
+        self._depth += 1
+        if self._depth == 1:
+            self._holder = holder
+            self._t0 = profile.monotonic_ns()
+
+    def exit(self) -> None:
+        self._depth -= 1
+        if self._depth == 0:
+            self.ns[self._holder] += profile.monotonic_ns() - self._t0
+
+    def held_us(self) -> dict:
+        """µs held by each holder, and their `total`."""
+        out = {k: v / 1e3 for k, v in dict(self.ns).items()}
+        out["total"] = sum(out.values())
+        return out
+
+
 class _held:
     """`with _held(lock):` is `with lock:`, with the time the caller is
-    blocked in acquire under its profile phase `mesh_lock_wait`."""
+    blocked in acquire under its profile phase `mesh_lock_wait` (with
+    `wait_phase` False, under whatever phase the caller is in: where that
+    is not a residual one, a phase inside it would count twice). Given a
+    `_HoldBook`, the time it then holds the lock goes to `holder`'s line
+    (two clock reads a hold; never a profile phase: holds overlap the
+    phases of what runs under them)."""
 
-    __slots__ = ("_lock",)
+    __slots__ = ("_lock", "_holder", "_book", "_wait_phase")
 
-    def __init__(self, lock):
+    def __init__(self, lock, holder: str = "", book=None,
+                 wait_phase: bool = True):
         self._lock = lock
+        self._holder = holder
+        self._book = book
+        self._wait_phase = wait_phase
 
     def __enter__(self):
-        with profile.phase("mesh_lock_wait"):
+        if self._wait_phase:
+            with profile.phase("mesh_lock_wait"):
+                self._lock.acquire()
+        else:
             self._lock.acquire()
+        if self._book is not None:
+            self._book.enter(self._holder)
 
     def __exit__(self, *exc):
+        if self._book is not None:
+            self._book.exit()
         self._lock.release()
 
 
@@ -536,6 +592,9 @@ class MeshManager:
         # in _resolve_budget / the quarantine fields below).
         self._config = dict(config or {})
         self._mu = threading.RLock()
+        # Held wall time of _mu by holder (_hold; /debug/vars
+        # mesh.mu_held_us, /metrics pilosa_mesh_lock_held_us_total).
+        self._mu_book = _HoldBook(MU_HOLDERS)
         # Staged device images, LRU-ordered (move-to-end on access):
         # total HBM held by staged pools is bounded by _hbm_budget_bytes
         # and the least-recently-USED view is evicted to make room — the
@@ -824,6 +883,16 @@ class MeshManager:
             self._mesh = default_mesh()
         return self._mesh
 
+    def _hold(self, holder: str, wait_phase: bool = True) -> _held:
+        """`with self._hold("memo"):` takes _mu, its wait under
+        mesh_lock_wait (see _held for `wait_phase`) and its hold in
+        `holder`'s line of the book."""
+        return _held(self._mu, holder, self._mu_book, wait_phase)
+
+    def lock_held_us(self) -> dict:
+        """µs _mu was held, by holder (MU_HOLDERS) and in `total`."""
+        return self._mu_book.held_us()
+
     def _hbm_budget_bytes(self) -> int:
         """Resolved staged-pool HBM byte budget; <= 0 means unlimited
         (no eviction, no infeasibility gate). Resolution order:
@@ -950,7 +1019,8 @@ class MeshManager:
         pinned, and anything else is worth less than recovering the
         request. Returns how many views were dropped (0 means nothing
         left to free — the retry will likely fail too)."""
-        with self._mu:
+        # Inside the failed launch's stage_h2d or device_exec.
+        with self._hold("evict", wait_phase=False):
             dropped = 0
             for key in [k for k, v in self._views.items()
                         if v.pins == 0]:
@@ -987,7 +1057,7 @@ class MeshManager:
                     for sv in list(self._views.values())]
             if self._views_gen == gen:
                 return self._device_memory_from(snap)
-        with self._mu:
+        with self._hold("memory"):
             snap = [(sv.sharded, sv.keys_host, sv.sparse,
                      sv.sparse_keys_host, sv.sparse_cards_host)
                     for sv in self._views.values()]
@@ -1085,7 +1155,8 @@ class MeshManager:
         for frame, view in dict.fromkeys((f, v)
                                          for f, v, _r, _q in leaves):
             ck = (index, frame, view, num_slices)
-            with self._mu:
+            # The executor's routing peek, inside its `plan` phase.
+            with self._hold("infeasible", wait_phase=False):
                 hit = self._infeasible_cache.get(ck)
                 if hit is not None and hit[0] == ep:
                     self._infeasible_cache.move_to_end(ck)
@@ -1094,7 +1165,7 @@ class MeshManager:
                     continue
             bad = self._view_would_exceed(index, frame, view,
                                           num_slices, budget)
-            with self._mu:
+            with self._hold("infeasible", wait_phase=False):
                 self._infeasible_cache[ck] = (ep, bad)
                 self._infeasible_cache.move_to_end(ck)
                 while (len(self._infeasible_cache)
@@ -1128,7 +1199,7 @@ class MeshManager:
         it IS bigger than the sparse one), the pin is dropped so
         leaf/pair queries keep their sparse serving, and the caller
         degrades to the host fold. Takes _mu (reentrant)."""
-        with self._mu:
+        with self._hold("stage"):
             self._dense_pins.add(key)
             self.stats.inc("sparse_demote")
             sv = self._views.pop(key, None)
@@ -1454,7 +1525,7 @@ class MeshManager:
         full queue drops the sample (bounded lag under a device stall;
         at most maxsize device images are pinned by pending items)."""
         if self._measure_thread is None:
-            with self._mu:
+            with self._hold("measure", wait_phase=False):  # in stage_h2d
                 if self._measure_thread is None:
                     t = threading.Thread(target=self._measure_loop,
                                          name="mesh-cost-measure",
@@ -1528,7 +1599,7 @@ class MeshManager:
         # Profile phases: the wait for _mu, then `view_refresh` for the
         # epoch check and, after a write, the walk and the patch
         # (residual: a restage below is stage_h2d's).
-        with _held(self._mu), profile.residual("view_refresh"):
+        with self._hold("refresh"), profile.residual("view_refresh"):
             # Epoch pair read UNDER _mu, before any staleness
             # inspection: a write that lands mid-walk bumps the pair
             # past `ep`, so stamping `ep` after the walk can never mark
@@ -1795,7 +1866,7 @@ class MeshManager:
                     # artificially cheap. Skip the sample; the
                     # stage side keeps the gate decidable.
                     return
-                with self._mu:
+                with self._hold("measure"):
                     sv.inc_ewma_s = (
                         dt if sv.inc_ewma_s is None
                         else 0.5 * (dt + sv.inc_ewma_s))
@@ -2016,7 +2087,7 @@ class MeshManager:
 
     def invalidate(self, index: Optional[str] = None):
         """Drop staged views (all, or one index's)."""
-        with self._mu:
+        with self._hold("invalidate"):
             if index is None:
                 for key in self._views:
                     costs.LEDGER.view_evicted(key)
@@ -2070,7 +2141,7 @@ class MeshManager:
     def _memo_get(self, key: tuple):
         """Finished limb array for `key`, or None. Takes _mu (reentrant —
         callers already under it just recurse)."""
-        with self._mu:
+        with self._hold("memo"):
             hit = self._topn_memo.get(key)
             if hit is None:
                 return None
@@ -2092,7 +2163,7 @@ class MeshManager:
         the host path per query. That's deliberate: the program runs
         over immutable staged arrays, so re-running it deterministically
         fails too; memoizing the failure loses nothing."""
-        with self._mu:
+        with self._hold("memo"):
             if epoch != self._memo_epoch:
                 return
             if key in self._topn_memo:
@@ -2146,7 +2217,7 @@ class MeshManager:
         exceeds the budget."""
         if not pins:
             return
-        with _held(self._mu):
+        with self._hold("release"):
             for sv in pins:
                 if sv.pins > 0:
                     sv.pins -= 1
@@ -2174,7 +2245,7 @@ class MeshManager:
         caller without `pins` must let no refresh run between this
         call and its launch (the SPMD descriptor plane: one descriptor
         at a time, writes among them)."""
-        with _held(self._mu):
+        with self._hold("count_args"):
             self._use_epoch += 1
             out = self._stage_leaves(index, leaves, num_slices, pins=pins)
             if out is None:
@@ -2747,7 +2818,7 @@ class MeshManager:
 
     def _ensure_batch_thread(self):
         if self._batch_thread is None:
-            with self._mu:
+            with self._hold("batch"):
                 if self._batch_thread is None:
                     t = threading.Thread(target=self._batch_loop,
                                          name="mesh-count-batch", daemon=True)
@@ -3221,7 +3292,7 @@ class MeshManager:
         transient fault straight into quarantine."""
         pins: list = []
         try:
-            with _held(self._mu):
+            with self._hold("lone_args"):
                 self._use_epoch += 1
                 out = self._stage_leaves_host(index, leaves, num_slices,
                                               pins=pins)
@@ -3488,7 +3559,7 @@ class MeshManager:
         jobs: list = []
         host_total = 0
         try:
-            with self._mu:
+            with self._hold("sparse_args"):
                 self._use_epoch += 1
                 staged: Dict[Tuple[str, str], StagedView] = {}
                 svs = []
@@ -3749,7 +3820,7 @@ class MeshManager:
         _row_counts_call, shared with the SPMD descriptor plane
         (spmd.SpmdServer) so staging/mask semantics cannot diverge.
         Takes _mu. `pins` collects an eviction pin (see _count_args)."""
-        with self._mu:
+        with self._hold("topn_args"):
             self._use_epoch += 1
             sv = self.refresh(index, frame, view, num_slices)
             if sv is None:
@@ -3821,7 +3892,7 @@ class MeshManager:
                     f"__row_counts__:{padded}",
                     lambda: fn(sharded, dev_mask), kind="row_counts")
 
-            out = self._single_flight(key, compute)
+            out = self._launch_shared(key, compute)
             self._memo_put(key, out, (sharded.words, dev_mask), epoch)
             return out
 
@@ -3860,6 +3931,18 @@ class MeshManager:
                 self._inflight.pop(key, None)
             pending[0].set()
 
+    def _launch_shared(self, key: tuple, compute):
+        """_single_flight, and with a profile active the launch and the
+        wait until its result is ready as the phase device_exec, as the
+        Count leg brackets it; unprofiled, dispatch stays async."""
+        prof = profile.current()
+        if prof is None:
+            return self._single_flight(key, compute)
+        with prof.phase("device_exec"):
+            out = self._single_flight(key, compute)
+            out.block_until_ready()
+        return out
+
     def row_counts(self, index: str, frame: str, view: str,
                    slices: Sequence[int], num_slices: int):
         """Exact per-row counts over the requested slices: one masked
@@ -3876,7 +3959,9 @@ class MeshManager:
             row_ids, call = out
             if call is None:
                 return row_ids, np.zeros(0, dtype=np.int64)
-            limbs = np.asarray(call())
+            dev = call()
+            with profile.phase("readback_d2h"):
+                limbs = np.asarray(dev)
         except DeviceResourceError:
             # Ladder exhausted (counted where it failed); degrade to
             # the host fold by answering "not staged".
@@ -3888,7 +3973,8 @@ class MeshManager:
             raise
         finally:
             self._release_pins(pins)
-        counts = combine_limbs(limbs, len(row_ids))
+        with profile.phase("rank"):
+            counts = combine_limbs(limbs, len(row_ids))
         self.stats.inc("topn")
         self.stats.inc("query_us", int((time.monotonic() - t0) * 1e6))
         return row_ids, counts
@@ -3933,13 +4019,15 @@ class MeshManager:
         if limbs is None:
             return []  # staged view has no rows
         r = len(all_rows)
-        full = combine_limbs(limbs, r)
-        inter = combine_limbs(limbs, r, start=padded)
-        src_count = int(combine_limbs(limbs, 1, start=2 * padded)[0])
+        with profile.phase("rank"):
+            full = combine_limbs(limbs, r)
+            inter = combine_limbs(limbs, r, start=padded)
+            src_count = int(combine_limbs(limbs, 1, start=2 * padded)[0])
         self.stats.inc("topn")
         self.stats.inc("query_us", int((time.monotonic() - t0) * 1e6))
-        return tanimoto_rank(all_rows, full, inter, src_count, n,
-                             tanimoto, row_ids, attr_predicate)
+        with profile.phase("rank"):
+            return tanimoto_rank(all_rows, full, inter, src_count, n,
+                                 tanimoto, row_ids, attr_predicate)
 
     def _src_counts_args(self, index: str, frame: str, view: str, src,
                          slices: Sequence[int], num_slices: int,
@@ -3957,7 +4045,7 @@ class MeshManager:
         (_stage_leaves); without it, as for tanimoto and the SPMD
         plane, a sparse view is demoted."""
         src_shape, src_leaves = src
-        with self._mu:
+        with self._hold("topn_args"):
             self._use_epoch += 1
             sv = self.refresh(index, frame, view, num_slices)
             if sv is None:
@@ -4073,11 +4161,12 @@ class MeshManager:
             def compute():
                 return self._guarded_exec(sig, launch, kind=kind)
 
-            out = self._single_flight(key, compute)
+            out = self._launch_shared(key, compute)
             self._memo_put(key, out,
                            ranked + (dev_mask,) + _pool_refs(words_t)
                            + tuple(idx_t), epoch)
-        return sv.row_ids, padded, np.asarray(out)
+        with profile.phase("readback_d2h"):
+            return sv.row_ids, padded, np.asarray(out)
 
     def row_counts_src(self, index: str, frame: str, view: str,
                        src_shape, src_leaves, slices: Sequence[int],
@@ -4109,7 +4198,8 @@ class MeshManager:
         row_ids, _padded, limbs = out
         if limbs is None:
             return row_ids, np.zeros(0, dtype=np.int64)
-        counts = combine_limbs(limbs, len(row_ids))
+        with profile.phase("rank"):
+            counts = combine_limbs(limbs, len(row_ids))
         self.stats.inc("topn")
         self.stats.inc("query_us", int((time.monotonic() - t0) * 1e6))
         return row_ids, counts
@@ -4125,7 +4215,7 @@ class MeshManager:
         same shard must skip the collective together rather than enter
         it with mismatched programs."""
         parts = []
-        with self._mu:
+        with self._hold("formats"):
             for frame, view in sorted(frames_views):
                 sv = self._views.get((index, frame, view))
                 if sv is None:
@@ -4190,21 +4280,28 @@ class MeshManager:
         incremental write scatters keeping the image warm, a TopN after
         writes pays no re-upload either — the two costs the rank cache
         amortizes on the host both vanish.
+
+        Profile phases: the locks, the view refresh, staging, compile,
+        the launch, the readback and the ranking have their own;
+        `mesh_prepare` is the rest of this call, as in count().
         """
-        if tanimoto_threshold > 0:
-            if src is None:
+        with profile.residual("mesh_prepare"):
+            if tanimoto_threshold > 0:
+                if src is None:
+                    return None
+                return self._top_n_tanimoto(
+                    index, frame, view, src, slices, num_slices,
+                    0 if row_ids else n, tanimoto_threshold, row_ids,
+                    attr_predicate)
+            if src is not None:
+                out = self.row_counts_src(index, frame, view, src[0],
+                                          src[1], slices, num_slices)
+            else:
+                out = self.row_counts(index, frame, view, slices,
+                                      num_slices)
+            if out is None:
                 return None
-            return self._top_n_tanimoto(index, frame, view, src, slices,
-                                        num_slices, 0 if row_ids else n,
-                                        tanimoto_threshold, row_ids,
-                                        attr_predicate)
-        if src is not None:
-            out = self.row_counts_src(index, frame, view, src[0], src[1],
-                                      slices, num_slices)
-        else:
-            out = self.row_counts(index, frame, view, slices, num_slices)
-        if out is None:
-            return None
-        all_rows, counts = out
-        return rank_pairs(all_rows, counts, n, row_ids, min_threshold,
-                          attr_predicate)
+            all_rows, counts = out
+            with profile.phase("rank"):
+                return rank_pairs(all_rows, counts, n, row_ids,
+                                  min_threshold, attr_predicate)

@@ -65,7 +65,7 @@ import numpy as np
 
 from .. import fault
 from ..obs import Histogram, StatMap
-from ..obs import costs
+from ..obs import costs, profile
 from ..obs.health import HEALTH
 from ..obs.metrics import TIER_BYTES
 from .broadcast import Broadcaster
@@ -305,27 +305,32 @@ class SpmdServer:
             if limbs is None:
                 return []  # staged view has no rows
             r = len(all_rows)
-            full = combine_limbs(limbs, r)
-            inter = combine_limbs(limbs, r, start=padded)
-            src_count = int(combine_limbs(limbs, 1, start=2 * padded)[0])
-            return tanimoto_rank(all_rows, full, inter, src_count,
-                                 0 if row_ids else n, tanimoto_threshold,
-                                 row_ids, attr_predicate)
+            with profile.phase("rank"):
+                full = combine_limbs(limbs, r)
+                inter = combine_limbs(limbs, r, start=padded)
+                src_count = int(combine_limbs(limbs, 1,
+                                              start=2 * padded)[0])
+                return tanimoto_rank(all_rows, full, inter, src_count,
+                                     0 if row_ids else n,
+                                     tanimoto_threshold, row_ids,
+                                     attr_predicate)
         if src is not None:
             out = self._rcsrc("rcs", index, frame, view, src, slices,
                               num_slices)
             if out is None:
                 return None
             all_rows, _padded, limbs = out
-            counts = (np.zeros(0, dtype=np.int64) if limbs is None
-                      else combine_limbs(limbs, len(all_rows)))
+            with profile.phase("rank"):
+                counts = (np.zeros(0, dtype=np.int64) if limbs is None
+                          else combine_limbs(limbs, len(all_rows)))
         else:
             out = self.row_counts(index, frame, view, slices, num_slices)
             if out is None:
                 return None
             all_rows, counts = out
-        return rank_pairs(all_rows, counts, n, row_ids, min_threshold,
-                          attr_predicate)
+        with profile.phase("rank"):
+            return rank_pairs(all_rows, counts, n, row_ids, min_threshold,
+                              attr_predicate)
 
     def _rcsrc(self, kind: str, index: str, frame: str, view: str,
                src, slices: Sequence[int], num_slices: int):

@@ -103,3 +103,59 @@ def test_every_phase_a_metric_file_reads_is_one_the_program_documents():
         for ph in _profile_phases(spec["value"]):
             assert ph in profile.PHASES, (m["name"], ph)
     assert set(WANT) <= {m["name"] for m in BENCHMARK["per_layer"]}
+
+
+# -- the TopN leg, its passes, the front end's own time, who holds _mu ---------------
+
+# ?profile=true of one mesh-served TopN(frame, n=3) after a write, as the
+# program gave it (CPU mesh, 16 slices), and of the same TopN on a program
+# without the leg's phases: its lock waits and refresh were all it had.
+TOPN_US = {"parse": 3.9, "plan": 34.0, "route_slices": 61.4,
+           "pool_handoff": 190.5, "mesh_prepare": 214.6,
+           "mesh_lock_wait": 9.9, "view_refresh": 1349.6,
+           "device_exec": 1345.3, "readback_d2h": 86.9, "rank": 149.3,
+           "account": 105.2, "respond": 23.1}
+OLD_TOPN_US = {k: TOPN_US[k] for k in ("parse", "plan", "route_slices",
+                                       "pool_handoff", "mesh_lock_wait",
+                                       "view_refresh", "respond")}
+HOLDERS = ({"total": 5000.0, "topn_args": 3000.0, "memo": 2000.0},
+           {"total": 41000.0, "topn_args": 27000.0, "memo": 14000.0})
+TOPN_VARS = tuple(
+    {"executor": {"topn_queries": q, "topn_passes": 2 * q},
+     "http": {"queries": n, "read_us": r, "write_us": w},
+     "mesh": {"count": 10, "topn": 2 * q, "mu_held_us": held}}
+    for q, n, r, w, held in ((100, 120, 6000.0, 9000.0, HOLDERS[0]),
+                             (400, 420, 21000.0, 54000.0, HOLDERS[1])))
+OLD_TOPN_VARS = ({"mesh": {"count": 10, "topn": 200}},
+                 {"mesh": {"count": 10, "topn": 800}})
+TOPN_WANT = {"topn.prepare_ms": 1.5741, "topn.wait_ms": 1.4322,
+             "topn.rank_ms": 0.1493, "topn.passes_per_query": 2.0,
+             "http.server_ms": 0.2, "mesh.lock_held_ms": 0.06}
+# What each reads on the parent's pair: it has the lock waits and the
+# refresh, and none of the rest.
+TOPN_PARENT = dict.fromkeys(TOPN_WANT, None)
+TOPN_PARENT["topn.prepare_ms"] = 1.3595
+
+
+@pytest.mark.parametrize("name", sorted(TOPN_WANT))
+def test_topn_and_front_end_metric_files_read_the_program(name):
+    spec = layers.load_metric(name)
+    got = layers.evaluate(spec["value"], ctx(TOPN_US, TOPN_VARS))
+    assert got == pytest.approx(TOPN_WANT[name])
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
+    assert (entry["better"], entry["source"]) == \
+        (spec["better"], spec["source"]) == ("lower", spec["source"])
+    assert "startrace-500m.lone1" not in entry["workloads"]
+    assert {"topn-1b.lone1", "topn-ingest-1b.lone1"} <= \
+        set(entry["workloads"])
+    old = layers.evaluate(spec["value"], ctx(OLD_TOPN_US, OLD_TOPN_VARS))
+    assert old == pytest.approx(TOPN_PARENT[name]) \
+        if TOPN_PARENT[name] is not None else old is None
+
+
+def test_no_query_in_the_window_is_none_not_zero():
+    same = (TOPN_VARS[1], TOPN_VARS[1])
+    for name in ("topn.passes_per_query", "http.server_ms",
+                 "mesh.lock_held_ms"):
+        spec = layers.load_metric(name)
+        assert layers.evaluate(spec["value"], ctx(TOPN_US, same)) is None
