@@ -1211,6 +1211,15 @@ class Handler:
                 "2's exact recount where phase 1 was not taken as "
                 "exact.").add(st.get("topn_passes", 0)))
         if mgr is not None:
+            st = mgr.stats.copy()
+            rank = prom.MetricFamily(
+                "pilosa_topn_rank_total", "counter",
+                "Ranking passes of the mesh TopN path by where the "
+                "ranking ran: over the row counts on the device, k "
+                "rows read back, or every row read back to the host.")
+            for where in ("device", "host"):
+                rank.add(st.get(f"topn_rank_{where}", 0), {"where": where})
+            fams.append(rank)
             try:
                 dm = mgr.device_memory()
             except Exception:  # noqa: BLE001 — telemetry never fails scrape

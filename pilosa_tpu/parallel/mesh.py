@@ -2096,6 +2096,74 @@ def compile_serve_row_counts(mesh: Mesh, num_rows: int):
     return row_counts
 
 
+def compile_serve_topn_rank(mesh: Mesh, num_rows: int, k: int):
+    """Jit TopN's phase 1 over a (2, num_rows) limb array where it lies
+    (a row-count program's output, still on the device): the rows
+    serve.rank_pairs keeps for TopN(n) leave the device as k (row
+    index, count) pairs, not num_rows limbs.
+
+    counts = (hi << 16) + lo in int32, exact while every count is below
+    2^31 (the caller's bound: serve.RANK_EXACT_SLICES); rows at or past
+    `live` and counts below `floor` (>= 1) drop out. kth, the n-th
+    largest kept count, is found bit by bit from the top: the largest
+    v with at least n counts >= v, one count over the rows a bit (31
+    passes of a compare and a sum; a v5e runs the whole program in ~1
+    ms at 2^20 rows where two lax.top_k took ~3: tools/rank_cut.py,
+    PERF.md). Kept: every row above kth, then the rows at kth in index
+    order (a cumulative sum over them) until n are taken: index order
+    is row-id order, so a tie at the n-th place goes to the lower id,
+    and nothing rests on how a device orders equal values. The kept
+    rows, at most n, are compacted by a binary search of the running
+    count of kept rows: no scatter over num_rows.
+
+    Returns fn(limbs (2, num_rows) int32, live, n, floor: int32 scalars,
+    1 <= n <= k <= num_rows) -> (2, k) int32 [row index, count] by
+    ascending index, padded with index -1, replicated over `mesh` as
+    the row-count programs leave their limbs."""
+
+    @partial(jax.jit, out_shardings=NamedSharding(mesh, P()))
+    def topn_rank(limbs, live, n, floor):
+        rows = jnp.arange(num_rows, dtype=jnp.int32)
+        counts = (limbs[1] << 16) + limbs[0]
+        c = jnp.where((rows < live) & (counts >= floor), counts, -1)
+
+        def bit(i, v):
+            up = v | (jnp.int32(1) << (30 - i))
+            return jnp.where(jnp.sum(c >= up, dtype=jnp.int32) >= n, up, v)
+
+        # With fewer than n rows kept, kth is -1: every kept row is
+        # above it and none is at it.
+        kth = jnp.where(jnp.sum(c >= 0, dtype=jnp.int32) >= n,
+                        lax.fori_loop(0, 31, bit, jnp.int32(0)), -1)
+        above = c > kth
+        at = (c == kth) & (c >= 0)
+        room = n - jnp.sum(above, dtype=jnp.int32)
+        keep = above | (at & (jnp.cumsum(at, dtype=jnp.int32) <= room))
+        seen = jnp.cumsum(keep, dtype=jnp.int32)
+        j = jnp.arange(k, dtype=jnp.int32)
+        idx = jnp.searchsorted(seen, j + 1).astype(jnp.int32)
+        idx = jnp.where(j < seen[-1], idx, -1)
+        return jnp.stack([idx, jnp.where(idx >= 0,
+                                         c[jnp.maximum(idx, 0)], 0)])
+
+    return topn_rank
+
+
+def compile_serve_topn_gather(mesh: Mesh):
+    """Jit TopN's phase 2 over a (2, R) limb array where it lies: the
+    columns at `pos` ((k,) int32, the candidates' row indices, padded
+    with any index in range) as a (2, k) limb array, replicated over
+    `mesh`, so k candidates leave the device, not R rows (combine_limbs
+    recombines them on the host). One compile for each (R, k) the
+    caller keys it by."""
+
+    @partial(jax.jit, out_shardings=NamedSharding(mesh, P()))
+    def topn_gather(limbs, pos):
+        return jnp.take(limbs, pos, axis=1)
+
+    return topn_gather
+
+
 def pack_mutation_batches(per_slice, num_slices: int, capacity: int):
     """Stack per-slice plan_slice_mutations outputs into padded (S, B)
     batch arrays for compile_serve_apply_writes, with the width of the

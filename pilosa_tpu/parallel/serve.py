@@ -40,6 +40,7 @@ import json
 import queue
 import threading
 import time
+import weakref
 from collections import OrderedDict
 from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -67,6 +68,8 @@ from .mesh import (
     coarse_row_starts,
     combine_count,
     compile_serve_count_sparse_pair,
+    compile_serve_topn_gather,
+    compile_serve_topn_rank,
     global_row_ids,
     pick_slice_formats,
     slice_format_stats,
@@ -357,6 +360,44 @@ def combine_limbs(limbs: np.ndarray, n: int, start: int = 0) -> np.ndarray:
     return (hi << 16) + lo
 
 
+RANK_DEVICE_MIN_ROWS = 1 << 18
+"""The padded row count from which TopN ranks the limbs where they lie
+(MeshManager._rank_on_device) instead of reading all of them back and
+ranking on the host (combine_limbs + rank_pairs). On a TPU v5e
+(tools/rank_cut.py, n = 5, medians of 50) a device pass costs about
+one launch and one small readback whatever the rows: phase 1 1.53,
+1.54, 1.60, 1.55, 1.50 and 2.18 ms at 2^8, 2^12, 2^16, 2^17, 2^18 and
+2^20 rows, phase 2 1.30-1.57. The host pass grows with the rows: 1.46,
+1.45, 2.18, 2.78, 4.36 and 11.19 ms where the limbs are read back (a
+memo miss), 0.03, 0.05, 0.34, 0.68, 1.36 and 6.18 where a memo hit's
+host copy is kept. From 2^18 rows the device form is within 0.14 ms of
+a memo hit's host form and a third of a miss's; at 2^17 and below a
+hit, the commoner pass, is cheaper on the host. Star Trace's `stargazer`
+(2^20) ranks on the device; `topn-1b`'s 256 rows and `language`'s 32
+on the host."""
+
+RANK_EXACT_SLICES = 1 << 11
+"""Phase 1 on the device recombines the limbs in int32, exact while a
+row's count, at most 2^20 a slice, stays below 2^31: under 2,048
+slices. A view of more ranks on the host, in int64."""
+
+
+def rank_k(n: int) -> int:
+    """The device rank's output width for n rows: n rounded up to a
+    power of two, at least 8, so a new n compiles at a doubling."""
+    return max(8, 1 << (n - 1).bit_length())
+
+
+def id_positions(all_rows, row_ids):
+    """TopN phase 2's candidates in the ascending `all_rows`: (the ids
+    sorted as uint64, their searchsorted positions, which are there)."""
+    want = np.asarray(sorted(row_ids), dtype=np.uint64)
+    i = np.searchsorted(all_rows, want)
+    ok = (i < len(all_rows))
+    ok &= all_rows[np.minimum(i, max(len(all_rows) - 1, 0))] == want
+    return want, i, ok
+
+
 def rank_pairs(all_rows, counts, n: int, row_ids, min_threshold: int,
                attr_predicate=None):
     """Host-side TopN semantics over exact per-row totals: candidate
@@ -367,10 +408,7 @@ def rank_pairs(all_rows, counts, n: int, row_ids, min_threshold: int,
     if len(all_rows) == 0:
         return []
     if row_ids:
-        want = np.asarray(sorted(row_ids), dtype=np.uint64)
-        i = np.searchsorted(all_rows, want)
-        ok = (i < len(all_rows))
-        ok &= all_rows[np.minimum(i, max(len(all_rows) - 1, 0))] == want
+        want, i, ok = id_positions(all_rows, row_ids)
         pairs = [(int(r), int(counts[j]))
                  for r, j in zip(want[ok], i[ok])
                  if counts[j] >= max(min_threshold, 1)
@@ -701,6 +739,11 @@ class MeshManager:
         # (_refresh_sparse picks), and the sparse ranked src programs.
         self._sparse_write_fns: dict = {}
         self._rowcount_src_sparse_fns: Dict[tuple, object] = {}
+        # TopN's rank and gather over limbs on the device, by
+        # (program, padded rows, k) (_rank_on_device).
+        self._rank_fns: Dict[tuple, object] = {}
+        # What those programs read back, by limb array (_readbacks).
+        self._ranked: Dict[int, tuple] = {}
         # EWMA (seconds) of measured incremental-apply cost — the other
         # side of refresh()'s cost gate (vs StagedView.last_stage_s) —
         # and the batch/pool shapes already compiled (novel shapes pay
@@ -848,6 +891,10 @@ class MeshManager:
             "sparse_patch_refused_value_cap": 0,
             "sparse_free_slots_min": 0,
             "memo_hit": 0, "memo_store": 0, "memo_size": 0,
+            # TopN ranking passes by where the ranking ran: over the
+            # limbs on the device (_rank_on_device) or read back whole
+            # and ranked on the host (rank_pairs, tanimoto_rank).
+            "topn_rank_device": 0, "topn_rank_host": 0,
             "idx_cache_hit": 0, "idx_cache_miss": 0,
             "mask_cache_hit": 0, "mask_cache_miss": 0,
             "routed_host": 0, "shared_batch": 0, "fetch_threads": 0,
@@ -3943,25 +3990,35 @@ class MeshManager:
             out.block_until_ready()
         return out
 
-    def row_counts(self, index: str, frame: str, view: str,
-                   slices: Sequence[int], num_slices: int):
-        """Exact per-row counts over the requested slices: one masked
-        popcount + segment-sum + psum. Returns (row_ids, counts int64)
-        or None. num_rows pads to a power of two so growing row spaces
-        recompile on doubling only."""
+    def _row_limbs(self, index: str, frame: str, view: str,
+                   slices: Sequence[int], num_slices: int, src, finish):
+        """One view's (2, padded) row-count limbs, launched or taken
+        from the memo, handed still on the device to finish(row_ids,
+        limbs) while the view stays pinned (limbs None for a view with
+        no rows). With `src` = (shape, leaves) the counts are |row ∩
+        src| (row_counts_src). Returns finish's result, or None on any
+        fallback: not staged, or the device out of memory."""
         t0 = time.monotonic()
         pins: list = []
         try:
-            out = self._row_counts_call(index, frame, view, slices,
-                                        num_slices, pins=pins)
-            if out is None:
-                return None
-            row_ids, call = out
-            if call is None:
-                return row_ids, np.zeros(0, dtype=np.int64)
-            dev = call()
-            with profile.phase("readback_d2h"):
-                limbs = np.asarray(dev)
+            if src is None:
+                out = self._row_counts_call(index, frame, view, slices,
+                                            num_slices, pins=pins)
+                if out is None:
+                    return None
+                row_ids, call = out
+                dev = None if call is None else call()
+            else:
+                out = self._src_counts_limbs(
+                    "rcs", self._rowcount_src_fns,
+                    compile_serve_row_counts_src, index, frame, view, src,
+                    slices, num_slices, pins=pins)
+                if out is None:
+                    return None
+                row_ids, _padded, dev = out
+            result = finish(row_ids, dev)
+            if dev is None:
+                return result  # a view with no rows: nothing ran
         except DeviceResourceError:
             # Ladder exhausted (counted where it failed); degrade to
             # the host fold by answering "not staged".
@@ -3973,11 +4030,29 @@ class MeshManager:
             raise
         finally:
             self._release_pins(pins)
-        with profile.phase("rank"):
-            counts = combine_limbs(limbs, len(row_ids))
         self.stats.inc("topn")
         self.stats.inc("query_us", int((time.monotonic() - t0) * 1e6))
-        return row_ids, counts
+        return result
+
+    @staticmethod
+    def _host_counts(row_ids, limbs):
+        """_row_limbs' finish that reads every row back: (row_ids,
+        counts int64), combined on the host."""
+        if limbs is None:
+            return row_ids, np.zeros(0, dtype=np.int64)
+        with profile.phase("readback_d2h"):
+            limbs = np.asarray(limbs)
+        with profile.phase("rank"):
+            return row_ids, combine_limbs(limbs, len(row_ids))
+
+    def row_counts(self, index: str, frame: str, view: str,
+                   slices: Sequence[int], num_slices: int):
+        """Exact per-row counts over the requested slices: one masked
+        popcount + segment-sum + psum. Returns (row_ids, counts int64)
+        or None. num_rows pads to a power of two so growing row spaces
+        recompile on doubling only."""
+        return self._row_limbs(index, frame, view, slices, num_slices,
+                               None, self._host_counts)
 
     def _top_n_tanimoto(self, index: str, frame: str, view: str, src,
                         slices: Sequence[int], num_slices: int, n: int,
@@ -4004,6 +4079,9 @@ class MeshManager:
                 "tan", self._tanimoto_fns,
                 compile_serve_row_counts_tanimoto,
                 index, frame, view, src, slices, num_slices, pins=pins)
+            if out is not None and out[2] is not None:
+                with profile.phase("readback_d2h"):
+                    out = out[:2] + (np.asarray(out[2]),)
         except DeviceResourceError:
             return None
         except Exception as e:  # noqa: BLE001 — classify fetch errors
@@ -4025,6 +4103,7 @@ class MeshManager:
             src_count = int(combine_limbs(limbs, 1, start=2 * padded)[0])
         self.stats.inc("topn")
         self.stats.inc("query_us", int((time.monotonic() - t0) * 1e6))
+        self.stats.inc("topn_rank_host")
         with profile.phase("rank"):
             return tanimoto_rank(all_rows, full, inter, src_count, n,
                                  tanimoto, row_ids, attr_predicate)
@@ -4099,9 +4178,9 @@ class MeshManager:
                           pins=None):
         """Shared resolve+execute for the src-tree row-count programs
         (row_counts_src and the fused tanimoto): snapshot under _mu,
-        compile outside it, memo/single-flight, one readback. Returns
-        (row_ids, padded, limbs np.ndarray), (row_ids, 0, None) for a
-        rowless view, or None on any fallback.
+        compile outside it, memo/single-flight. Returns (row_ids,
+        padded, limbs as a device array, not read back), (row_ids, 0,
+        None) for a rowless view, or None on any fallback.
 
         The consistency contract lives HERE, once: the memo/in-flight
         key carries every src leaf's words identity (ADVICE r2 medium —
@@ -4165,8 +4244,7 @@ class MeshManager:
             self._memo_put(key, out,
                            ranked + (dev_mask,) + _pool_refs(words_t)
                            + tuple(idx_t), epoch)
-        with profile.phase("readback_d2h"):
-            return sv.row_ids, padded, np.asarray(out)
+        return sv.row_ids, padded, out
 
     def row_counts_src(self, index: str, frame: str, view: str,
                        src_shape, src_leaves, slices: Sequence[int],
@@ -4176,33 +4254,8 @@ class MeshManager:
         fused pass (the device form of the reference's per-row
         src.intersection_count loop, fragment.go:564-608). Returns
         (row_ids, counts int64) or None."""
-        t0 = time.monotonic()
-        pins: list = []
-        try:
-            out = self._src_counts_limbs(
-                "rcs", self._rowcount_src_fns,
-                compile_serve_row_counts_src,
-                index, frame, view, (src_shape, src_leaves), slices,
-                num_slices, pins=pins)
-        except DeviceResourceError:
-            return None
-        except Exception as e:  # noqa: BLE001 — classify fetch errors
-            if _is_resource_exhausted(e):
-                self.stats.inc("fallback_oom")
-                return None
-            raise
-        finally:
-            self._release_pins(pins)
-        if out is None:
-            return None
-        row_ids, _padded, limbs = out
-        if limbs is None:
-            return row_ids, np.zeros(0, dtype=np.int64)
-        with profile.phase("rank"):
-            counts = combine_limbs(limbs, len(row_ids))
-        self.stats.inc("topn")
-        self.stats.inc("query_us", int((time.monotonic() - t0) * 1e6))
-        return row_ids, counts
+        return self._row_limbs(index, frame, view, slices, num_slices,
+                               (src_shape, src_leaves), self._host_counts)
 
     def staged_format_blob(self, index: str, frames_views) -> bytes:
         """Deterministic bytes describing the PER-SHARD sparse/dense
@@ -4281,6 +4334,14 @@ class MeshManager:
         writes pays no re-upload either — the two costs the rank cache
         amortizes on the host both vanish.
 
+        Where the ranking runs is a property of the ranked view's
+        shape: from RANK_DEVICE_MIN_ROWS padded rows up, phase 1 (`n`
+        rows, no `row_ids`) and phase 2 (`row_ids`) run where the limbs
+        lie and k rows leave the device (_rank_on_device); below it,
+        and for every row (`n` 0), an attribute filter or tanimoto,
+        the limbs are read back whole and ranked on the host. Counted
+        a pass in `topn_rank_device` / `topn_rank_host`.
+
         Profile phases: the locks, the view refresh, staging, compile,
         the launch, the readback and the ranking have their own;
         `mesh_prepare` is the rest of this call, as in count().
@@ -4293,15 +4354,114 @@ class MeshManager:
                     index, frame, view, src, slices, num_slices,
                     0 if row_ids else n, tanimoto_threshold, row_ids,
                     attr_predicate)
-            if src is not None:
-                out = self.row_counts_src(index, frame, view, src[0],
-                                          src[1], slices, num_slices)
-            else:
-                out = self.row_counts(index, frame, view, slices,
-                                      num_slices)
-            if out is None:
-                return None
+
+            def finish(all_rows, limbs):
+                if limbs is not None and self._ranks_on_device(
+                        limbs.shape[1], num_slices, n, row_ids,
+                        attr_predicate):
+                    self.stats.inc("topn_rank_device")
+                    return self._rank_on_device(all_rows, limbs, n,
+                                                row_ids, min_threshold)
+                return self._host_counts(all_rows, limbs)
+
+            out = self._row_limbs(index, frame, view, slices, num_slices,
+                                  src, finish)
+            if out is None or isinstance(out, list):
+                return out  # a fallback, or the pairs the device ranked
+            self.stats.inc("topn_rank_host")
             all_rows, counts = out
             with profile.phase("rank"):
                 return rank_pairs(all_rows, counts, n, row_ids,
                                   min_threshold, attr_predicate)
+
+    @staticmethod
+    def _ranks_on_device(padded: int, num_slices: int, n: int, row_ids,
+                         attr_predicate) -> bool:
+        """Whether top_n ranks a view of `padded` rows on the device:
+        from RANK_DEVICE_MIN_ROWS rows up, for phase 2 (`row_ids`) or
+        for phase 1's first `n` rows where the int32 counts are exact
+        (RANK_EXACT_SLICES); never with an attribute filter, whose walk
+        past n reads the host's attribute store."""
+        if padded < RANK_DEVICE_MIN_ROWS or attr_predicate is not None:
+            return False
+        if row_ids:
+            return True
+        return 0 < n and rank_k(n) <= padded \
+            and num_slices < RANK_EXACT_SLICES
+
+    def _rank_on_device(self, all_rows, limbs, n: int, row_ids,
+                        min_threshold: int) -> List[Tuple[int, int]]:
+        """rank_pairs' answer, ranked where the (2, padded) limbs lie.
+        Phase 1: compile_serve_topn_rank keeps the first n rows and k pairs
+        come back. Phase 2: the candidates' positions in `all_rows`
+        (ascending) are found on the host, compile_serve_topn_gather
+        takes those columns, and combine_limbs recombines them. Either
+        way rank_pairs sorts and trims the few rows that came back, so
+        the one host form decides the order.
+
+        What came back stays with the array while it lives
+        (_readbacks): a memo hit asks phase 1 the same again, and phase
+        2's candidates are phase 1's rows, whose exact counts came back
+        with them, so neither goes to the device again."""
+        reads = self._readbacks(limbs)
+        if row_ids:
+            _want, i, ok = id_positions(all_rows, row_ids)
+            pos = np.unique(i[ok]).astype(np.int32)
+            known = {}
+            for got in list(reads.values()):
+                known.update((j, c) for j, c in zip(got[0].tolist(),
+                                                    got[1].tolist())
+                             if j >= 0)
+            if all(p in known for p in pos.tolist()):
+                counts = np.asarray([known[p] for p in pos.tolist()],
+                                    dtype=np.int64)
+            else:
+                padded, k = limbs.shape[1], rank_k(len(pos))
+                fn = self._get_or_compile(
+                    self._rank_fns, ("gather", padded, k),
+                    lambda: compile_serve_topn_gather(self.mesh),
+                    entry="topn_gather")
+                counts = combine_limbs(self._run_rank(
+                    fn, limbs, np.pad(pos, (0, k - len(pos)))), len(pos))
+            with profile.phase("rank"):
+                return rank_pairs(all_rows[pos], counts, n, row_ids,
+                                  min_threshold)
+        key = (len(all_rows), n, max(min_threshold, 1))
+        got = reads.get(key)
+        if got is None:
+            padded, k = limbs.shape[1], rank_k(n)
+            fn = self._get_or_compile(
+                self._rank_fns, ("rank", padded, k),
+                lambda: compile_serve_topn_rank(self.mesh, padded, k),
+                entry="topn_rank")
+            got = reads[key] = self._run_rank(fn, limbs,
+                                              *map(np.int32, key))
+        keep = got[0] >= 0
+        with profile.phase("rank"):
+            return rank_pairs(all_rows[got[0][keep]],
+                              got[1][keep].astype(np.int64), n, (),
+                              min_threshold)
+
+    def _run_rank(self, fn, limbs, *args) -> np.ndarray:
+        """One launch of a rank program over `limbs`, in the phase
+        `rank`, and the readback of its k columns, in `readback_d2h`."""
+        with profile.phase("rank"):
+            with self._launch_gate():
+                out = fn(limbs, *args)
+            if profile.current() is not None:
+                out.block_until_ready()  # unprofiled, the readback waits
+        with profile.phase("readback_d2h"):
+            return np.asarray(out)
+
+    def _readbacks(self, limbs) -> dict:
+        """{(live, n, floor): (2, k) readback}: what _rank_on_device
+        read back of one limb array, kept by the array's id, checked by
+        a weak reference and dropped with the array (the memo holds a
+        memoised one until a write purges it)."""
+        key = id(limbs)
+        seen = self._ranked.get(key)
+        if seen is None or seen[0]() is not limbs:
+            seen = (weakref.ref(limbs,
+                                lambda _r: self._ranked.pop(key, None)), {})
+            self._ranked[key] = seen
+        return seen[1]

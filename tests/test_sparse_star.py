@@ -522,3 +522,93 @@ def test_free_slots_gauge_reads_the_sparse_pool(holder):
     q(e, read)
     assert mgr.stats["sparse_inserts"] == 1 and mgr.stats["stage"] == 1
     assert mgr.stats["sparse_free_slots_min"] == before - 1
+
+
+# -- where the ranking runs -----------------------------------------------------
+
+
+def rank_counters(h):
+    """{where: ranking passes} from /debug/vars, which /metrics'
+    pilosa_topn_rank_total{where} has to match."""
+    import re
+
+    mesh = h.handle("GET", "/debug/vars").json()["mesh"]
+    prom = h.handle("GET", "/metrics").body.decode()
+    out = {}
+    for where in ("device", "host"):
+        m = re.search(r'^pilosa_topn_rank_total\{where="%s"\} (\d+)$'
+                      % where, prom, re.M)
+        assert m is not None and int(m.group(1)) == \
+            mesh[f"topn_rank_{where}"]
+        out[where] = mesh[f"topn_rank_{where}"]
+    return out
+
+
+def test_a_view_from_the_cut_up_ranks_on_the_device(holder, monkeypatch):
+    """Star Trace's shapes: `stargazer`'s 24 users pad to 32 rows,
+    `language`'s 5 to 8. With the cut at 32 a language-src TopN ranks
+    its users on the device, both passes (phase 1's n, phase 2's ids),
+    and a user-src TopN its languages on the host; n = 0 takes phase 1
+    to the host, an attribute filter and tanimoto both passes. Every
+    pass counts once, where it ran. Both directions answer as the host
+    recount does and as they did below the cut, across a memo hit and
+    a star."""
+    from pilosa_tpu.api import Handler
+    from pilosa_tpu.parallel import new_test_cluster, serve
+
+    rng = np.random.default_rng(29)
+    lang, star = populate(holder, rng)
+    cluster = new_test_cluster(1)  # one node: two passes a TopN, as served
+    host = cluster.nodes[0].host
+    e = Executor(holder, host=host, cluster=cluster, use_device=True,
+                 device_min_work=0)
+    h = Handler(holder, e, cluster=cluster, host=host)
+    for u in range(0, 24, 2):
+        q(e, f'SetRowAttrs(rowID={u}, frame="stargazer", team={u % 3})')
+    src = 'Bitmap(rowID=0, frame="language")'
+    forms = {  # (pql, passes on the device, on the host) from the cut up
+        "every_row": (f'TopN({src}, frame="stargazer")', 1, 1),
+        "filtered": (f'TopN({src}, frame="stargazer", n=4, field="team", '
+                     'filters=[1])', 0, 2),
+        "tanimoto": ('TopN(Bitmap(rowID=3, frame="stargazer"), '
+                     'frame="stargazer", tanimotoThreshold=10)', 0, 2)}
+
+    def run(pql):
+        before, passes = rank_counters(h), e.exec_stats["topn_passes"]
+        out = q(e, pql)
+        after = rank_counters(h)
+        moved = (after["device"] - before["device"],
+                 after["host"] - before["host"])
+        assert sum(moved) == e.exec_stats["topn_passes"] - passes
+        return out, moved
+
+    below = both_directions(e)
+    assert below == recount(holder)
+    assert rank_counters(h)["device"] == 0
+    answers = {name: run(pql)[0] for name, (pql, _d, _h) in forms.items()}
+    assert all(a[0] for a in answers.values())  # each ranks some rows
+    mgr = e.mesh_manager()
+    assert len(mgr._views[STAR].row_ids) == 24
+    monkeypatch.setattr(serve, "RANK_DEVICE_MIN_ROWS", 32)
+
+    hits = mgr.stats["memo_hit"]
+    before = rank_counters(h)
+    assert both_directions(e) == below  # from the memo
+    after = rank_counters(h)
+    assert mgr.stats["memo_hit"] > hits
+    # 5 language-src TopNs on the device, 5 user-src on the host.
+    assert after["device"] - before["device"] == 10
+    assert after["host"] - before["host"] == 10
+    for i in range(12):  # stars, each then read both ways
+        star.set_bit(int(rng.integers(24)),
+                     int(rng.integers(3)) * SLICE_WIDTH
+                     + int(rng.integers(8 * 65536)))
+        if i % 4 == 3:
+            assert both_directions(e) == recount(holder)
+    for name, (pql, on_device, on_host) in forms.items():
+        out, moved = run(pql)
+        assert moved == (on_device, on_host), name
+        monkeypatch.setattr(serve, "RANK_DEVICE_MIN_ROWS", 1 << 14)
+        assert q(e, pql) == out, name  # the host form, the same answer
+        monkeypatch.setattr(serve, "RANK_DEVICE_MIN_ROWS", 32)
+    assert mgr.stats["fallback"] == 0

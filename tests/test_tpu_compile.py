@@ -602,7 +602,8 @@ ST_S, ST_C, ST_K, ST_LANG = 480, 10480, 128, 512  # startrace-500m, seed 3700000
 
 
 @pytest.mark.parametrize("program", ["src_leaf_sparse", "ranked_sparse",
-                                     "write_donated", "write_copied"])
+                                     "write_donated", "write_copied",
+                                     "topn_rank", "topn_gather"])
 def test_star_trace_programs(chip, program):
     """startrace-500m's programs at its size: `language` dense, (480,
     512, 2048) words; `stargazer` sparse, (480, 10,480) keys and cards
@@ -612,7 +613,9 @@ def test_star_trace_programs(chip, program):
     (2 GB at this size: the largest temporary is the gathered src rows
     of the containers' first values); a star's write
     runs in the pool's own buffers when donated (no temporary), and
-    from one copy of them when not."""
+    from one copy of them when not. The ranking of those 2^20 users
+    runs where their (2, 2^20) limbs lie and leaves (2, 8) int32:
+    phase 1's rows, phase 2's candidates' columns."""
     from pilosa_tpu.parallel import mesh as M
 
     keys, vals, cards = (chip.sliced(np.int32, ST_C, s=ST_S),
@@ -642,6 +645,18 @@ def test_star_trace_programs(chip, program):
         # (2,690,210,816 B in all: PERF.md section 5, ROADMAP W7 (a)).
         assert mem.temp_size_in_bytes < 1.2 * ST_S * ST_C * 128 * 4
         assert not re.search(r"\[480,104857[67]\]", text)
+    elif program.startswith("topn_"):
+        limbs = chip.repl(np.int32, 2, 1 << 20)
+        if program == "topn_rank":
+            fn = M.compile_serve_topn_rank(chip.mesh, 1 << 20, 8)
+            _, mem = compiled(fn, limbs, *(chip.repl(np.int32),) * 3)
+        else:
+            fn = M.compile_serve_topn_gather(chip.mesh)
+            _, mem = compiled(fn, limbs, chip.repl(np.int32, 8))
+        # A few (2^20,) vectors at most, nothing pool-sized; and (2, 8)
+        # int32 out, one padded tile, not the 8 MB of limbs.
+        assert mem.temp_size_in_bytes < 64 << 20
+        assert mem.output_size_in_bytes <= 4096
     else:
         donate = program == "write_donated"
         fn = M.compile_serve_sparse_write(chip.mesh, donate=donate)
